@@ -24,7 +24,7 @@ def rows(nets=("vgg",), batch=8, limit=2, top_k=3, iters=2):
         scenes = all_scenes[net][:limit] if limit else all_scenes[net]
         for i, sc in enumerate(scenes):
             t = autotune_scene(sc, cache=cache, top_k=top_k, iters=iters,
-                               interpret=True, measure_batch=2,
+                               measure_batch=2,
                                measure_max_ch=16, measure_max_hw=8)
             tuned.append((f"{net}_L{i}", sc, t))
 

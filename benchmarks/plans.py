@@ -110,7 +110,7 @@ def rows(iters: int = 10):
         inp, flt = make_operands(sc)
         plan = make_plan(sc, ConvOp.FPROP)          # plan-once, off the clock
         legacy_us = _time_us(
-            lambda: ops.mg3m_conv_op(inp, flt, sc, interpret=True), iters)
+            lambda: ops.mg3m_conv_op(inp, flt, sc), iters)
         plan_us = _time_us(lambda: plan.execute(inp, flt), iters)
         out.append((
             f"plan_{name}", plan_us,

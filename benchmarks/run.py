@@ -9,6 +9,7 @@ from benchmarks import (batch, calibration, channels, cnns, filters,
                         granularity, padstride, plans, serving, sharding,
                         training, tuned)
 from benchmarks.common import emit, parse_derived
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def roofline_rows():
@@ -44,6 +45,7 @@ def main() -> None:
                     help="emit one machine-readable JSON document instead "
                          "of CSV (CI and dashboards consume this)")
     args = ap.parse_args()
+    enable_compile_cache()
     mods = {"channels": channels.rows, "batch": batch.rows,
             "filters": filters.rows, "padstride": padstride.rows,
             "cnns": cnns.rows, "granularity": granularity.rows,

@@ -19,7 +19,7 @@ def rows(nets=("vgg",), batch=8, limit=2, top_k=3, iters=2):
     for net in nets:
         scenes = all_scenes[net][:limit] if limit else all_scenes[net]
         for i, sc in enumerate(scenes):
-            t = autotune_scene(sc, top_k=top_k, iters=iters, interpret=True,
+            t = autotune_scene(sc, top_k=top_k, iters=iters,
                                measure_batch=2, measure_max_ch=16,
                                measure_max_hw=8)
             a = select_schedule(sc)

@@ -39,6 +39,6 @@ assert err < 1e-3
 
 # 5. The legacy one-shot call still works (it builds a plan under the hood);
 #    the backward directions are plans too — see ConvOp.DGRAD / WGRAD.
-one_shot = mg3m_conv(inp, flt, scene, interpret=True)
+one_shot = mg3m_conv(inp, flt, scene)
 assert float(jnp.max(jnp.abs(one_shot - out))) < 1e-5
 print("OK")

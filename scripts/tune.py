@@ -1,13 +1,13 @@
 """Batch-tune CNN workload scene sets and write the schedule-cache artifact.
 
-Usage (CPU-interpret, the container default):
+Usage (off a TPU the kernels run in the Pallas interpreter):
 
     PYTHONPATH=src python scripts/tune.py --nets vgg --batch 8 --limit 2
 
-On a real TPU drop the proxy caps and interpret mode:
+On a TPU the kernels compile for the chip; drop the proxy caps:
 
     PYTHONPATH=src python scripts/tune.py --nets all --batch 128 \
-        --no-interpret --measure-batch 0 --measure-max-ch 0 --measure-max-hw 0
+        --measure-batch 0 --measure-max-ch 0 --measure-max-hw 0
 
 Each scene is tuned through ``repro.tune.autotune_scene`` (analytic top-k
 pruning -> wall-clock measurement through the real kernel dispatch) and the
@@ -25,6 +25,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.mapping import select_schedule           # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models.cnn import cnn_scenes                  # noqa: E402
 from repro.tune import ScheduleCache, autotune_scene     # noqa: E402
 from repro.tune.autotune import error_summary            # noqa: E402
@@ -52,8 +53,6 @@ def parse_args(argv=None):
                     help="proxy cap on IC/OC (0 = exact)")
     ap.add_argument("--measure-max-hw", type=int, default=8,
                     help="proxy cap on inH/inW (0 = exact)")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="compile for real (TPU); default is interpret mode")
     ap.add_argument("--force", action="store_true",
                     help="re-measure scenes already in the cache")
     return ap.parse_args(argv)
@@ -61,7 +60,7 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    interpret = not args.no_interpret
+    enable_compile_cache()
     all_scenes = cnn_scenes(args.batch)
     nets = list(all_scenes) if args.nets == "all" else args.nets.split(",")
     unknown = [n for n in nets if n not in all_scenes]
@@ -73,7 +72,7 @@ def main(argv=None) -> int:
     cap = lambda v: v if v > 0 else None
 
     errors, disagreements, tuned_total = [], 0, 0
-    print(f"# cache: {cache.path} (backend={default_backend(interpret)})")
+    print(f"# cache: {cache.path} (backend={default_backend()})")
     print("scene,analytic,tuned,measured_us,analytic_measured_us,"
           "pred_err,n_cand")
     for net in nets:
@@ -83,7 +82,7 @@ def main(argv=None) -> int:
         for i, sc in enumerate(scenes):
             t = autotune_scene(
                 sc, cache=cache, top_k=args.top_k, iters=args.iters,
-                warmup=args.warmup, interpret=interpret,
+                warmup=args.warmup,
                 timeout_s=args.timeout_s,
                 measure_batch=cap(args.measure_batch),
                 measure_max_ch=cap(args.measure_max_ch),

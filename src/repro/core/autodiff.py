@@ -83,7 +83,7 @@ def backward_policy(policy: Union[None, str, ScheduleChoice]) -> str:
 
 def make_training_plans(scene: ConvScene, *,
                         policy: Union[None, str, ScheduleChoice] = "analytic",
-                        interpret: bool = True, use_pallas: bool = True,
+                        use_pallas: bool = True,
                         registry: Optional[PlanRegistry] = None
                         ) -> TrainingPlans:
     """Plan all three directions of one layer, each through the selector.
@@ -93,11 +93,11 @@ def make_training_plans(scene: ConvScene, *,
     propagate to the backward scenes).
     """
     bwd_policy = backward_policy(policy)
-    kw = dict(interpret=interpret, use_pallas=use_pallas)
     if registry is not None:
-        build = functools.partial(registry.get_or_build, scene, **kw)
+        build = functools.partial(registry.get_or_build, scene,
+                                  use_pallas=use_pallas)
     else:
-        build = functools.partial(make_plan, scene, **kw)
+        build = functools.partial(make_plan, scene, use_pallas=use_pallas)
     return TrainingPlans(fprop=build(ConvOp.FPROP, policy=policy),
                          dgrad=build(ConvOp.DGRAD, policy=bwd_policy),
                          wgrad=build(ConvOp.WGRAD, policy=bwd_policy))
@@ -192,7 +192,7 @@ class ModelPlans:
 
 def make_model_plans(scenes: Mapping[str, ConvScene], *,
                      policy: Union[None, str, ScheduleChoice] = "analytic",
-                     interpret: bool = True, use_pallas: bool = True,
+                     use_pallas: bool = True,
                      registry: Optional[PlanRegistry] = None,
                      devices: Optional[Sequence] = None,
                      max_shards: Optional[int] = None) -> ModelPlans:
@@ -215,18 +215,18 @@ def make_model_plans(scenes: Mapping[str, ConvScene], *,
         return ModelPlans(layers=tuple(
             (name, make_sharded_training_plans(
                 sc, policy=policy if isinstance(policy, str) else "analytic",
-                interpret=interpret, devices=devices, max_shards=max_shards))
+                devices=devices, max_shards=max_shards))
             for name, sc in scenes.items()))
     reg = registry if registry is not None else default_registry()
     scene_list = list(scenes.values())
     bwd = backward_policy(policy)
     reg.warm(scene_list, ops=(ConvOp.FPROP,), policy=policy,
-             interpret=interpret, use_pallas=use_pallas)
+             use_pallas=use_pallas)
     reg.warm(scene_list, ops=(ConvOp.DGRAD, ConvOp.WGRAD), policy=bwd,
-             interpret=interpret, use_pallas=use_pallas)
+             use_pallas=use_pallas)
     return ModelPlans(layers=tuple(
-        (name, make_training_plans(sc, policy=policy, interpret=interpret,
-                                   use_pallas=use_pallas, registry=reg))
+        (name, make_training_plans(sc, policy=policy, use_pallas=use_pallas,
+                                   registry=reg))
         for name, sc in scenes.items()))
 
 
@@ -250,11 +250,10 @@ def apply_conv(inp: jax.Array, flt: jax.Array, plans) -> jax.Array:
 # legacy per-call shims (signatures preserved)
 # --------------------------------------------------------------------------
 def grad_input(d_out: jax.Array, flt: jax.Array, scene: ConvScene, *,
-               interpret: bool = True, use_pallas: bool = True) -> jax.Array:
+               use_pallas: bool = True) -> jax.Array:
     """dL/dIN via the scene's DGRAD plan (Pallas even on strided forwards;
     see the plan's ``uses_reference``/``notes`` for the rare fallback)."""
-    plan = get_plan(scene, ConvOp.DGRAD, interpret=interpret,
-                    use_pallas=use_pallas)
+    plan = get_plan(scene, ConvOp.DGRAD, use_pallas=use_pallas)
     return plan.execute(d_out, flt)
 
 
@@ -265,13 +264,12 @@ def grad_filter(inp: jax.Array, d_out: jax.Array, scene: ConvScene
 
 
 def mg3m_conv_trainable(inp: jax.Array, flt: jax.Array, scene: ConvScene,
-                        schedule: Optional[str] = None,
-                        interpret: bool = True) -> jax.Array:
+                        schedule: Optional[str] = None) -> jax.Array:
     """Differentiable MG3MConv — Pallas forward, MG3M-scene backward.
 
     Legacy signature; plans come from the default ``PlanRegistry``, so
     repeated calls on the same scene reuse the same frozen plans."""
     from repro.plan.registry import default_registry
-    plans = make_training_plans(scene, policy=schedule, interpret=interpret,
+    plans = make_training_plans(scene, policy=schedule,
                                 registry=default_registry())
     return conv_with_plans(inp, flt, plans)

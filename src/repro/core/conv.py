@@ -38,7 +38,7 @@ def __getattr__(name):
 
 
 def mg3m_conv(inp: jax.Array, flt: jax.Array, scene: ConvScene, *,
-              schedule: ScheduleSpec = None, interpret: bool = True,
+              schedule: ScheduleSpec = None,
               use_pallas: bool = True) -> jax.Array:
     """Convolution in the paper's layouts IN[H,W,IC,B], FLT[h,w,IC,OC].
 
@@ -47,12 +47,12 @@ def mg3m_conv(inp: jax.Array, flt: jax.Array, scene: ConvScene, *,
     exact ScheduleChoice.  Per-call shim — see ``make_plan`` to amortize
     resolution over many executions."""
     return ops.mg3m_conv_op(inp, flt, scene, schedule=schedule,
-                            interpret=interpret, use_pallas=use_pallas)
+                            use_pallas=use_pallas)
 
 
 def mg3m_conv_nhwc(x: jax.Array, flt: jax.Array, *, stride=(1, 1),
                    padding=(0, 0), schedule: ScheduleSpec = None,
-                   interpret: bool = True, use_pallas: bool = True) -> jax.Array:
+                   use_pallas: bool = True) -> jax.Array:
     """Framework-friendly NHWC entry point (x: [B,H,W,C], flt: [h,w,IC,OC]).
 
     Transposes into the paper's [H,W,C,B] layout (a one-time layout choice in
@@ -69,6 +69,6 @@ def mg3m_conv_nhwc(x: jax.Array, flt: jax.Array, *, stride=(1, 1),
                       padH=padding[0], padW=padding[1],
                       stdH=stride[0], stdW=stride[1], dtype=str(x.dtype))
     inp = jnp.transpose(x, (1, 2, 3, 0))  # [H, W, C, B]
-    out = mg3m_conv(inp, flt, scene, schedule=schedule, interpret=interpret,
+    out = mg3m_conv(inp, flt, scene, schedule=schedule,
                     use_pallas=use_pallas)
     return jnp.transpose(out, (3, 0, 1, 2))  # [B, outH, outW, OC]

@@ -32,12 +32,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Mapping, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.analysis.footprint import vmem_bytes as _vmem_bytes
 from repro.core.scene import ConvScene, ceil_div, round_up
 
-# TPU v5e model constants (per chip).  bf16 MXU rate; fp32 runs at half.
+# TPU v5e per-chip peaks (Google Cloud documentation, "TPU v5e": 197
+# TFLOP/s bf16, 16 GB HBM at 819 GB/s).  bf16 MXU rate; fp32 runs at half.
 MXU_FLOPS_BF16 = 197e12
 MXU_FLOPS_FP32 = MXU_FLOPS_BF16 / 2
 HBM_BW = 819e9  # bytes/s
@@ -137,7 +139,27 @@ class CostModel:
         return bool(self.corrections)
 
 
+# The v5e, the stated target: priced wherever no TPU is attached (CPU
+# tests, compiles for a described chip).
 DEFAULT_COST_MODEL = CostModel()
+# Uncalibrated models keyed by ``jax.Device.device_kind``.  A TPU whose kind
+# is missing here is an error (``device_cost_model``), not a default.
+DEVICE_COST_MODELS: Dict[str, CostModel] = {"TPU v5 lite": DEFAULT_COST_MODEL}
+
+
+def device_cost_model() -> CostModel:
+    """The uncalibrated model of the chip this process runs on: on a TPU,
+    the one of its ``device_kind`` (``ValueError`` for a kind with no
+    entry); elsewhere ``DEFAULT_COST_MODEL``."""
+    if jax.default_backend() != "tpu":
+        return DEFAULT_COST_MODEL
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_COST_MODELS:
+        raise ValueError(
+            f"no published peaks for TPU device kind {kind!r} (known: "
+            f"{sorted(DEVICE_COST_MODELS)}); add its entry to "
+            f"repro.core.mapping.DEVICE_COST_MODELS")
+    return DEVICE_COST_MODELS[kind]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,10 +183,6 @@ class ScheduleChoice:
 
 def _dtype_bytes(dtype: str) -> int:
     return jnp.dtype(dtype).itemsize
-
-
-def _mxu_rate(dtype: str) -> float:
-    return DEFAULT_COST_MODEL.mxu_rate(dtype)
 
 
 def grid_steps(scene: ConvScene, bm: int, bn: int, bk: int) -> int:
@@ -229,7 +247,7 @@ def _traffic_bytes(scene: ConvScene, schedule: str, bm: int, bn: int, bk: int) -
 
 def _score(scene: ConvScene, schedule: str, bm: int, bn: int, bk: int,
            model: Optional[CostModel] = None) -> Optional[ScheduleChoice]:
-    model = model if model is not None else DEFAULT_COST_MODEL
+    model = model if model is not None else device_cost_model()
     vmem = _vmem_bytes(scene, schedule, bm, bn, bk)
     if vmem > VMEM_BUDGET:
         return None
@@ -322,6 +340,6 @@ def predicted_efficiency(scene: ConvScene, choice: ScheduleChoice,
                          model: Optional[CostModel] = None) -> float:
     """Useful FLOPs / (peak FLOPs x modeled time) — the paper's
     'hardware efficiency' metric under the analytic model."""
-    model = model if model is not None else DEFAULT_COST_MODEL
+    model = model if model is not None else device_cost_model()
     ideal_s = scene.flops / model.mxu_rate(scene.dtype)
     return min(1.0, ideal_s / max(choice.predicted_s, 1e-30))

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import TPUCompilerParams
+from repro.kernels import interpret_mode
 
 
 def _kernel(x_ref, prev_ref, w_ref, out_ref, *, kw: int, block_l: int):
@@ -36,7 +36,7 @@ def _kernel(x_ref, prev_ref, w_ref, out_ref, *, kw: int, block_l: int):
 
 
 def causal_conv1d(x: jax.Array, w: jax.Array, *, block_l: int,
-                  block_d: int, interpret: bool = False) -> jax.Array:
+                  block_d: int) -> jax.Array:
     b, l, d = x.shape
     kw = w.shape[0]
     if l % block_l != 0 or d % block_d != 0:
@@ -61,7 +61,7 @@ def causal_conv1d(x: jax.Array, w: jax.Array, *, block_l: int,
         out_specs=pl.BlockSpec((1, block_l, block_d),
                                lambda bi, li, di: (bi, li, di)),
         out_shape=jax.ShapeDtypeStruct((b, l, d), x.dtype),
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "parallel")),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, x, w)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import TPUCompilerParams
+from repro.kernels import interpret_mode
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -74,8 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = False
-                        ) -> jax.Array:
+                        block_k: int = 128) -> jax.Array:
     """q: (BH, S, D); k, v: (BHkv, T, D); BH % BHkv == 0."""
     bh, s, d = q.shape
     bhkv, t, _ = k.shape
@@ -104,16 +103,15 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq,), F32), pltpu.VMEM((bq,), F32),
                         pltpu.VMEM((bq, d), F32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(q, k, v)
 
 
 def flash_attention_bshd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = False
-                         ) -> jax.Array:
+                         block_k: int = 128) -> jax.Array:
     """Model-layout wrapper: q (B,S,H,D), k/v (B,T,Hkv,D) -> (B,S,H,D)."""
     b, s, h, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
@@ -121,5 +119,5 @@ def flash_attention_bshd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, t, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, t, d)
     of = flash_attention_fwd(qf, kf, vf, causal=causal, block_q=block_q,
-                             block_k=block_k, interpret=interpret)
+                             block_k=block_k)
     return of.reshape(b, h, s, d).transpose(0, 2, 1, 3)

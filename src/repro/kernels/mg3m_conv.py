@@ -45,7 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,10 +53,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.footprint import vmem_bytes
-from repro.kernels.pallas_compat import TPUCompilerParams
-
 from repro.core.mapping import VMEM_BUDGET
 from repro.core.scene import ConvScene, ceil_div
+from repro.kernels import interpret_mode
 
 Shape4 = Tuple[int, int, int, int]
 
@@ -244,7 +243,7 @@ def _launch(spec: KernelGridSpec, kernel, inp: jax.Array, flt: jax.Array, *,
         out_specs=pl.BlockSpec(spec.out_block, spec.out_index),
         out_shape=jax.ShapeDtypeStruct(spec.out_shape, inp.dtype),
         scratch_shapes=[pltpu.VMEM(spec.acc_shape, spec.acc_dtype)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=spec.dimension_semantics),
         interpret=interpret,
     )(inp, flt)
@@ -254,10 +253,15 @@ def _dot_kt(flt_blk: jax.Array, in_blk: jax.Array) -> jax.Array:
     """(K, M) x (K, N) -> (M, N) contracting K (the paper's MM_unit, Eq. 2).
 
     FLT is consumed in its natural [.., IC, OC] layout: no transposition, the
-    TPU analogue of the paper's `ldde`-broadcast trick (§4.4.1)."""
+    TPU analogue of the paper's `ldde`-broadcast trick (§4.4.1).  f32
+    operands contract at f32 precision on the MXU whatever Mosaic's default
+    or an enclosing ``jax.default_matmul_precision``: an f32 scene is an f32
+    convolution."""
+    f32 = flt_blk.dtype == jnp.float32 and in_blk.dtype == jnp.float32
     return jax.lax.dot_general(
         flt_blk, in_blk,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
         preferred_element_type=jnp.float32,
     )
 
@@ -286,14 +290,17 @@ def _tb11_kernel(in_ref, flt_ref, out_ref, acc_ref, *, flt_hw: Tuple[int, int],
 
 
 def conv_tb11(inp: jax.Array, flt: jax.Array, scene: ConvScene, *,
-              interpret: bool = False) -> jax.Array:
+              interpret: Optional[bool] = None) -> jax.Array:
     """inp pre-padded (or compact+sentinel when lhs-dilated, see module doc);
-    returns [outH, outW, M, N]."""
+    returns [outH, outW, M, N].  ``interpret`` None derives the kernel mode
+    from the platform (``repro.kernels.interpret_mode``); tests pass False
+    to compile for a described chip."""
     spec = kernel_grid_spec(scene, "TB11", in_shape=inp.shape,
                             flt_shape=flt.shape, vmem_budget=VMEM_BUDGET)
     kernel = functools.partial(_tb11_kernel, flt_hw=spec.reduction_extents,
                                out_dtype=inp.dtype)
-    return _launch(spec, kernel, inp, flt, interpret=interpret)
+    return _launch(spec, kernel, inp, flt,
+                   interpret=interpret_mode(interpret))
 
 
 # --------------------------------------------------------------------------
@@ -318,13 +325,14 @@ def _tb18_kernel(in_ref, flt_ref, out_ref, acc_ref, *, flt_hw: Tuple[int, int],
 
 
 def conv_tb18(inp: jax.Array, flt: jax.Array, scene: ConvScene, *, bm: int,
-              interpret: bool = False) -> jax.Array:
+              interpret: Optional[bool] = None) -> jax.Array:
     spec = kernel_grid_spec(scene, "TB18", in_shape=inp.shape,
                             flt_shape=flt.shape, bm=bm,
                             vmem_budget=VMEM_BUDGET)
     kernel = functools.partial(_tb18_kernel, flt_hw=spec.reduction_extents,
                                out_dtype=inp.dtype)
-    return _launch(spec, kernel, inp, flt, interpret=interpret)
+    return _launch(spec, kernel, inp, flt,
+                   interpret=interpret_mode(interpret))
 
 
 # --------------------------------------------------------------------------
@@ -350,10 +358,12 @@ def _tb88_kernel(in_ref, flt_ref, out_ref, acc_ref, *, red_dims, out_dtype):
 
 
 def conv_tb88(inp: jax.Array, flt: jax.Array, scene: ConvScene, *, bm: int,
-              bn: int, bk: int, interpret: bool = False) -> jax.Array:
+              bn: int, bk: int, interpret: Optional[bool] = None
+              ) -> jax.Array:
     spec = kernel_grid_spec(scene, "TB88", in_shape=inp.shape,
                             flt_shape=flt.shape, bm=bm, bn=bn, bk=bk,
                             vmem_budget=VMEM_BUDGET)
     kernel = functools.partial(_tb88_kernel, red_dims=spec.reduction_extents,
                                out_dtype=inp.dtype)
-    return _launch(spec, kernel, inp, flt, interpret=interpret)
+    return _launch(spec, kernel, inp, flt,
+                   interpret=interpret_mode(interpret))

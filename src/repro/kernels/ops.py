@@ -23,8 +23,8 @@ from repro.plan.build import _pad_axis
 ScheduleSpec = Union[None, str, ScheduleChoice]
 
 
-def resolve_choice(scene: ConvScene, schedule: ScheduleSpec,
-                   interpret: bool = True) -> ScheduleChoice:
+def resolve_choice(scene: ConvScene, schedule: ScheduleSpec
+                   ) -> ScheduleChoice:
     """Schedule-spec resolution shared by every conv entry point.
 
       None          multi-grained selection under the active cost model
@@ -38,12 +38,11 @@ def resolve_choice(scene: ConvScene, schedule: ScheduleSpec,
     Delegates to ``repro.plan.build.resolve_policy`` — the same resolution a
     ``ConvPlan`` runs once at build time.
     """
-    return plan_build.resolve_policy(scene, schedule, interpret)
+    return plan_build.resolve_policy(scene, schedule)
 
 
 def mg3m_conv_op(inp: jax.Array, flt: jax.Array, scene: ConvScene, *,
                  schedule: ScheduleSpec = None,
-                 interpret: bool = True,
                  use_pallas: bool = True) -> jax.Array:
     """Multi-grained convolution in the paper's layouts (per-call shim).
 
@@ -52,8 +51,6 @@ def mg3m_conv_op(inp: jax.Array, flt: jax.Array, scene: ConvScene, *,
       schedule: force "TB11"/"TB18"/"TB88"; None = analytic auto-select;
         "auto" = tuned-cache resolution (repro.tune) with analytic fallback;
         a ScheduleChoice pins the exact (schedule, bm, bn, bk).
-      interpret: run the Pallas kernel in interpret mode (CPU validation);
-        set False on real TPU.
       use_pallas: False routes to the pure-jnp reference (used by the
         distributed model code on CPU-only dry-runs).
     Returns: [outH, outW, OC, B].
@@ -71,13 +68,12 @@ def mg3m_conv_op(inp: jax.Array, flt: jax.Array, scene: ConvScene, *,
             f"filter shape {flt.shape} does not match the scene's FLT layout "
             f"{scene.flt_shape()} for {scene.describe()}")
     plan = plan_build.make_plan(scene, plan_build.ConvOp.FPROP,
-                                policy=schedule, interpret=interpret,
-                                use_pallas=use_pallas)
+                                policy=schedule, use_pallas=use_pallas)
     return plan.execute(inp, flt)
 
 
 def causal_conv1d_op(x: jax.Array, w: jax.Array, *, block_l: int = 256,
-                     block_d: int = 256, interpret: bool = True,
+                     block_d: int = 256,
                      use_pallas: bool = True) -> jax.Array:
     """Depthwise causal conv1d (Mamba2's conv) — see kernels/causal_conv1d.py."""
     from repro.kernels import causal_conv1d, ref
@@ -89,6 +85,5 @@ def causal_conv1d_op(x: jax.Array, w: jax.Array, *, block_l: int = 256,
     lp, dp = round_up(l, bl), round_up(d, bd)
     x_a = _pad_axis(_pad_axis(x, 1, lp), 2, dp)
     w_a = _pad_axis(w, 1, dp)
-    out = causal_conv1d.causal_conv1d(x_a, w_a, block_l=bl, block_d=bd,
-                                      interpret=interpret)
+    out = causal_conv1d.causal_conv1d(x_a, w_a, block_l=bl, block_d=bd)
     return out[:, :l, :d]

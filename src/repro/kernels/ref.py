@@ -15,7 +15,8 @@ from repro.core.scene import ConvScene
 
 
 def conv_ref(inp: jax.Array, flt: jax.Array, scene: ConvScene) -> jax.Array:
-    """Oracle via lax.conv_general_dilated in the paper's layouts.
+    """Oracle via lax.conv_general_dilated in the paper's layouts, at
+    ``precision=HIGHEST`` so that on a TPU it is an f32 reference.
 
     Covers the full dilated scene family: ``dilH/dilW`` map to
     ``lhs_dilation`` (transposed-conv / dgrad scenes), ``fdilH/fdilW`` to
@@ -32,6 +33,7 @@ def conv_ref(inp: jax.Array, flt: jax.Array, scene: ConvScene) -> jax.Array:
         lhs_dilation=(scene.dilH, scene.dilW),
         rhs_dilation=(scene.fdilH, scene.fdilW),
         dimension_numbers=dn,
+        precision=jax.lax.Precision.HIGHEST,
     )
     return out.astype(inp.dtype)
 

@@ -8,6 +8,14 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules here
+    are constraints for the partitioner (``with_sharding_constraint``,
+    ``shard_map``), which ``Explicit`` axes — JAX's default — reject."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_mesh_for(n_data: int, n_model: int):
     """("data", "model") mesh sized for this process's devices.
 
@@ -28,13 +36,13 @@ def make_mesh_for(n_data: int, n_model: int):
     n_data = min(n_data, avail // n_model)
     while (avail // n_model) % n_data:
         n_data -= 1
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.pipeline import SyntheticImages
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.drift import default_monitor
 from repro.obs.metrics import default_metrics
 from repro.train import checkpoint as ckpt
@@ -83,6 +84,7 @@ def main() -> None:
     ap.add_argument("--no-strict", dest="strict", action="store_false",
                     help="disable the steady-state zero-resolution guard")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.batch % args.microbatches:
         raise ValueError(f"--batch {args.batch} not divisible by "
                          f"--microbatches {args.microbatches}")

@@ -202,15 +202,14 @@ def small_cnn_scenes(p: Params, batch: int, res: int,
 
 def small_cnn_plans(p: Params, batch: int, res: int, *,
                     dtype: str = "float32", policy=None,
-                    interpret: bool = True, devices=None) -> "ModelPlans":
+                    devices=None) -> "ModelPlans":
     """Pre-build the (fprop, dgrad, wgrad) plan triple of every layer into
     one ``ModelPlans`` — plan-once (one ``PlanRegistry.warm`` pass), then
     every forward/backward step is pure dispatch.  ``devices`` (a
     data-parallel ring) builds mesh-sharded triples instead."""
     from repro.core.autodiff import make_model_plans
     return make_model_plans(small_cnn_scenes(p, batch, res, dtype),
-                            policy=policy, interpret=interpret,
-                            devices=devices)
+                            policy=policy, devices=devices)
 
 
 def small_cnn_forward(p: Params, x: jax.Array, *, use_pallas: bool = False,

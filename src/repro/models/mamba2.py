@@ -144,7 +144,7 @@ def mamba2_block(p: Params, x: jax.Array, cfg: SSMConfig, *,
         zxbcdt, [di, 2 * di, 2 * di + 2 * g * s], axis=-1)
     conv_in = jnp.concatenate([xin, bc], -1)
     if use_pallas:
-        conv_out = kops.causal_conv1d_op(conv_in, p["conv_w"], interpret=True)
+        conv_out = kops.causal_conv1d_op(conv_in, p["conv_w"])
     else:
         conv_out = kref.causal_conv1d_ref(conv_in, p["conv_w"])
     conv_out = jax.nn.silu(conv_out.astype(F32)).astype(x.dtype)

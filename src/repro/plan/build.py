@@ -98,8 +98,7 @@ def policy_tag(policy: PolicySpec) -> str:
     return f"forced:{policy}"
 
 
-def resolve_policy(scene: ConvScene, policy: PolicySpec,
-                   interpret: bool = True) -> ScheduleChoice:
+def resolve_policy(scene: ConvScene, policy: PolicySpec) -> ScheduleChoice:
     """One-time schedule resolution for a plan (and the legacy per-call path).
 
       None / "analytic"   multi-grained selection under the active cost model
@@ -118,7 +117,7 @@ def resolve_policy(scene: ConvScene, policy: PolicySpec,
     try:
         if policy in ("auto", "tuned"):
             from repro.tune.autotune import resolve_schedule  # avoids cycle
-            return resolve_schedule(scene, interpret=interpret)
+            return resolve_schedule(scene)
         if policy in (None, "analytic"):
             return select_schedule(scene, model=_active_cost_model())
         return select_schedule(scene, allowed=(policy,),
@@ -315,7 +314,7 @@ def wgrad_finish(out: jax.Array) -> jax.Array:
 
 
 def _conv_body(inp: jax.Array, flt: jax.Array, scene: ConvScene,
-               spec: ExecSpec, interpret: bool) -> jax.Array:
+               spec: ExecSpec) -> jax.Array:
     """Kernel dispatch from a precomputed spec (no shape arithmetic here).
 
     Lhs-dilated scenes take the sentinel route: the compact input gains one
@@ -328,45 +327,44 @@ def _conv_body(inp: jax.Array, flt: jax.Array, scene: ConvScene,
                               (spec.pad_w, spec.pad_w + spec.apad_w),
                               (0, 0), (0, 0)))
     if spec.schedule == "TB11":
-        out = kernels.conv_tb11(inp_p, flt, scene, interpret=interpret)
+        out = kernels.conv_tb11(inp_p, flt, scene)
     elif spec.schedule == "TB18":
         flt_a = _pad_axis(flt, 3, spec.mp)
-        out = kernels.conv_tb18(inp_p, flt_a, scene, bm=spec.bm,
-                                interpret=interpret)[:, :, :spec.m, :]
+        out = kernels.conv_tb18(inp_p, flt_a, scene,
+                                bm=spec.bm)[:, :, :spec.m, :]
     else:
         inp_a = _pad_axis(_pad_axis(inp_p, 2, spec.kp), 3, spec.np_)
         flt_a = _pad_axis(_pad_axis(flt, 2, spec.kp), 3, spec.mp)
         out = kernels.conv_tb88(inp_a, flt_a, scene, bm=spec.bm, bn=spec.bn,
-                                bk=spec.bk,
-                                interpret=interpret)[:, :, :spec.m, :spec.n]
+                                bk=spec.bk)[:, :, :spec.m, :spec.n]
     if (spec.out_h, spec.out_w) not in ((0, 0), (scene.outH, scene.outW)):
         out = out[:spec.out_h, :spec.out_w]
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("scene", "spec", "interpret"))
-def _exec_fprop(inp, flt, scene: ConvScene, spec: ExecSpec, interpret: bool):
-    return _conv_body(inp, flt, scene, spec, interpret)
+@functools.partial(jax.jit, static_argnames=("scene", "spec"))
+def _exec_fprop(inp, flt, scene: ConvScene, spec: ExecSpec):
+    return _conv_body(inp, flt, scene, spec)
 
 
-@functools.partial(jax.jit, static_argnames=("scene", "spec", "interpret"))
-def _exec_dgrad(d_out, flt, scene: ConvScene, spec: ExecSpec, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("scene", "spec"))
+def _exec_dgrad(d_out, flt, scene: ConvScene, spec: ExecSpec):
     # scene/spec here describe the *dgrad* scene (grad_input_scene); for a
     # strided forward it is lhs-dilated and the kernels read the compact
     # dOUT through the sentinel index maps.
     a, b = dgrad_operands(d_out, flt)   # rot180 + IC<->OC
-    return _conv_body(a, b, scene, spec, interpret)
+    return _conv_body(a, b, scene, spec)
 
 
-@functools.partial(jax.jit, static_argnames=("scene", "spec", "interpret"))
-def _exec_wgrad(inp, d_out, scene: ConvScene, spec: ExecSpec, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("scene", "spec"))
+def _exec_wgrad(inp, d_out, scene: ConvScene, spec: ExecSpec):
     # scene/spec describe the *wgrad* scene (grad_filter_scene): input with
     # (IC, B) swapped, filter = dOUT with (OC, B) swapped (rhs-dilated by
     # the forward stride), output [fltH(+r), fltW(+r), OC, IC] sliced back
     # to the true filter dims (spec.out_h/out_w, inside _conv_body) and
     # transposed to the FLT layout.
     a, b = wgrad_operands(inp, d_out)
-    return wgrad_finish(_conv_body(a, b, scene, spec, interpret))
+    return wgrad_finish(_conv_body(a, b, scene, spec))
 
 
 # Reference executors (use_pallas=False and the recorded fallbacks).
@@ -419,7 +417,6 @@ class ConvPlan:
     scene: ConvScene                    # the *forward* scene the plan serves
     op: ConvOp
     policy: str                         # canonical tag (see ``policy_tag``)
-    interpret: bool
     use_pallas: bool
     uses_reference: bool
     notes: Tuple[str, ...] = ()
@@ -442,7 +439,7 @@ class ConvPlan:
             return fn(a, b, self.scene)
         fn = {ConvOp.FPROP: _exec_fprop, ConvOp.DGRAD: _exec_dgrad,
               ConvOp.WGRAD: _exec_wgrad}[self.op]
-        return fn(a, b, self.exec_scene, self.spec, self.interpret)
+        return fn(a, b, self.exec_scene, self.spec)
 
     __call__ = execute
 
@@ -480,7 +477,7 @@ class ConvPlan:
 
 
 def make_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
-              policy: PolicySpec = "analytic", interpret: bool = True,
+              policy: PolicySpec = "analytic",
               use_pallas: bool = True) -> ConvPlan:
     """Build a frozen ``ConvPlan``: resolve the schedule once, derive the
     backward scene (DGRAD/WGRAD), precompute every padded/aligned shape.
@@ -501,11 +498,11 @@ def make_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
     tag = policy_tag(policy)
     with default_tracer().span("repro.plan.make_plan", op=op.value,
                                policy=tag, scene=scene.describe()):
-        return _make_plan_inner(scene, op, policy, tag, interpret, use_pallas)
+        return _make_plan_inner(scene, op, policy, tag, use_pallas)
 
 
 def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
-                     tag: str, interpret: bool, use_pallas: bool) -> ConvPlan:
+                     tag: str, use_pallas: bool) -> ConvPlan:
     t_build = time.perf_counter()
     notes = []
     uses_reference = not use_pallas
@@ -542,22 +539,21 @@ def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
 
     choice = spec = None
     if not uses_reference:
-        choice = resolve_policy(exec_scene, policy, interpret)
+        choice = resolve_policy(exec_scene, policy)
         spec = derive_exec_spec(exec_scene, choice, out_hw)
     m = default_metrics()
     m.counter("repro.plan.builds").inc()
     if uses_reference:
         m.counter("repro.plan.reference_fallbacks").inc()
     m.histogram("repro.plan.build_s").observe(time.perf_counter() - t_build)
-    return ConvPlan(scene=scene, op=op, policy=tag,
-                    interpret=interpret, use_pallas=use_pallas,
+    return ConvPlan(scene=scene, op=op, policy=tag, use_pallas=use_pallas,
                     uses_reference=uses_reference, notes=tuple(notes),
                     exec_scene=None if uses_reference else exec_scene,
                     choice=choice, spec=spec)
 
 
 def assemble_plan(scene: ConvScene, op: Union[ConvOp, str], policy: str,
-                  choice: Optional[ScheduleChoice], *, interpret: bool = True,
+                  choice: Optional[ScheduleChoice], *,
                   use_pallas: bool = True) -> ConvPlan:
     """Rebuild a plan from a stored (scene, op, policy-tag, choice) without
     re-running resolution — the registry's deserialization path.  A stored
@@ -566,16 +562,14 @@ def assemble_plan(scene: ConvScene, op: Union[ConvOp, str], policy: str,
     what the op can execute (e.g. a Pallas choice for a strided dgrad)."""
     op = ConvOp(op)
     if choice is None:
-        plan = make_plan(scene, op, policy="analytic", interpret=interpret,
-                         use_pallas=use_pallas)
+        plan = make_plan(scene, op, policy="analytic", use_pallas=use_pallas)
         if not plan.uses_reference:
             raise ValueError(
                 f"stored {op.value} plan for {scene.describe()} has no "
                 f"schedule choice but the op does not require a reference "
                 f"fallback")
         return dataclasses.replace(plan, policy=policy)
-    plan = make_plan(scene, op, policy=choice, interpret=interpret,
-                     use_pallas=use_pallas)
+    plan = make_plan(scene, op, policy=choice, use_pallas=use_pallas)
     if plan.uses_reference:
         raise ValueError(
             f"stored {op.value} plan for {scene.describe()} pins "

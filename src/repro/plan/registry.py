@@ -1,7 +1,7 @@
 """Process-level plan repository — plan-once, execute-many, serve-forever.
 
 ``PlanRegistry`` memoizes frozen ``ConvPlan``s under a canonical signature
-(scene dims + dtype + op + policy + interpret + use_pallas), with the same
+(scene dims + dtype + op + policy + use_pallas), with the same
 conventions as the tune subsystem's schedule cache: hit/miss counters,
 bounded LRU eviction, and a versioned JSON artifact (atomic tmp+rename
 merge-on-``save`` so concurrent writers union rather than clobber,
@@ -40,8 +40,8 @@ _SCENE_FIELDS = ("B", "IC", "OC", "inH", "inW", "fltH", "fltW",
 
 
 def plan_signature(scene: ConvScene, op: Union[ConvOp, str],
-                   policy: PolicySpec, interpret: bool,
-                   use_pallas: bool, shard: Optional[str] = None) -> str:
+                   policy: PolicySpec, use_pallas: bool,
+                   shard: Optional[str] = None) -> str:
     """Canonical registry key.  Dtype-alias-stable (via numpy dtype names)
     and explicit about everything that changes the executable.  Dilation
     axes are appended only when active, so undilated keys — the entire
@@ -53,7 +53,7 @@ def plan_signature(scene: ConvScene, op: Union[ConvOp, str],
     dt = jnp.dtype(scene.dtype).name
     frag = f"|shard={shard}" if shard else ""
     return (f"v={PLAN_VERSION}|op={ConvOp(op).value}|pol={policy_tag(policy)}"
-            f"|int={int(interpret)}|pl={int(use_pallas)}|dt={dt}"
+            f"|pl={int(use_pallas)}|dt={dt}"
             f"|B={scene.B}|IC={scene.IC}|OC={scene.OC}"
             f"|in={scene.inH}x{scene.inW}|flt={scene.fltH}x{scene.fltW}"
             f"|pad={scene.padH},{scene.padW}|std={scene.stdH},{scene.stdW}"
@@ -65,7 +65,6 @@ def plan_to_dict(plan) -> Dict:
         "scene": {f: getattr(plan.scene, f) for f in _SCENE_FIELDS},
         "op": plan.op.value,
         "policy": plan.policy,
-        "interpret": plan.interpret,
         "use_pallas": plan.use_pallas,
         "uses_reference": plan.uses_reference,
         "notes": list(plan.notes),
@@ -84,19 +83,28 @@ def plan_from_dict(d: Dict):
     Sharded entries rebuild through ``assemble_sharded_plan`` and raise
     ``ValueError`` when this process has fewer devices than the stored
     ring (``load`` skips them, ``save`` keeps them — see
-    ``valid_plan_dict``)."""
+    ``valid_plan_dict``).  The ``interpret`` field of artifacts written
+    before the kernel mode was derived from the platform is ignored."""
     scene = ConvScene(**d["scene"])
     sh = d.get("shard")
     if sh:
         from repro.shard.plan import assemble_sharded_plan
         choice = choice_from_dict(d["choice"])
         return assemble_sharded_plan(scene, d["op"], d["policy"],
-                                     sh["axis"], int(sh["n"]), choice,
-                                     interpret=bool(d.get("interpret", True)))
+                                     sh["axis"], int(sh["n"]), choice)
     choice = choice_from_dict(d["choice"]) if d.get("choice") else None
     return assemble_plan(scene, d["op"], d["policy"], choice,
-                         interpret=bool(d.get("interpret", True)),
                          use_pallas=bool(d.get("use_pallas", True)))
+
+
+def entry_key(d: Dict) -> str:
+    """Registry key of one stored entry, recomputed from its fields: an
+    artifact whose keys carry a fragment the signature no longer has (the
+    ``|int=`` kernel mode) still serves its plans under today's keys."""
+    sh = d.get("shard")
+    return plan_signature(ConvScene(**d["scene"]), d["op"], d["policy"],
+                          bool(d.get("use_pallas", True)),
+                          shard=f"{sh['axis']}:{int(sh['n'])}" if sh else None)
 
 
 def valid_plan_dict(d) -> bool:
@@ -185,18 +193,18 @@ class PlanRegistry:
             return key in self._mem
 
     def key(self, scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP,
-            policy: PolicySpec = "analytic", interpret: bool = True,
-            use_pallas: bool = True, shard: Optional[str] = None) -> str:
-        return plan_signature(scene, op, policy, interpret, use_pallas, shard)
+            policy: PolicySpec = "analytic", use_pallas: bool = True,
+            shard: Optional[str] = None) -> str:
+        return plan_signature(scene, op, policy, use_pallas, shard)
 
     # -- lookup ------------------------------------------------------------
     def get(self, scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
-            policy: PolicySpec = "analytic", interpret: bool = True,
-            use_pallas: bool = True, shard: Optional[str] = None):
+            policy: PolicySpec = "analytic", use_pallas: bool = True,
+            shard: Optional[str] = None):
         """Registered plan, or None on miss (LRU-touching).  ``shard`` is a
         ``ShardSpec.tag`` and selects the mesh-sharded entry population
         (``ShardedConvPlan``); ``None`` addresses unsharded plans only."""
-        k = self.key(scene, op, policy, interpret, use_pallas, shard)
+        k = self.key(scene, op, policy, use_pallas, shard)
         with self._lock:
             plan = self._mem.get(k)
             if plan is None:
@@ -207,8 +215,7 @@ class PlanRegistry:
             return plan
 
     def put(self, plan) -> str:
-        k = plan_signature(plan.scene, plan.op, plan.policy, plan.interpret,
-                           plan.use_pallas,
+        k = plan_signature(plan.scene, plan.op, plan.policy, plan.use_pallas,
                            shard=getattr(plan, "shard_tag", None))
         with self._lock:
             self._mem[k] = plan
@@ -218,7 +225,7 @@ class PlanRegistry:
 
     def get_or_build(self, scene: ConvScene,
                      op: Union[ConvOp, str] = ConvOp.FPROP, *,
-                     policy: PolicySpec = "analytic", interpret: bool = True,
+                     policy: PolicySpec = "analytic",
                      use_pallas: bool = True) -> ConvPlan:
         """The plan-once entry: registry hit, or ``make_plan`` + register.
         Atomic under the registry lock: concurrent callers racing the same
@@ -228,10 +235,9 @@ class PlanRegistry:
         analytic fallback), so the critical section is bounded by selector
         math — cheap enough that same-key dedup beats per-key locking."""
         with self._lock:
-            plan = self.get(scene, op, policy=policy, interpret=interpret,
-                            use_pallas=use_pallas)
+            plan = self.get(scene, op, policy=policy, use_pallas=use_pallas)
             if plan is None:
-                plan = make_plan(scene, op, policy=policy, interpret=interpret,
+                plan = make_plan(scene, op, policy=policy,
                                  use_pallas=use_pallas)
                 self._c_builds.inc()
                 self.put(plan)
@@ -240,7 +246,7 @@ class PlanRegistry:
     def warm(self, scenes: Iterable[ConvScene],
              ops: Sequence[Union[ConvOp, str]] = (ConvOp.FPROP,),
              buckets: Optional[Sequence[int]] = None, *,
-             policy: PolicySpec = "analytic", interpret: bool = True,
+             policy: PolicySpec = "analytic",
              use_pallas: bool = True) -> int:
         """Pre-build every (scene x op x bucket) plan not already registered;
         returns how many were built.  ``buckets`` rebatches each scene to
@@ -266,7 +272,7 @@ class PlanRegistry:
                     for op in ops:
                         work.append((rebatched, op,
                                      self.key(rebatched, op, policy,
-                                              interpret, use_pallas)))
+                                              use_pallas)))
             if len({k for _, _, k in work}) > self.max_plans:
                 raise ValueError(
                     f"cannot warm {len({k for _, _, k in work})} plans into "
@@ -277,8 +283,7 @@ class PlanRegistry:
             for rebatched, op, k in work:
                 if k not in self._mem:
                     self._mem[k] = make_plan(
-                        rebatched, op, policy=policy, interpret=interpret,
-                        use_pallas=use_pallas)
+                        rebatched, op, policy=policy, use_pallas=use_pallas)
                     self._c_builds.inc()
                     built += 1
                 self._mem.move_to_end(k)
@@ -327,7 +332,6 @@ class PlanRegistry:
     def warmed_buckets(self, scene: ConvScene,
                        op: Union[ConvOp, str] = ConvOp.FPROP, *,
                        policy: PolicySpec = "analytic",
-                       interpret: bool = True,
                        use_pallas: bool = True) -> tuple:
         """Every batch size of ``scene``'s family resident for ``op`` under
         the given build options, ascending.  This is the sub-rung execution
@@ -343,7 +347,6 @@ class PlanRegistry:
         with self._lock:
             for plan in self._mem.values():
                 if (plan.op is op and plan.policy == pol
-                        and plan.interpret == interpret
                         and plan.use_pallas == use_pallas
                         and getattr(plan, "shard_tag", None) is None
                         and plan.scene.with_batch(1) == base):
@@ -383,9 +386,9 @@ class PlanRegistry:
                     on_disk = {}
             except (json.JSONDecodeError, OSError):
                 on_disk = {}   # corrupt artifact: overwrite with our state
-            for k, d in on_disk.items():
-                if k not in plans and valid_plan_dict(d):
-                    plans[k] = d   # drop malformed disk entries on save
+            for d in on_disk.values():
+                if valid_plan_dict(d):   # drop malformed disk entries
+                    plans.setdefault(entry_key(d), d)
         doc = {"schema": _SCHEMA, "version": PLAN_VERSION, "plans": plans}
         os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=os.path.dirname(p) or ".",
@@ -414,6 +417,7 @@ class PlanRegistry:
                 for k, d in doc.get("plans", {}).items():
                     try:
                         plan = plan_from_dict(d)
+                        k = entry_key(d)
                     except (KeyError, TypeError, ValueError) as e:
                         skipped.append((k, e))
                         continue
@@ -450,10 +454,8 @@ def set_default_registry(registry: Optional[PlanRegistry]) -> None:
 
 
 def get_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
-             policy: PolicySpec = "analytic", interpret: bool = True,
-             use_pallas: bool = True,
+             policy: PolicySpec = "analytic", use_pallas: bool = True,
              registry: Optional[PlanRegistry] = None) -> ConvPlan:
     """Plan-once convenience on the default (or given) registry."""
     reg = registry if registry is not None else default_registry()
-    return reg.get_or_build(scene, op, policy=policy, interpret=interpret,
-                            use_pallas=use_pallas)
+    return reg.get_or_build(scene, op, policy=policy, use_pallas=use_pallas)

@@ -223,7 +223,7 @@ class ConvServer:
     """
 
     def __init__(self, *, registry: Optional[PlanRegistry] = None,
-                 policy: PolicySpec = "analytic", interpret: bool = True,
+                 policy: PolicySpec = "analytic",
                  use_pallas: bool = True, max_batch: int = 32,
                  min_bucket: int = 1, ladder_slack: float = 1.15,
                  cost_model: Optional[CostModel] = None, strict: bool = False,
@@ -247,7 +247,6 @@ class ConvServer:
         # at prewarm so steady state never re-runs the joint selector
         self._shard_tags: Dict[Tuple[str, ConvOp, int], str] = {}
         self.policy = policy
-        self.interpret = interpret
         self.use_pallas = use_pallas
         self.max_batch = max_batch
         self.min_bucket = min_bucket
@@ -335,8 +334,7 @@ class ConvServer:
             else:
                 built += self.registry.warm(
                     [fam.base], ops=fam.ops, buckets=fam.ladder,
-                    policy=self.policy, interpret=self.interpret,
-                    use_pallas=self.use_pallas)
+                    policy=self.policy, use_pallas=self.use_pallas)
         if compile:
             for fam in families:
                 for op, bucket in itertools.product(fam.ops, fam.ladder):
@@ -440,8 +438,7 @@ class ConvServer:
             for bucket in fam.ladder:
                 plan = self._build_sharded(fam.base.with_batch(bucket), op)
                 k = self.registry.key(plan.scene, op, self.policy,
-                                      self.interpret, self.use_pallas,
-                                      shard=plan.shard_tag)
+                                      self.use_pallas, shard=plan.shard_tag)
                 if k not in self.registry:
                     built += 1
                 self.registry.put(plan)
@@ -452,7 +449,6 @@ class ConvServer:
     def _build_sharded(self, scene: ConvScene, op: ConvOp):
         from repro.shard.plan import make_sharded_plan
         return make_sharded_plan(scene, op, policy=self.policy,
-                                 interpret=self.interpret,
                                  devices=self._ring, axes=("batch",),
                                  model=self.cost_model)
 
@@ -462,12 +458,10 @@ class ConvServer:
             with self._lock:
                 tag = self._shard_tags.get((fam.layer, op, bucket))
             plan = (self.registry.get(scene, op, policy=self.policy,
-                                      interpret=self.interpret,
                                       use_pallas=self.use_pallas, shard=tag)
                     if tag else None)
         else:
             plan = self.registry.get(scene, op, policy=self.policy,
-                                     interpret=self.interpret,
                                      use_pallas=self.use_pallas)
         if plan is None:
             self._c_plan_misses.inc()
@@ -484,7 +478,6 @@ class ConvServer:
                     self._shard_tags[(fam.layer, op, bucket)] = plan.shard_tag
             else:
                 plan = make_plan(scene, op, policy=self.policy,
-                                 interpret=self.interpret,
                                  use_pallas=self.use_pallas)
             self.registry.put(plan)
             self._c_plan_builds.inc()

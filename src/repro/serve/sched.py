@@ -277,13 +277,12 @@ class ConvScheduler(ConvServer):
                                   min_bucket=self.min_bucket, slack=0.0)
             built += self.registry.warm(
                 [fam.base], ops=fam.ops, buckets=rungs,
-                policy=self.policy, interpret=self.interpret,
-                use_pallas=self.use_pallas)
+                policy=self.policy, use_pallas=self.use_pallas)
             for op in fam.ops:
                 for b in rungs:
                     plan = self.registry.get(
                         fam.base.with_batch(b), op, policy=self.policy,
-                        interpret=self.interpret, use_pallas=self.use_pallas)
+                        use_pallas=self.use_pallas)
                     self._pred_s[(fam.layer, op, b)] = plan.predicted_s or 0.0
             with self._lock:
                 self._flush_rungs[fam.layer] = rungs

@@ -65,7 +65,6 @@ class ShardedTrainingPlans:
 
 
 def make_sharded_training_plans(scene: ConvScene, *, policy: str = "analytic",
-                                interpret: bool = True,
                                 devices: Optional[Sequence] = None,
                                 max_shards: Optional[int] = None,
                                 axes: Sequence[str] = PARTITION_AXES,
@@ -79,8 +78,8 @@ def make_sharded_training_plans(scene: ConvScene, *, policy: str = "analytic",
     exist (``grad_*_scene`` raises) get the unsharded plan's reference
     route instead.
     """
-    kw = dict(policy=policy, interpret=interpret, devices=devices,
-              max_shards=max_shards, axes=axes, model=model)
+    kw = dict(policy=policy, devices=devices, max_shards=max_shards,
+              axes=axes, model=model)
 
     def build(op: ConvOp) -> AnyPlan:
         try:
@@ -88,8 +87,7 @@ def make_sharded_training_plans(scene: ConvScene, *, policy: str = "analytic",
         except ValueError:
             # no MG3M exec scene for this direction: unsharded fallback
             # (make_plan routes it to the jnp reference and records why)
-            return make_plan(scene, op, policy="analytic",
-                             interpret=interpret)
+            return make_plan(scene, op, policy="analytic")
 
     return ShardedTrainingPlans(
         fprop=make_sharded_plan(scene, ConvOp.FPROP, **kw),

@@ -3,9 +3,14 @@
 ``ShardedConvPlan`` is the mesh-aware sibling of ``plan.build.ConvPlan``:
 same frozen plan-once / execute-many contract, same global-array
 ``execute(a, b)`` signature and op semantics, but the dispatch runs the
-per-shard ``ConvPlan`` under ``jax.experimental.shard_map`` on a 1-D
-``("shard",)`` device ring, with ``jax.lax`` collectives wired per
-partition axis:
+per-shard ``ConvPlan`` under ``jax.shard_map`` over the plan's whole
+device pool, laid out as ``("shard", "rep")``: ``n_shards`` partitions,
+each replicated on ``len(pool) // n_shards`` devices (the ``n_shards == 1``
+fallback runs replicated on every device).  Every plan built for one pool
+therefore shares one device assignment, which a jitted model needs: a
+Mosaic kernel cannot be partitioned automatically, so an unwrapped plan,
+or one on a sub-ring, cannot sit in a program over the pool.  The
+``jax.lax`` collectives are wired per partition axis:
 
   batch / oc   pure data decomposition over independent GEMM columns /
                rows — no collective, bitwise-identical (f32) to the
@@ -45,7 +50,6 @@ from typing import Optional, Sequence, Tuple, Union
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.mapping import (SHARD_LAUNCH_OVERHEAD_S, SCHEDULES,
@@ -62,8 +66,8 @@ from repro.shard.spec import (PARTITION_AXES, UNSHARDED_AXIS, ShardSpec,
                               halo_geometry, select_shard_spec,
                               shard_sub_scene)
 
-#: shard_map needs check_rep=False: pallas_call has no replication rule.
-_SHMAP = functools.partial(shard_map, check_rep=False)
+#: check_vma=False: pallas_call has no varying-manual-axes rule.
+_SHMAP = functools.partial(jax.shard_map, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,11 +84,10 @@ class ShardedConvPlan:
     scene: ConvScene                  # the *forward* scene the plan serves
     op: ConvOp
     policy: str                       # canonical tag (requested policy)
-    interpret: bool
     spec: ShardSpec
     inner: ConvPlan                   # fprop-form plan over spec.sub_scene
     exec_scene: ConvScene             # the full (unpartitioned) exec scene
-    devices: Tuple[object, ...]       # the shard ring, len == spec.n_shards
+    devices: Tuple[object, ...]       # the pool; spec.n_shards divides it
     out_hw: Tuple[int, int] = (0, 0)  # wgrad spatial slice-back (0,0 = none)
 
     # -- execution ---------------------------------------------------------
@@ -116,18 +119,22 @@ class ShardedConvPlan:
     # -- the sharded executable (built once, cached on the frozen plan) ----
     @functools.cached_property
     def _mesh(self) -> Mesh:
-        return Mesh(np.asarray(self.devices), ("shard",))
+        n = self.spec.n_shards
+        return Mesh(np.asarray(self.devices).reshape(n, -1), ("shard", "rep"))
 
     @functools.cached_property
     def _runner(self):
         """Jitted global-array fprop-form executor for the exec scene."""
         spec, E, inner = self.spec, self.exec_scene, self.inner
         n, sub = spec.n_shards, spec.sub_scene
-        if n == 1:
+        if len(self.devices) == 1:
             return inner.execute
         mesh = self._mesh
 
-        if spec.axis == "batch":
+        if n == 1:
+            fn = _SHMAP(inner.execute, mesh=mesh, in_specs=(P(), P()),
+                        out_specs=P())
+        elif spec.axis == "batch":
             nb = n * sub.B
 
             def fn(a, b):
@@ -283,7 +290,6 @@ def _allowed_schedules(tag: str) -> Tuple[str, ...]:
 
 def make_sharded_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP,
                       *, policy: PolicySpec = "analytic",
-                      interpret: bool = True,
                       devices: Optional[Sequence] = None,
                       max_shards: Optional[int] = None,
                       axes: Sequence[str] = PARTITION_AXES,
@@ -293,8 +299,10 @@ def make_sharded_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP,
     (partition x grain) jointly (``select_shard_spec``), build the
     per-shard fprop-form plan with its choice pinned.
 
-    ``devices`` is the shard ring pool (default: all local devices);
-    ``max_shards`` additionally caps the ring (default: the pool size).
+    ``devices`` is the device pool (default: all local devices);
+    ``max_shards`` additionally caps it (default: the pool size).  The plan
+    runs on the first multiple of ``n_shards`` devices of the capped pool —
+    all of it for every count the selector proposes.
     ``axes`` restricts the candidate partitions — ``("batch",)`` is the
     serving layer's data-parallel mode.  ``spec`` pins a partition exactly
     (the registry's reload path and the tests' "force a partition" knob);
@@ -325,18 +333,19 @@ def make_sharded_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP,
                                      allowed=allowed, model=model)
         else:
             _validate_spec(spec, exec_scene, len(devs))
-        inner = make_plan(spec.sub_scene, ConvOp.FPROP, policy=spec.choice,
-                          interpret=interpret)
+        inner = make_plan(spec.sub_scene, ConvOp.FPROP, policy=spec.choice)
         m = default_metrics()
         m.counter("repro.shard.plans").inc()
         if not spec.is_sharded:
             m.counter("repro.shard.fallbacks").inc()
         m.histogram("repro.shard.plan_build_s").observe(
             time.perf_counter() - t0)
+        n = spec.n_shards
         return ShardedConvPlan(scene=scene, op=op, policy=tag,
-                               interpret=interpret, spec=spec, inner=inner,
+                               spec=spec, inner=inner,
                                exec_scene=exec_scene,
-                               devices=devs[:spec.n_shards], out_hw=out_hw)
+                               devices=devs[:max(cap - cap % n, n)],
+                               out_hw=out_hw)
 
 
 def _validate_spec(spec: ShardSpec, exec_scene: ConvScene,
@@ -377,7 +386,7 @@ def pinned_shard_spec(scene: ConvScene, op: Union[ConvOp, str], axis: str,
 
 def assemble_sharded_plan(scene: ConvScene, op: Union[ConvOp, str],
                           policy: str, axis: str, n_shards: int,
-                          choice: ScheduleChoice, *, interpret: bool = True,
+                          choice: ScheduleChoice, *,
                           devices: Optional[Sequence] = None
                           ) -> ShardedConvPlan:
     """Rebuild a sharded plan from stored identity without re-running the
@@ -385,5 +394,5 @@ def assemble_sharded_plan(scene: ConvScene, op: Union[ConvOp, str],
     when the process has fewer devices than the stored ring — the loader
     skips such entries the way it skips any stale plan."""
     spec = pinned_shard_spec(scene, op, axis, n_shards, choice)
-    return make_sharded_plan(scene, op, policy=policy, interpret=interpret,
-                             devices=devices, spec=spec)
+    return make_sharded_plan(scene, op, policy=policy, devices=devices,
+                             spec=spec)

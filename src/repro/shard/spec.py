@@ -235,15 +235,16 @@ def collective_seconds(scene: ConvScene, axis: str, n: int) -> float:
 # joint grain x partition selection
 # --------------------------------------------------------------------------
 def _shard_counts(max_shards: int) -> Tuple[int, ...]:
-    """Candidate shard counts: powers of two up to ``max_shards``, plus
-    ``max_shards`` itself (a 6-chip ring is a legal partition)."""
-    counts = []
+    """Candidate shard counts: the powers of two that divide
+    ``max_shards``, plus ``max_shards`` itself (a 6-chip ring is a legal
+    partition).  Each divides the ring, so a plan's shards, replicated,
+    tile the whole ring (``ShardedConvPlan``)."""
+    counts = {max_shards} if max_shards >= 2 else set()
     n = 2
     while n <= max_shards:
-        counts.append(n)
+        if max_shards % n == 0:
+            counts.add(n)
         n *= 2
-    if max_shards >= 2 and max_shards not in counts:
-        counts.append(max_shards)
     return tuple(sorted(counts))
 
 

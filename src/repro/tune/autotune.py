@@ -92,7 +92,7 @@ def _predicted_us(scene: ConvScene, choice: ScheduleChoice) -> float:
 def autotune_scene(scene: ConvScene, *,
                    cache: Optional[cache_mod.ScheduleCache] = None,
                    top_k: int = 4, iters: int = 3, warmup: int = 1,
-                   interpret: bool = True, timeout_s: float = 120.0,
+                   timeout_s: float = 120.0,
                    measure_batch: Optional[int] = None,
                    measure_max_ch: Optional[int] = None,
                    measure_max_hw: Optional[int] = None,
@@ -104,7 +104,7 @@ def autotune_scene(scene: ConvScene, *,
     timings); the default measures through ``ops.mg3m_conv_op``.
     """
     cache = cache if cache is not None else cache_mod.default_cache()
-    backend = cache_mod.default_backend(interpret)
+    backend = cache_mod.default_backend()
     if not force:
         rec = cache.get(scene, backend)
         if rec is not None:
@@ -123,7 +123,7 @@ def autotune_scene(scene: ConvScene, *,
                  "inH": msc.inH, "inW": msc.inW}
     if measure_fn is None:
         measure_fn = lambda s, c: measure_mod.measure_choice(
-            s, c, interpret=interpret, iters=iters, warmup=warmup,
+            s, c, iters=iters, warmup=warmup,
             timeout_s=timeout_s)
 
     # The kernel wrapper clips blocks to the measurement scene's dims, so on
@@ -183,15 +183,15 @@ def autotune_scene(scene: ConvScene, *,
 
 
 def resolve_schedule(scene: ConvScene, *,
-                     cache: Optional[cache_mod.ScheduleCache] = None,
-                     interpret: bool = True) -> ScheduleChoice:
+                     cache: Optional[cache_mod.ScheduleCache] = None
+                     ) -> ScheduleChoice:
     """``schedule="auto"`` resolution: tuned cache first; on a miss, select
     under the active cost model (calibrated when an artifact exists — see
     ``tune/calibrate.py`` — else the analytic roofline).
 
     Never measures — the hot path must not block on a tuning run."""
     cache = cache if cache is not None else cache_mod.default_cache()
-    choice = cache.get_choice(scene, cache_mod.default_backend(interpret))
+    choice = cache.get_choice(scene, cache_mod.default_backend())
     if choice is not None:
         return choice
     from repro.tune import calibrate as calibrate_mod  # local: import order

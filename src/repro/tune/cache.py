@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core.mapping import SCHEDULES, ScheduleChoice
 from repro.core.scene import ConvScene
+from repro.kernels import interpret_mode
 from repro.obs.metrics import default_metrics
 from repro.obs.trace import default_tracer
 
@@ -45,11 +46,12 @@ def resolve_cache_path(path: Optional[str] = None) -> str:
     return os.path.abspath(os.path.expanduser(p))
 
 
-def default_backend(interpret: bool = True) -> str:
-    """Backend tag for cache keys: timings on CPU-interpret are not timings
-    on a real TPU, so they must never alias."""
+def default_backend() -> str:
+    """Backend tag for cache keys, derived like the kernel mode: timings
+    in the Pallas interpreter are not timings on a real TPU, so they must
+    never alias."""
     base = jax.default_backend()
-    return f"{base}+interpret" if interpret else base
+    return f"{base}+interpret" if interpret_mode() else base
 
 
 def scene_signature(scene: ConvScene, *, backend: str,

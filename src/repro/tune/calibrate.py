@@ -29,7 +29,7 @@ Honesty caveats, recorded rather than hidden: proxy-capped measurements
 calibrate the model *at the measured proxy geometry* (class bands are
 computed on the measurement scene), and CPU-interpret µs calibrate a model of
 the interpreter, not of a TPU — fit per backend (``backend=`` filter) and
-re-fit after tuning with ``--no-interpret`` on real hardware.
+re-fit after tuning on real hardware.
 """
 from __future__ import annotations
 
@@ -405,14 +405,15 @@ def set_active_cost_model(model: Optional[CostModel]) -> None:
 def active_cost_model() -> CostModel:
     """Cost model for selection right now: explicitly-installed model, else
     the calibration artifact at the resolved path (auto-reloaded when its
-    mtime changes), else the uncalibrated roofline default."""
+    mtime changes), else the uncalibrated roofline of this chip
+    (``mapping.device_cost_model``)."""
     if _active is not None:
         return _active
     p = resolve_calibration_path()
     try:
         mtime = os.path.getmtime(p)
     except OSError:
-        return mapping.DEFAULT_COST_MODEL
+        return mapping.device_cost_model()
     cached = _autoload.get(p)
     if cached is None or cached[0] != mtime:
         model: Optional[CostModel] = None
@@ -425,4 +426,5 @@ def active_cost_model() -> CostModel:
                   file=sys.stderr)
         _autoload[p] = (mtime, model)
         cached = _autoload[p]
-    return cached[1] if cached[1] is not None else mapping.DEFAULT_COST_MODEL
+    return (cached[1] if cached[1] is not None
+            else mapping.device_cost_model())

@@ -1,10 +1,10 @@
 """Measurement harness: wall-clock one candidate schedule through the real
 ``ops.mg3m_conv_op`` dispatch.
 
-Honesty conventions follow ``benchmarks/common.py``: on this container the
-kernels run in Pallas interpret mode on CPU, so absolute µs validate
-*relative* candidate ordering, not TPU truth; on a real TPU pass
-``interpret=False`` and the same harness times compiled kernels.  Proxy mode
+Honesty conventions follow ``benchmarks/common.py``: off a TPU the kernels
+run in the Pallas interpreter, so absolute µs validate *relative*
+candidate ordering, not TPU truth; on a TPU the same harness times
+compiled kernels (the kernel mode follows the platform).  Proxy mode
 (channel/batch/spatial caps) measures a shrunken stand-in of the scene —
 every use is recorded in the tuned artifact, never silent.
 """
@@ -68,7 +68,7 @@ def make_operands(scene: ConvScene, seed: int = 0):
 
 
 def measure_choice(scene: ConvScene, choice: ScheduleChoice, *,
-                   interpret: bool = True, iters: int = 3, warmup: int = 1,
+                   iters: int = 3, warmup: int = 1,
                    timeout_s: float = DEFAULT_TIMEOUT_S) -> float:
     """Median wall-time (µs) of ``mg3m_conv_op`` pinned to ``choice``.
 
@@ -91,8 +91,7 @@ def measure_choice(scene: ConvScene, choice: ScheduleChoice, *,
                                scene=scene.describe()) as sp:
         t0 = time.perf_counter()
         try:
-            fn = lambda: ops.mg3m_conv_op(inp, flt, scene, schedule=choice,
-                                          interpret=interpret)
+            fn = lambda: ops.mg3m_conv_op(inp, flt, scene, schedule=choice)
             for _ in range(max(warmup, 1)):
                 jax.block_until_ready(fn())
                 if time.perf_counter() - t0 > timeout_s:

@@ -42,7 +42,7 @@ def test_verified_point_matches_reference(sc, data):
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
     choice = ScheduleChoice(pt.schedule, pt.bm, pt.bn, pt.bk,
                             0.0, 0.0, 0.0, 0)
-    got = mg3m_conv(inp, flt, sc, schedule=choice, interpret=True)
+    got = mg3m_conv(inp, flt, sc, schedule=choice)
     want = ref.conv_ref(inp, flt, sc)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)

@@ -68,6 +68,24 @@ def test_default_model_matches_legacy_constants():
     assert not m.is_calibrated
 
 
+@pytest.mark.parametrize("backend,kind,known", [
+    ("cpu", "cpu", True),                 # off a TPU: the v5e target
+    ("tpu", "TPU v5 lite", True),
+    ("tpu", "TPU v9 imaginary", False),   # unknown chip: error, no default
+])
+def test_device_cost_model_keyed_by_device_kind(monkeypatch, backend, kind,
+                                                known):
+    import types
+    monkeypatch.setattr(mapping.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(mapping.jax, "devices",
+                        lambda: [types.SimpleNamespace(device_kind=kind)])
+    if known:
+        assert mapping.device_cost_model() is mapping.DEFAULT_COST_MODEL
+    else:
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            mapping.device_cost_model()
+
+
 def test_score_with_default_model_is_identity():
     sc = scene_grid()[0]
     for pt in tune.enumerate_space(sc):
@@ -120,7 +138,8 @@ def test_samples_reconstruct_measurement_scene(tuned_cache):
 
 
 def test_samples_respect_backend_filter(tuned_cache):
-    be = tune.default_backend(True)
+    be = tune.default_backend()
+    assert be == "cpu+interpret", "off a TPU the tag follows the kernel mode"
     samples, _ = tune.samples_from_cache(tuned_cache, backend=be)
     assert samples
     none, skipped = tune.samples_from_cache(tuned_cache, backend="tpu")

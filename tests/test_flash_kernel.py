@@ -37,7 +37,7 @@ def test_flash_kernel_matches_naive(shape, causal):
     k = jax.random.normal(ks[1], (b, t, hkv, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, t, hkv, d), jnp.float32)
     got = flash_attention_bshd(q, k, v, causal=causal, block_q=32,
-                               block_k=32, interpret=True)
+                               block_k=32)
     want = _naive(q, k, v, causal)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
@@ -48,8 +48,7 @@ def test_flash_kernel_matches_jnp_flash():
     q = jax.random.normal(ks[0], (2, 128, 8, 32), jnp.float32)
     k = jax.random.normal(ks[1], (2, 128, 2, 32), jnp.float32)
     v = jax.random.normal(ks[2], (2, 128, 2, 32), jnp.float32)
-    got = flash_attention_bshd(q, k, v, causal=True, block_q=32, block_k=64,
-                               interpret=True)
+    got = flash_attention_bshd(q, k, v, causal=True, block_q=32, block_k=64)
     want = flash_jnp(q, k, v, causal=True, q_chunk=32, kv_chunk=32)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
@@ -59,8 +58,7 @@ def test_flash_kernel_bf16():
     q = jax.random.normal(ks[0], (1, 64, 4, 32), jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 64, 4, 32), jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 64, 4, 32), jnp.bfloat16)
-    got = flash_attention_bshd(q, k, v, causal=True, block_q=32, block_k=32,
-                               interpret=True)
+    got = flash_attention_bshd(q, k, v, causal=True, block_q=32, block_k=32)
     want = _naive(q.astype(jnp.float32), k.astype(jnp.float32),
                   v.astype(jnp.float32), True)
     np.testing.assert_allclose(got.astype(np.float32), want, rtol=2e-2,

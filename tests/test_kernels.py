@@ -35,7 +35,7 @@ def test_mg3m_conv_schedules_match_oracle(spec, schedule):
     inp = jax.random.normal(k1, sc.in_shape(), jnp.float32)
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
     want = ref.conv_ref(inp, flt, sc)
-    got = mg3m_conv(inp, flt, sc, schedule=schedule, interpret=True)
+    got = mg3m_conv(inp, flt, sc, schedule=schedule)
     # fp32 accumulation order differs between the Pallas grid walk and the
     # lax oracle; spec2 (K=32*25 taps) lands ~9e-5 relative on one element.
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
@@ -47,7 +47,7 @@ def test_mg3m_conv_autoselect(spec):
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     inp = jax.random.normal(k1, sc.in_shape(), jnp.float32)
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
-    got = mg3m_conv(inp, flt, sc, interpret=True)
+    got = mg3m_conv(inp, flt, sc)
     np.testing.assert_allclose(got, ref.conv_ref(inp, flt, sc),
                                rtol=3e-5, atol=3e-5)
 
@@ -57,7 +57,7 @@ def test_mg3m_conv_bf16():
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
     inp = jax.random.normal(k1, sc.in_shape(), jnp.bfloat16)
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.bfloat16)
-    got = mg3m_conv(inp, flt, sc, schedule="TB88", interpret=True)
+    got = mg3m_conv(inp, flt, sc, schedule="TB88")
     want = ref.conv_ref(inp, flt, sc)
     np.testing.assert_allclose(got.astype(np.float32),
                                want.astype(np.float32), rtol=2e-2, atol=2e-2)
@@ -77,7 +77,7 @@ def test_conv_ref_matches_direct_loop():
 def test_nhwc_wrapper_roundtrip():
     x = jax.random.normal(jax.random.PRNGKey(3), (4, 9, 9, 6))
     w = jax.random.normal(jax.random.PRNGKey(4), (3, 3, 6, 10))
-    got = mg3m_conv_nhwc(x, w, stride=(2, 2), padding=(1, 1), interpret=True)
+    got = mg3m_conv_nhwc(x, w, stride=(2, 2), padding=(1, 1))
     dn = jax.lax.conv_dimension_numbers(x.shape, w.shape,
                                         ("NHWC", "HWIO", "NHWC"))
     want = jax.lax.conv_general_dilated(x, w, (2, 2), ((1, 1), (1, 1)),
@@ -93,7 +93,7 @@ def test_causal_conv1d_matches_oracle(shape):
     k1, k2 = jax.random.split(jax.random.PRNGKey(l * d))
     x = jax.random.normal(k1, (b, l, d), jnp.float32)
     w = jax.random.normal(k2, (k, d), jnp.float32)
-    got = causal_conv1d_op(x, w, block_l=16, block_d=8, interpret=True)
+    got = causal_conv1d_op(x, w, block_l=16, block_d=8)
     want = ref.causal_conv1d_ref(x, w)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -103,8 +103,40 @@ def test_causal_conv1d_is_causal():
     k1, k2 = jax.random.split(jax.random.PRNGKey(5))
     x = jax.random.normal(k1, (1, 32, 8), jnp.float32)
     w = jax.random.normal(k2, (4, 8), jnp.float32)
-    y1 = causal_conv1d_op(x, w, block_l=8, block_d=8, interpret=True)
+    y1 = causal_conv1d_op(x, w, block_l=8, block_d=8)
     x2 = x.at[:, 20].add(100.0)
-    y2 = causal_conv1d_op(x2, w, block_l=8, block_d=8, interpret=True)
+    y2 = causal_conv1d_op(x2, w, block_l=8, block_d=8)
     np.testing.assert_allclose(y1[:, :20], y2[:, :20], rtol=1e-6, atol=1e-6)
     assert not np.allclose(y1[:, 20:], y2[:, 20:])
+
+
+def _dot_precisions(jaxpr):
+    """``precision`` of every dot_general in a jaxpr, nested ones included
+    (the Pallas kernel body is a jaxpr parameter of ``pallas_call``)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                found += _dot_precisions(inner)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("schedule", ["TB11", "TB18", "TB88"])
+def test_kernel_dot_precision_follows_dtype(schedule, dtype):
+    """An f32 scene contracts at f32 precision on the MXU whatever the
+    enclosing default (Mosaic's own default rounds f32 products to bf16);
+    a bf16 scene keeps the default."""
+    sc = _scene(*SCENES[0], dtype=dtype)
+    x = jnp.zeros(sc.in_shape(), dtype)
+    w = jnp.zeros(sc.flt_shape(), dtype)
+    with jax.default_matmul_precision("bfloat16"):
+        jaxpr = jax.make_jaxpr(
+            lambda a, b: mg3m_conv(a, b, sc, schedule=schedule))(x, w)
+    precs = _dot_precisions(jaxpr.jaxpr)
+    assert precs
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    assert all((p == highest) == (dtype == "float32") for p in precs), precs

@@ -380,4 +380,73 @@ def test_plans_are_frozen_and_hashable():
     plan = make_plan(sc)
     hash(plan)   # jit-stability requires hashable static plans
     with pytest.raises(dataclasses.FrozenInstanceError):
-        plan.interpret = False
+        plan.use_pallas = False
+
+
+# -- kernel mode derived from the platform -----------------------------------
+def test_kernel_mode_follows_platform(monkeypatch):
+    from repro.kernels import interpret_mode
+    assert interpret_mode() is True            # the CPU runs the interpreter
+    assert interpret_mode(False) is False      # tests can force a compile
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    assert cache_mod.default_backend() == "tpu"
+
+
+def _entry_points():
+    from repro.core import autodiff, conv
+    from repro.serve.conv import ConvServer
+    from repro.shard import autodiff as shard_autodiff
+    from repro.shard import plan as shard_plan
+    from repro.tune import autotune, measure
+    return {
+        "make_plan": make_plan, "assemble_plan": build_mod.assemble_plan,
+        "resolve_policy": build_mod.resolve_policy,
+        "registry.get": PlanRegistry.get,
+        "registry.get_or_build": PlanRegistry.get_or_build,
+        "registry.warm": PlanRegistry.warm, "get_plan": get_plan,
+        "ConvServer": ConvServer, "mg3m_conv": conv.mg3m_conv,
+        "mg3m_conv_op": ops.mg3m_conv_op,
+        "causal_conv1d_op": ops.causal_conv1d_op,
+        "make_model_plans": autodiff.make_model_plans,
+        "make_sharded_plan": shard_plan.make_sharded_plan,
+        "make_sharded_training_plans":
+            shard_autodiff.make_sharded_training_plans,
+        "autotune_scene": autotune.autotune_scene,
+        "measure_choice": measure.measure_choice,
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_entry_points()))
+def test_no_interpret_option_above_the_kernels(name):
+    import inspect
+    assert "interpret" not in inspect.signature(
+        _entry_points()[name]).parameters
+
+
+def test_registry_loads_artifact_with_interpret_field(tmp_path):
+    """An artifact written when plans carried the kernel mode (``|int=``
+    in the key, ``interpret`` in the entry) still loads, serves its plans
+    under today's keys, and is rewritten without the field."""
+    import json
+    sc = _scene(*SCENES["plain"])
+    reg = PlanRegistry()
+    plan = reg.get_or_build(sc)
+    path = str(tmp_path / "old.json")
+    reg.save(path)
+    with open(path) as f:
+        doc = json.load(f)
+    (key, entry), = doc["plans"].items()
+    old_key = key.replace("|pl=", "|int=1|pl=")
+    doc["plans"] = {old_key: dict(entry, interpret=True)}
+    with open(path, "w") as f:
+        json.dump(doc, f)
+    fresh = PlanRegistry()
+    assert fresh.load(path) == 1
+    got = fresh.get(sc)
+    assert got is not None and got.choice == plan.choice
+    fresh.save(path)
+    with open(path) as f:
+        saved = json.load(f)["plans"]
+    assert list(saved) == [key]
+    assert "interpret" not in saved[key]

@@ -167,9 +167,8 @@ def test_selector_respects_axis_restriction():
 
 
 def test_plan_signature_shard_fragment():
-    base = plan_signature(SC, ConvOp.FPROP, "analytic", True, True)
-    tagged = plan_signature(SC, ConvOp.FPROP, "analytic", True, True,
-                            shard="h:8")
+    base = plan_signature(SC, ConvOp.FPROP, "analytic", True)
+    tagged = plan_signature(SC, ConvOp.FPROP, "analytic", True, shard="h:8")
     assert tagged == base + "|shard=h:8"
 
 
@@ -344,3 +343,23 @@ def test_conv_server_mesh_mode_parity_and_zero_resolution():
     st = mesh_srv.stats(since=snap)
     assert st["plan_misses"] == 0 and st["plan_builds"] == 0
     assert st["dispatches"] >= 1
+
+
+def test_shard_counts_divide_the_ring():
+    """Every candidate count divides the ring, so a plan's replicated
+    shards tile the whole pool and plans of one pool share its devices."""
+    from repro.shard.spec import _shard_counts
+    assert _shard_counts(1) == ()
+    assert _shard_counts(4) == (2, 4)
+    assert _shard_counts(8) == (2, 4, 8)
+    assert _shard_counts(6) == (2, 6)
+
+
+@need8
+def test_sub_ring_partition_runs_on_the_whole_pool():
+    plan = _pinned_plan(SC, ConvOp.FPROP, "batch", 2)
+    assert len(plan.devices) == jax.device_count()
+    a, b = _rand_io(SC, ConvOp.FPROP)
+    np.testing.assert_array_equal(
+        np.asarray(plan.execute(a, b)),
+        np.asarray(make_plan(SC, ConvOp.FPROP).execute(a, b)))

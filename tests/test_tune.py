@@ -325,7 +325,7 @@ def test_measure_timeout_enforced_during_warmup(monkeypatch):
 
     calls = []
 
-    def slow_op(inp, flt, scene, schedule=None, interpret=True):
+    def slow_op(inp, flt, scene, schedule=None):
         calls.append(1)
         time.sleep(0.05)
         import jax.numpy as jnp
@@ -400,8 +400,7 @@ def test_mg3m_conv_never_silently_substitutes_forced_schedule():
     from repro.core.conv import mg3m_conv
     inp, flt = tune.make_operands(BIG_TB11_INFEASIBLE)
     with pytest.raises(ValueError, match="TB11"):
-        mg3m_conv(inp, flt, BIG_TB11_INFEASIBLE, schedule="TB11",
-                  interpret=True)
+        mg3m_conv(inp, flt, BIG_TB11_INFEASIBLE, schedule="TB11")
 
 
 def test_forced_feasible_schedule_still_honored():
@@ -442,7 +441,7 @@ def test_mg3m_conv_auto_matches_oracle(fresh_default_cache):
     hits0 = cache.hits
     from repro.core.conv import mg3m_conv
     inp, flt = tune.make_operands(SC)
-    got = mg3m_conv(inp, flt, SC, schedule="auto", interpret=True)
+    got = mg3m_conv(inp, flt, SC, schedule="auto")
     np.testing.assert_allclose(got, ref.conv_ref(inp, flt, SC),
                                rtol=3e-5, atol=3e-5)
     assert cache.hits == hits0 + 1
@@ -452,7 +451,7 @@ def test_mg3m_conv_accepts_explicit_choice():
     choice = tune.ranked_space(SC)[-1]   # worst-predicted, still feasible
     from repro.core.conv import mg3m_conv
     inp, flt = tune.make_operands(SC)
-    got = mg3m_conv(inp, flt, SC, schedule=choice, interpret=True)
+    got = mg3m_conv(inp, flt, SC, schedule=choice)
     np.testing.assert_allclose(got, ref.conv_ref(inp, flt, SC),
                                rtol=3e-5, atol=3e-5)
 
