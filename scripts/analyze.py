@@ -14,8 +14,8 @@ Two gates, both exit-1 on any finding:
            sentinel taps, VMEM budget, dtype promotion, MAC agreement —
            without executing a single kernel.
   lint     ``repro.analysis.lint`` over ``src/repro`` — public asserts,
-           metric-name namespace, traced-disabled hot-path allocations,
-           bare/unreviewed broad excepts.
+           metric-name namespace, computed span args on the serving/plan
+           hot path, bare/unreviewed broad excepts.
 
 The verifier sweep caches per-(scene, op) clean verdicts keyed by a
 digest of the verifier-relevant sources, so an unchanged tree re-checks

@@ -1,18 +1,25 @@
 """Render an observability artifact as a human (or machine) report.
 
-Reads either artifact the obs layer writes and prints what an operator asks
-of the serving/tuning stack first — latency quantiles, occupancy, padding
-waste, cost-model drift:
+Reads any artifact the obs layer or the profiler writes and prints what an
+operator asks of the serving/tuning stack first — latency quantiles,
+occupancy, padding waste, cost-model drift, and what the host did while
+the device idled:
 
   metrics dump   ``MetricRegistry.dump(path)`` JSON ({"kind": "repro-obs"}),
                  optionally carrying a drift-monitor snapshot under "drift";
   trace export   ``Tracer.export(path)`` Chrome trace-event JSON
-                 ({"traceEvents": [...]}) — per-span-name duration stats.
+                 ({"traceEvents": [...]}) — per-span-name duration stats;
+  profiler trace a ``jax.profiler`` ``.xplane.pb`` (or a directory holding
+                 one, e.g. ``bench/run.py --trace-dir``) — device busy and
+                 idle time over the ``bench.window`` span (else the extent
+                 of the device's ops), every idle interval split by the
+                 innermost ``repro.*`` span open at that instant.
 
 Usage:
 
     PYTHONPATH=src python scripts/obsreport.py metrics.json
     PYTHONPATH=src python scripts/obsreport.py trace.json --json
+    python scripts/obsreport.py <trace-dir or .xplane.pb>
 
 ``--json`` emits the computed report as one JSON document instead of text
 (the same numbers, for CI assertions and dashboards).
@@ -20,13 +27,17 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
 import os
 import sys
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+sys.path.insert(0, os.path.join(ROOT, "src"))
+sys.path.insert(1, ROOT)
 
+from bench import trace_reduce                         # noqa: E402
 from repro.obs.metrics import summarize_histogram      # noqa: E402
 
 
@@ -96,9 +107,8 @@ def metrics_report(doc: Dict) -> Dict:
             },
         }
         for label, name in (("queue_wait", "repro.serve.queue_wait_s"),
-                            ("dispatch", "repro.serve.dispatch_s"),
-                            ("layer_dispatch",
-                             "repro.serve.layer_dispatch_s"),
+                            ("enqueue", "repro.serve.enqueue_s"),
+                            ("poll", "repro.serve.poll_s"),
                             ("deadline_slack",
                              "repro.serve.deadline_slack_s")):
             if name in h:
@@ -153,8 +163,7 @@ def print_metrics_report(report: Dict) -> None:
         print(f"  flushes: deadline={fl['deadline']:.0f} "
               f"occupancy={fl['occupancy']:.0f} "
               f"gather_timeout={fl['gather_timeout']:.0f}")
-        for label in ("queue_wait", "dispatch", "layer_dispatch",
-                      "deadline_slack"):
+        for label in ("queue_wait", "enqueue", "poll", "deadline_slack"):
             if label in s:
                 q = s[label]
                 print(f"  {label:<16} n={q['count']:<6.0f} "
@@ -243,6 +252,144 @@ def print_trace_report(report: Dict) -> None:
 
 
 # --------------------------------------------------------------------------
+# profiler-trace report (.xplane.pb)
+# --------------------------------------------------------------------------
+PROGRAM_PREFIX = "repro."
+NO_HOST_EVENT = "no host event"     # trace_reduce's label for the same
+# spans that run only on the thread that drives the scheduler
+LOOP_SPANS = ("repro.serve.step", "repro.serve.poll")
+
+
+def _idle_intervals(busy: List[Tuple[float, float]], w0: float,
+                    w1: float) -> List[Tuple[float, float]]:
+    """The gaps of the window ``[w0, w1)`` between merged busy intervals."""
+    edges = [w0] + [x for iv in busy for x in iv] + [w1]
+    return [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
+
+
+def _attribute(idle: List[Tuple[float, float]], ranked) -> Dict[str, float]:
+    """Split the idle intervals among ``ranked`` events, given in the order
+    in which they claim time ((start, end, name), best first): each idle
+    instant goes to the first event that covers it, the rest to
+    ``NO_HOST_EVENT``.  Returns ns by name."""
+    rem = list(idle)                   # unclaimed idle time, sorted, disjoint
+    out: Dict[str, float] = {}
+    for s, e, name in ranked:
+        j = bisect.bisect_right(rem, s, key=lambda iv: iv[1])
+        k = j
+        pieces = []
+        while k < len(rem) and rem[k][0] < e:
+            a, b = rem[k]
+            lo, hi = max(a, s), min(b, e)
+            out[name] = out.get(name, 0.0) + (hi - lo)
+            if a < lo:
+                pieces.append((a, lo))
+            if hi < b:
+                pieces.append((hi, b))
+            k += 1
+        if k > j:
+            rem[j:k] = pieces
+    left = sum(b - a for a, b in rem)
+    if left:
+        out[NO_HOST_EVENT] = left
+    return out
+
+
+def xplane_report(planes) -> Dict:
+    """Device busy and idle time of the first device over the window
+    (``trace_reduce``'s: the ``bench.window`` span, else the extent of the
+    device's ops), and every idle interval split by what the host was
+    doing at that instant: the innermost ``repro.*`` span open on the
+    serving loop's thread (the host line that runs ``repro.serve.step`` /
+    ``repro.serve.poll`` longest), else the innermost on any thread, else
+    the innermost runtime host event, else "no host event".  On one thread
+    spans nest, so the innermost open span is the shortest that covers the
+    instant."""
+    planes = list(planes)
+    devices = sorted(
+        (p for p in planes if p.name.startswith(trace_reduce.DEVICE_PREFIX)),
+        key=lambda p: int(p.name[len(trace_reduce.DEVICE_PREFIX):]))
+    ops = [(ev.start_ns, ev.start_ns + ev.duration_ns)
+           for p in devices[:1] for line in p.lines
+           if line.name == trace_reduce.OPS_LINE for ev in line.events]
+    if not ops:
+        raise ValueError("the trace holds no device operation")
+    lines = [(line.name, [(ev.start_ns, ev.start_ns + ev.duration_ns,
+                           ev.name) for ev in line.events])
+             for p in planes if p.name.startswith(trace_reduce.HOST_PLANE)
+             for line in p.lines]
+    w0, w1 = trace_reduce._window([ev for _, evs in lines for ev in evs],
+                                  [(s, e, None, None) for s, e in ops])
+    busy = trace_reduce._union([(max(s, w0), min(e, w1)) for s, e in ops
+                                if e > w0 and s < w1])
+    busy_s = sum(e - s for s, e in busy) * 1e-9
+    idle = _idle_intervals(busy, w0, w1)
+
+    def loop_time(evs):
+        return sum(min(e, w1) - max(s, w0) for s, e, n in evs
+                   if n in LOOP_SPANS and e > w0 and s < w1)
+    loop_time_ns = [loop_time(evs) for _, evs in lines]
+    loop = (max(range(len(lines)), key=loop_time_ns.__getitem__)
+            if any(loop_time_ns) else None)
+    ranked = []
+    for i, (_, evs) in enumerate(lines):
+        for s, e, n in evs:
+            if n == trace_reduce.WINDOW_SPAN or e <= w0 or s >= w1:
+                continue
+            rank = (0 if i == loop else 1) if n.startswith(
+                PROGRAM_PREFIX) else 2
+            ranked.append((rank, e - s, s, e, n))
+    ranked.sort()
+    by_name = _attribute(idle, ((s, e, n) for _, _, s, e, n in ranked))
+    idle_s = sum(by_name.values()) * 1e-9
+    program_s = sum(v for n, v in by_name.items()
+                    if n.startswith(PROGRAM_PREFIX)) * 1e-9
+    return {
+        "kind": "xplane",
+        "window_s": (w1 - w0) * 1e-9,
+        "busy_s": busy_s,
+        "idle_s": idle_s,
+        "idle_intervals": len(idle),
+        "loop_thread": lines[loop][0] if loop is not None else None,
+        "idle_by_span": {n: v * 1e-9 for n, v in
+                         sorted(by_name.items(), key=lambda kv: -kv[1])},
+        "unattributed_s": idle_s - program_s,
+    }
+
+
+def xplane_file(path: str) -> str:
+    """``path`` itself, or the newest ``.xplane.pb`` under a directory."""
+    if os.path.isdir(path):
+        files = trace_reduce.trace_files(path)
+        if not files:
+            raise ValueError(f"no .xplane.pb under {path}")
+        return max(files, key=os.path.getmtime)
+    return path
+
+
+def xplane_file_report(path: str) -> Dict:
+    from jax.profiler import ProfileData
+    report = xplane_report(ProfileData.from_file(xplane_file(path)).planes)
+    report["path"] = path
+    return report
+
+
+def print_xplane_report(report: Dict) -> None:
+    idle = report["idle_s"]
+    share = (lambda v: 100.0 * v / idle) if idle else (lambda v: 0.0)
+    print(f"== profiler trace: window {_fmt_s(report['window_s'])}, device "
+          f"busy {_fmt_s(report['busy_s'])}, idle {_fmt_s(idle)} in "
+          f"{report['idle_intervals']} intervals ==")
+    print(f"  serving loop thread: {report['loop_thread']}")
+    print(f"  unattributed (no repro.* span): "
+          f"{_fmt_s(report['unattributed_s'])} "
+          f"({share(report['unattributed_s']):.1f}%)")
+    print("== device idle by host span ==")
+    for name, v in report["idle_by_span"].items():
+        print(f"  {name:<42} {_fmt_s(v):>10} {share(v):6.2f}%")
+
+
+# --------------------------------------------------------------------------
 # entry
 # --------------------------------------------------------------------------
 def build_report(doc: Dict) -> Dict:
@@ -258,23 +405,26 @@ def build_report(doc: Dict) -> Dict:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("path", help="metrics dump or exported trace JSON")
+    ap.add_argument("path", help="metrics dump or exported trace JSON, or a "
+                                 "profiler trace (.xplane.pb or its "
+                                 "directory)")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as JSON instead of text")
     args = ap.parse_args(argv)
-    with open(args.path) as f:
-        doc = json.load(f)
     try:
-        report = build_report(doc)
+        if os.path.isdir(args.path) or args.path.endswith(".xplane.pb"):
+            report = xplane_file_report(args.path)
+        else:
+            with open(args.path) as f:
+                report = build_report(json.load(f))
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
     if args.json:
         print(json.dumps(report, indent=1, sort_keys=True))
-    elif report["kind"] == "metrics":
-        print_metrics_report(report)
     else:
-        print_trace_report(report)
+        {"metrics": print_metrics_report, "trace": print_trace_report,
+         "xplane": print_xplane_report}[report["kind"]](report)
     return 0
 
 

@@ -17,10 +17,12 @@ Four rules, each born from a bug class this codebase has already paid for
     (lowercase, dot-separated, at least three segments).
 
 ``hot-path-alloc``
-    The traced-disabled dispatch path (``if not ...enabled:`` branches)
-    runs once per request even when observability is off; it must not
-    allocate (displays, comprehensions, f-strings, lambdas, ``with``
-    locks) or call anything beyond a small allowlist.
+    A span site in the serving and plan layers runs on every request even
+    when nothing records, and its keyword args are evaluated before the
+    span knows that.  So ``.span(...)`` there takes only free args
+    (literals, names, attribute loads); anything computed (calls,
+    f-strings, displays, arithmetic, subscripts, ``**`` unpacking) goes on
+    the live span under ``if sp: sp.set(...)``.
 
 ``bare-except``
     Bare ``except:`` is forbidden everywhere.  Broad handlers
@@ -34,12 +36,11 @@ import ast
 import dataclasses
 import os
 import re
-from typing import Iterable, List, Sequence, Tuple
+from typing import Iterable, List, Sequence
 
-#: Calls the traced-disabled dispatch path may make: publishing the
-#: dispatch record is the one job that branch keeps when tracing is off
-#: (``len`` rides along — allocation-free O(1) builtin).
-HOT_PATH_ALLOWED_CALLS = frozenset({"_publish", "DispatchRecord", "len"})
+#: Directories (relative to the lint root) whose span sites sit on the
+#: per-request path (rule ``hot-path-alloc``).
+HOT_PATH_DIRS = ("serve", "plan")
 
 #: Directories (relative to the lint root) whose broad excepts must be
 #: explicitly reviewed (rule ``bare-except``, second half).
@@ -73,53 +74,21 @@ def _is_private_scope(scope_names: Sequence[str]) -> bool:
     return False
 
 
-def _call_name(node: ast.Call) -> str:
-    """The terminal name a call resolves through (``f`` / ``obj.f`` → f)."""
-    fn = node.func
-    if isinstance(fn, ast.Attribute):
-        return fn.attr
-    if isinstance(fn, ast.Name):
-        return fn.id
-    return ""
-
-
-def _is_disabled_guard(test: ast.expr) -> bool:
-    """``not enabled`` / ``not <x>.enabled`` — the traced-off fast path."""
-    if not (isinstance(test, ast.UnaryOp) and isinstance(test.op, ast.Not)):
-        return False
-    opnd = test.operand
-    if isinstance(opnd, ast.Attribute) and opnd.attr == "enabled":
-        return True
-    return isinstance(opnd, ast.Name) and opnd.id == "enabled"
-
-
-_ALLOC_NODES = (ast.ListComp, ast.SetComp, ast.DictComp, ast.GeneratorExp,
-                ast.Lambda, ast.JoinedStr, ast.List, ast.Set, ast.Dict)
-
-
-def _hot_path_violations(body: Sequence[ast.stmt]
-                         ) -> List[Tuple[int, str]]:
-    """(line, what) for each allocation/lock/stray call under a guard."""
-    out: List[Tuple[int, str]] = []
-    for stmt in body:
-        for node in ast.walk(stmt):
-            if isinstance(node, ast.Call):
-                name = _call_name(node)
-                if name not in HOT_PATH_ALLOWED_CALLS:
-                    out.append((node.lineno, f"call to {name or '<expr>'}()"))
-            elif isinstance(node, _ALLOC_NODES):
-                kind = type(node).__name__
-                out.append((node.lineno, f"allocation ({kind})"))
-            elif isinstance(node, ast.With):
-                out.append((node.lineno, "lock/context acquisition (with)"))
-    return out
+def _is_free_arg(node: ast.expr) -> bool:
+    """A literal, a name or an attribute load off one: nothing to compute
+    before the span knows whether anything records."""
+    while isinstance(node, ast.Attribute):
+        node = node.value
+    return isinstance(node, (ast.Constant, ast.Name))
 
 
 class _Linter(ast.NodeVisitor):
-    def __init__(self, path: str, lines: Sequence[str], guarded: bool):
+    def __init__(self, path: str, lines: Sequence[str], guarded: bool,
+                 hot_path: bool):
         self.path = path
         self.lines = lines
         self.guarded = guarded  # broad-except review required (serve/obs)
+        self.hot_path = hot_path  # span args must be free (serve/plan)
         self.scopes: List[str] = []
         self.findings: List[LintFinding] = []
 
@@ -141,8 +110,17 @@ class _Linter(ast.NodeVisitor):
                 f"(asserts vanish under -O)"))
         self.generic_visit(node)
 
-    # -- rule: metric-name -------------------------------------------------
+    # -- rules: metric-name, hot-path-alloc ---------------------------------
     def visit_Call(self, node: ast.Call) -> None:
+        if (self.hot_path and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "span"):
+            for kw in node.keywords:
+                if kw.arg is None or not _is_free_arg(kw.value):
+                    what = (f"arg {kw.arg}=" if kw.arg else "**-unpacked args")
+                    self.findings.append(LintFinding(
+                        "hot-path-alloc", self.path, kw.value.lineno,
+                        f"computed span {what} is evaluated even when "
+                        f"nothing records; set it under 'if sp: sp.set(...)'"))
         if (isinstance(node.func, ast.Attribute)
                 and node.func.attr in _METRIC_METHODS and node.args
                 and isinstance(node.args[0], ast.Constant)
@@ -153,16 +131,6 @@ class _Linter(ast.NodeVisitor):
                     "metric-name", self.path, node.lineno,
                     f"metric name {name!r} does not match "
                     f"repro.<subsystem>.<name>"))
-        self.generic_visit(node)
-
-    # -- rule: hot-path-alloc ----------------------------------------------
-    def visit_If(self, node: ast.If) -> None:
-        if _is_disabled_guard(node.test):
-            for line, what in _hot_path_violations(node.body):
-                self.findings.append(LintFinding(
-                    "hot-path-alloc", self.path, line,
-                    f"{what} in the traced-disabled fast path; only "
-                    f"{sorted(HOT_PATH_ALLOWED_CALLS)} are allowed there"))
         self.generic_visit(node)
 
     # -- rule: bare-except -------------------------------------------------
@@ -194,14 +162,16 @@ class _Linter(ast.NodeVisitor):
 
 
 def lint_source(src: str, path: str = "<string>", *,
-                guarded_except: bool = False) -> List[LintFinding]:
+                guarded_except: bool = False,
+                hot_path: bool = False) -> List[LintFinding]:
     """Lint one module's source text.  ``guarded_except`` applies the
-    strict broad-except rule (serving/obs layers)."""
+    strict broad-except rule (serving/obs layers), ``hot_path`` the span-arg
+    rule (serving/plan layers)."""
     try:
         tree = ast.parse(src, filename=path)
     except SyntaxError as e:
         return [LintFinding("syntax-error", path, e.lineno or 0, str(e))]
-    linter = _Linter(path, src.splitlines(), guarded_except)
+    linter = _Linter(path, src.splitlines(), guarded_except, hot_path)
     linter.visit(tree)
     return sorted(linter.findings, key=lambda f: (f.path, f.line, f.code))
 
@@ -218,9 +188,9 @@ def _iter_py(paths: Iterable[str]) -> Iterable[str]:
                     yield os.path.join(root, f)
 
 
-def _needs_guard(path: str) -> bool:
-    parts = os.path.normpath(path).split(os.sep)
-    return any(d in parts for d in GUARDED_EXCEPT_DIRS)
+def _under(path: str, dirs: Sequence[str]) -> bool:
+    parts = os.path.normpath(path).split(os.sep)[:-1]
+    return any(d in parts for d in dirs)
 
 
 def lint_paths(paths: Iterable[str] | str) -> List[LintFinding]:
@@ -231,6 +201,7 @@ def lint_paths(paths: Iterable[str] | str) -> List[LintFinding]:
     for path in _iter_py(paths):
         with open(path, "r") as f:
             src = f.read()
-        findings.extend(lint_source(src, path,
-                                    guarded_except=_needs_guard(path)))
+        findings.extend(lint_source(
+            src, path, guarded_except=_under(path, GUARDED_EXCEPT_DIRS),
+            hot_path=_under(path, HOT_PATH_DIRS)))
     return sorted(findings, key=lambda f: (f.path, f.line, f.code))

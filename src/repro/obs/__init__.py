@@ -13,8 +13,9 @@ The measurement substrate of the tune -> plan -> serve stack:
                    whose error says the calibration artifact is stale.
 
 Instrumented call sites live in ``plan/build.py``, ``plan/registry.py``,
-``tune/measure.py``/``autotune.py``/``cache.py``, and ``serve/conv.py``;
-``scripts/obsreport.py`` renders snapshots and traces post-hoc.
+``tune/measure.py``/``autotune.py``/``cache.py``, and ``serve/conv.py``/
+``serve/sched.py``; ``scripts/obsreport.py`` renders snapshots, exported
+traces and profiler traces post-hoc.
 """
 from repro.obs.drift import (DriftMonitor, DriftStat, default_monitor,
                              scene_class, set_default_monitor)
@@ -22,13 +23,14 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricRegistry,
                                default_metrics, histogram_percentile,
                                set_default_metrics, snapshot_delta,
                                snapshot_value, summarize_histogram)
-from repro.obs.trace import Span, Tracer, default_tracer, set_default_tracer
+from repro.obs.trace import (Tracer, default_tracer, profiler_recording,
+                             set_default_tracer)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry", "default_metrics",
     "set_default_metrics", "snapshot_delta", "snapshot_value",
     "histogram_percentile", "summarize_histogram",
-    "Span", "Tracer", "default_tracer", "set_default_tracer",
+    "Tracer", "default_tracer", "profiler_recording", "set_default_tracer",
     "DriftMonitor", "DriftStat", "default_monitor", "set_default_monitor",
     "scene_class",
 ]

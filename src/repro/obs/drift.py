@@ -4,10 +4,10 @@
 nothing today notices when reality moves afterwards (new backend, thermal
 throttling, a kernel change that invalidates the fitted constants).  This
 module streams (predicted, measured) pairs — from tuner measurements and
-from timed plan executions in the serving layer — into per-scene-class
-EWMAs of relative error and flags classes whose error exceeds a threshold:
-the signal that a re-fit (or a re-tune) is due, *before* the selector
-quietly starts ranking schedules on a stale model.
+from the timed plan executions of CNN training (``train/cnn.py``) — into
+per-scene-class EWMAs of relative error and flags classes whose error
+exceeds a threshold: the signal that a re-fit (or a re-tune) is due,
+*before* the selector quietly starts ranking schedules on a stale model.
 
 Scene classes reuse calibration's bucketing (``mapping.class_key``:
 schedule x bound-type x arithmetic-intensity band), so a flagged class maps

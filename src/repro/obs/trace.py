@@ -1,28 +1,35 @@
-"""Structured span tracing with Chrome/Perfetto trace-event export.
+"""Structured span tracing on the profiler's clock, with Chrome/Perfetto
+trace-event export.
 
-A ``Tracer`` records *complete* spans ("ph": "X" trace events): wall-clock
-begin + duration, per-thread track, nesting derived from the per-thread span
-stack.  The API is a context manager (``with tracer.span("repro.x.y",
-k=v):``) or a decorator (``@tracer.traced()``); exported JSON
-(``tracer.export(path)``) loads directly in ``chrome://tracing`` and
-https://ui.perfetto.dev.
+A span is opened with ``with tracer.span("repro.x.y", k=v):`` (or the
+``@tracer.traced()`` decorator).  What it records depends on two switches:
 
-Overhead contract (the serving hot path depends on it): the *disabled* path
-is a single branch — ``span()`` returns a shared no-op handle without
-allocating anything, and callers pay only the attribute check.  Code that
-wants to skip even argument computation can guard on ``tracer.enabled``
-explicitly.  Enabled-path cost is two ``perf_counter`` calls, one dict, and
-one list append per span.
+* a ``jax.profiler`` session is recording (``start_trace`` or the profiler
+  server): the span opens a ``jax.profiler.TraceAnnotation`` of the same
+  name and args on the calling thread, so it lands on the trace's host
+  plane beside the runtime's own events and can name the device's idle
+  gaps.  This holds whether or not the tracer is ``enabled``;
+* the tracer is ``enabled``: the span is also buffered as a complete
+  trace event ("ph": "X") for ``tracer.export(path)``, whose JSON loads in
+  ``chrome://tracing`` and https://ui.perfetto.dev.  Its ``ts`` is the
+  host's wall clock in µs since the Unix epoch (``time.time_ns``), the
+  clock the profiler stamps host events with: an exported span starts
+  where the same span sits in the ``.xplane.pb`` (at
+  ``profile_start_time`` plus the event's offset), to within a few µs.
 
-The span stream is subscribable: ``tracer.subscribe(fn)`` delivers every
-finished ``Span`` (name, wall-times, args) to ``fn`` — the serving layer's
-``DispatchRecord`` emission is one such subscriber, so anything the audit
-hook sees is definitionally also in the exported trace.
+Neither switch touches the device: a span never waits on a result.
+
+Overhead contract (the serving hot path depends on it): with neither
+switch on, ``span()`` costs one ``TraceMe.is_enabled()`` call and one
+attribute check, and returns a shared no-op handle: no span object, no
+annotation (the call's own ``**args`` dict is the one allocation).  Span
+sites whose args cost something set them on the live handle only
+(``if sp: sp.set(...)``; the no-op handle is falsy); lint rule
+``hot-path-alloc`` holds the serving and plan layers to that.
 """
 from __future__ import annotations
 
 import collections
-import dataclasses
 import functools
 import json
 import os
@@ -31,22 +38,18 @@ import threading
 import time
 from typing import Callable, Deque, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "default_tracer", "set_default_tracer"]
+from jax.profiler import TraceAnnotation
 
+__all__ = ["Tracer", "default_tracer", "profiler_recording",
+           "set_default_tracer"]
 
-@dataclasses.dataclass(frozen=True)
-class Span:
-    """One finished span, as delivered to subscribers."""
-
-    name: str
-    t0: float              # tracer-relative start, seconds
-    dur: float             # seconds
-    tid: int
-    args: Dict
+# True while a profiler session records host events (a static method of
+# the annotation's ``TraceMe`` base, well under a µs a call).
+profiler_recording: Callable[[], bool] = TraceAnnotation.is_enabled
 
 
 class _NoopSpan:
-    """Shared do-nothing handle returned while tracing is disabled."""
+    """Shared do-nothing handle returned while nothing records."""
 
     __slots__ = ()
 
@@ -56,6 +59,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def __bool__(self) -> bool:
+        return False
+
     def set(self, **kwargs) -> "_NoopSpan":
         return self
 
@@ -63,45 +69,71 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _Annotation(TraceAnnotation):
+    """Live span while only the profiler records: the profiler's own
+    annotation, with the handle's ``set``, and nothing buffered."""
+
+    def set(self, **kwargs) -> "_Annotation":
+        """Attach/overwrite args on the profiler's event."""
+        self.set_metadata(**kwargs)
+        return self
+
+
 class _SpanHandle:
-    """Live span: records on ``__exit__``.  Only ever constructed while the
-    tracer is enabled (tests assert the disabled path allocates none)."""
+    """Live span of an enabled tracer: a buffered event, and a profiler
+    annotation too when ``profiled``.  Only ever constructed while the
+    tracer is enabled (tests assert the idle path allocates none)."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann", "_buffered")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Dict):
+    def __init__(self, tracer: "Tracer", name: str, args: Dict,
+                 profiled: bool):
         self._tracer = tracer
         self.name = name
         self.args = args
-        self._t0 = 0.0
+        self._t0 = 0
+        self._ann = TraceAnnotation(name, **args) if profiled else None
+        self._buffered = tracer.enabled
 
     def set(self, **kwargs) -> "_SpanHandle":
-        """Attach/overwrite args on the live span (visible in the exported
-        event and to subscribers)."""
+        """Attach/overwrite args on the live span (in the profiler's event
+        and in the exported one)."""
         self.args.update(kwargs)
+        if self._ann is not None:
+            self._ann.set_metadata(**kwargs)
         return self
 
     def __enter__(self) -> "_SpanHandle":
-        stack = self._tracer._stack()
-        if stack:
-            self.args.setdefault("parent", stack[-1].name)
-        stack.append(self)
-        self._t0 = time.perf_counter()
+        if self._buffered:
+            stack = self._tracer._stack()
+            if stack:
+                self.args.setdefault("parent", stack[-1].name)
+            stack.append(self)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        t1 = time.perf_counter()
-        stack = self._tracer._stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        if exc_type is not None:
-            self.args.setdefault("error", exc_type.__name__)
-        self._tracer._finish(self, self._t0, t1 - self._t0)
+        t1 = time.time_ns()
+        if self._ann is not None:
+            if exc_type is not None:
+                self._ann.set_metadata(error=exc_type.__name__)
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._buffered:
+            stack = self._tracer._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            if exc_type is not None:
+                self.args.setdefault("error", exc_type.__name__)
+            self._tracer._finish(self, self._t0, t1)
         return False
 
 
 class Tracer:
-    """Span recorder with an explicit ``enabled`` gate.
+    """Span recorder with an explicit ``enabled`` gate for the buffer (the
+    profiler's annotations follow the profiler session, see the module
+    docstring).
 
     ``max_events`` bounds memory as a ring buffer: the newest spans win and
     ``dropped_events`` counts what fell off — a long soak with tracing left
@@ -114,17 +146,19 @@ class Tracer:
         self._events: Deque[Dict] = collections.deque(maxlen=max_events)
         self.dropped_events = 0
         self._lock = threading.Lock()
-        self._subscribers: List[Callable[[Span], None]] = []
         self._tls = threading.local()
-        self._epoch = time.perf_counter()
 
     # -- span API ------------------------------------------------------------
-    def span(self, name: str, **args) -> "_SpanHandle":
-        """Context manager for one span.  Disabled tracing returns a shared
-        no-op handle — a single branch, zero allocation."""
-        if not self.enabled:
+    def span(self, name: str, **args):
+        """Context manager for one span.  With no profiler session and the
+        tracer disabled it returns the shared no-op handle: one check, no
+        span object."""
+        profiled = profiler_recording()
+        if not (profiled or self.enabled):
             return _NOOP
-        return _SpanHandle(self, name, args)
+        if not self.enabled:
+            return _Annotation(name, **args)
+        return _SpanHandle(self, name, args, profiled)
 
     def traced(self, name: Optional[str] = None) -> Callable:
         """Decorator form: spans every call of the wrapped function."""
@@ -139,7 +173,7 @@ class Tracer:
         return deco
 
     def current(self) -> Optional[str]:
-        """Name of this thread's innermost open span, if any."""
+        """Name of this thread's innermost open buffered span, if any."""
         stack = self._stack()
         return stack[-1].name if stack else None
 
@@ -149,11 +183,11 @@ class Tracer:
             stack = self._tls.stack = []
         return stack
 
-    def _finish(self, handle: "_SpanHandle", t0: float, dur: float) -> None:
+    def _finish(self, handle: "_SpanHandle", t0_ns: int, t1_ns: int) -> None:
         event = {
             "ph": "X", "cat": "repro", "name": handle.name,
-            "ts": (t0 - self._epoch) * 1e6,     # trace-event µs
-            "dur": dur * 1e6,
+            "ts": t0_ns / 1e3,                  # trace-event µs, wall clock
+            "dur": (t1_ns - t0_ns) / 1e3,
             "pid": os.getpid(), "tid": threading.get_ident(),
             "args": handle.args,
         }
@@ -161,28 +195,6 @@ class Tracer:
             if len(self._events) == self.max_events:
                 self.dropped_events += 1
             self._events.append(event)
-            subscribers = list(self._subscribers)
-        if subscribers:
-            span = Span(name=handle.name, t0=t0 - self._epoch, dur=dur,
-                        tid=event["tid"], args=handle.args)
-            for fn in subscribers:
-                try:
-                    fn(span)
-                except Exception:  # noqa: BLE001 — a broken sink must never
-                    pass           # kill the traced operation
-
-    # -- span stream ---------------------------------------------------------
-    def subscribe(self, fn: Callable[[Span], None]) -> Callable:
-        """Deliver every finished span to ``fn`` (while enabled); returns
-        ``fn`` so callers can ``unsubscribe`` it later."""
-        with self._lock:
-            self._subscribers.append(fn)
-        return fn
-
-    def unsubscribe(self, fn: Callable[[Span], None]) -> None:
-        with self._lock:
-            if fn in self._subscribers:
-                self._subscribers.remove(fn)
 
     # -- buffer --------------------------------------------------------------
     def events(self) -> List[Dict]:

@@ -427,19 +427,24 @@ class ConvPlan:
     # -- execution ---------------------------------------------------------
     def execute(self, a: jax.Array, b: jax.Array) -> jax.Array:
         """Run the planned op: (inp, flt) for FPROP, (d_out, flt) for DGRAD,
-        (inp, d_out) for WGRAD."""
+        (inp, d_out) for WGRAD.  Enqueues the kernel under a
+        ``repro.plan.execute`` span (args: the op) and returns without
+        waiting for it."""
         a_shape, b_shape, _ = self.io_shapes()
         if a.shape != a_shape or b.shape != b_shape:
             raise ValueError(
                 f"{self.op.value} plan for {self.scene.describe()} expects "
                 f"operands {a_shape} x {b_shape}, got {a.shape} x {b.shape}")
-        if self.uses_reference:
-            fn = {ConvOp.FPROP: _ref_fprop, ConvOp.DGRAD: _ref_dgrad,
-                  ConvOp.WGRAD: _ref_wgrad}[self.op]
-            return fn(a, b, self.scene)
-        fn = {ConvOp.FPROP: _exec_fprop, ConvOp.DGRAD: _exec_dgrad,
-              ConvOp.WGRAD: _exec_wgrad}[self.op]
-        return fn(a, b, self.exec_scene, self.spec)
+        with default_tracer().span("repro.plan.execute") as sp:
+            if sp:
+                sp.set(op=self.op.value)
+            if self.uses_reference:
+                fn = {ConvOp.FPROP: _ref_fprop, ConvOp.DGRAD: _ref_dgrad,
+                      ConvOp.WGRAD: _ref_wgrad}[self.op]
+                return fn(a, b, self.scene)
+            fn = {ConvOp.FPROP: _exec_fprop, ConvOp.DGRAD: _exec_dgrad,
+                  ConvOp.WGRAD: _exec_wgrad}[self.op]
+            return fn(a, b, self.exec_scene, self.spec)
 
     __call__ = execute
 
@@ -496,8 +501,9 @@ def make_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
     """
     op = ConvOp(op)
     tag = policy_tag(policy)
-    with default_tracer().span("repro.plan.make_plan", op=op.value,
-                               policy=tag, scene=scene.describe()):
+    with default_tracer().span("repro.plan.make_plan") as sp:
+        if sp:
+            sp.set(op=op.value, policy=tag, scene=scene.describe())
         return _make_plan_inner(scene, op, policy, tag, use_pallas)
 
 

@@ -41,12 +41,18 @@ tag per (layer, op, bucket) is recorded at prewarm, so steady state stays
 a zero-resolution registry lookup (tag dict hit + shard-keyed ``get``).
 
 Observability: every server owns a ``MetricRegistry`` (``repro.serve.*``
-counters + queue-wait/dispatch histograms; ``stats(since=snapshot())``
-windows them) and dispatches under a ``repro.serve.dispatch`` span when the
-tracer is enabled — ``DispatchRecord`` emission is a *subscriber of the span
-stream*, so anything ``on_dispatch`` sees is definitionally in the exported
-trace; with tracing off, records are published directly and the hot path
-pays one branch.  A hook that raises is counted
+counters + queue-wait/enqueue histograms; ``stats(since=snapshot())``
+windows them).  Each stage a request passes through runs under a span —
+``repro.serve.submit``, ``repro.serve.step``, ``repro.serve.dispatch``,
+``repro.serve.glue`` (concat/pad before the plan, lane slices after),
+``repro.plan.execute`` and, at start-up, ``repro.serve.prewarm`` — which
+becomes a host event of a recording ``jax.profiler`` session and, with the
+tracer enabled, a buffered Chrome-trace event (``repro.obs.trace``).
+Tracing never waits on the device: a dispatch is asynchronous either way,
+``repro.serve.enqueue_s`` times its host side (outputs not ready), and the
+profiler's device plane, not a span, says when the kernels ran.
+``DispatchRecord``s are published directly to ``on_dispatch`` after every
+successful dispatch; a hook that raises is counted
 (``repro.serve.dispatch_hook_errors``) and never fails the dispatch.
 """
 from __future__ import annotations
@@ -65,11 +71,9 @@ import jax.numpy as jnp
 from repro.core.mapping import (CostModel, predicted_efficiency,
                                 select_schedule)
 from repro.core.scene import ConvScene
-from repro.obs import drift as drift_mod
 from repro.obs.metrics import (DEFAULT_RATIO_BUCKETS, MetricRegistry,
                                snapshot_delta, snapshot_value)
-from repro.obs.trace import _NOOP as _NOOP_SPAN
-from repro.obs.trace import Span, Tracer, default_tracer
+from repro.obs.trace import Tracer, default_tracer
 from repro.plan import ConvOp, ConvPlan, PlanRegistry, make_plan
 from repro.plan.build import PolicySpec, _active_cost_model
 
@@ -202,9 +206,6 @@ class _Family:
 # --------------------------------------------------------------------------
 # the server
 # --------------------------------------------------------------------------
-_SERVER_SEQ = itertools.count()   # unique per-process ids for span filtering
-
-
 class ConvServer:
     """Scene-bucketed micro-batching conv server over a prewarmed
     ``PlanRegistry``.
@@ -229,9 +230,7 @@ class ConvServer:
                  cost_model: Optional[CostModel] = None, strict: bool = False,
                  on_dispatch: Optional[Callable[[DispatchRecord], None]]
                  = None, metrics: Optional[MetricRegistry] = None,
-                 tracer: Optional[Tracer] = None,
-                 drift: Optional["drift_mod.DriftMonitor"] = None,
-                 mesh=None):
+                 tracer: Optional[Tracer] = None, mesh=None):
         if mesh is not None and not use_pallas:
             raise ValueError(
                 "mesh serving requires use_pallas=True: sharded plans "
@@ -264,7 +263,6 @@ class ConvServer:
         # to aggregate several servers into one registry instead
         self.metrics = metrics if metrics is not None else MetricRegistry()
         self.tracer = tracer if tracer is not None else default_tracer()
-        self.drift = drift if drift is not None else drift_mod.default_monitor()
         self._c_requests = self.metrics.counter("repro.serve.requests")
         self._c_dispatches = self.metrics.counter("repro.serve.dispatches")
         self._c_occupied = self.metrics.counter("repro.serve.occupied_lanes")
@@ -275,15 +273,11 @@ class ConvServer:
             "repro.serve.dispatch_hook_errors")
         self._g_queue = self.metrics.gauge("repro.serve.queue_depth")
         self._h_wait = self.metrics.histogram("repro.serve.queue_wait_s")
-        self._h_dispatch = self.metrics.histogram("repro.serve.dispatch_s")
+        # host seconds from dispatch start to the group's last enqueue; the
+        # outputs are not ready then (dispatch is asynchronous)
+        self._h_enqueue = self.metrics.histogram("repro.serve.enqueue_s")
         self._h_occupancy = self.metrics.histogram(
             "repro.serve.occupancy", bounds=DEFAULT_RATIO_BUCKETS)
-        # DispatchRecord emission rides the span stream when tracing is on:
-        # the sink below filters this server's finished dispatch spans, so
-        # the audit hook and the exported trace can never disagree.  The id
-        # is a process-unique sequence number (id() could be reused).
-        self._sid = next(_SERVER_SEQ)
-        self.tracer.subscribe(self._span_sink)
 
     # -- setup -------------------------------------------------------------
     def register_layer(self, layer: str, scene: ConvScene, flt: jax.Array,
@@ -329,22 +323,35 @@ class ConvServer:
         with self._lock:
             families = list(self._layers.values())
         for fam in families:
-            if self._ring is not None:
-                built += self._prewarm_sharded(fam)
-            else:
-                built += self.registry.warm(
-                    [fam.base], ops=fam.ops, buckets=fam.ladder,
-                    policy=self.policy, use_pallas=self.use_pallas)
+            with self._prewarm_span(fam, "plans"):
+                if self._ring is not None:
+                    built += self._prewarm_sharded(fam)
+                else:
+                    built += self.registry.warm(
+                        [fam.base], ops=fam.ops, buckets=fam.ladder,
+                        policy=self.policy, use_pallas=self.use_pallas)
         if compile:
             for fam in families:
-                for op, bucket in itertools.product(fam.ops, fam.ladder):
-                    plan = self._plan(fam, op, bucket)
-                    a_shape = fam.a_spatial(op) + (bucket,)
-                    jax.block_until_ready(plan.execute(
-                        jnp.zeros(a_shape, fam.base.dtype), fam.flt))
+                with self._prewarm_span(fam, "compile"):
+                    self._compile(fam, fam.ladder)
         with self._lock:
             self._warmed = True
         return built
+
+    def _prewarm_span(self, fam: _Family, stage: str):
+        """The ``repro.serve.prewarm`` span of one family's start-up
+        ``stage``."""
+        return self.tracer.span("repro.serve.prewarm", layer=fam.layer,
+                                stage=stage)
+
+    def _compile(self, fam: _Family, buckets: Sequence[int]) -> None:
+        """Execute each of ``fam``'s plans at ``buckets`` once on zeros,
+        so kernel JIT is paid before traffic."""
+        for op, bucket in itertools.product(fam.ops, buckets):
+            plan = self._plan(fam, op, bucket)
+            a_shape = fam.a_spatial(op) + (bucket,)
+            jax.block_until_ready(plan.execute(
+                jnp.zeros(a_shape, fam.base.dtype), fam.flt))
 
     def save(self, path: str) -> str:
         """Persist the plan repository as the prewarm artifact of the next
@@ -369,24 +376,33 @@ class ConvServer:
             raise ValueError(f"layer {req.layer!r} serves ops "
                              f"{[o.value for o in fam.ops]}, not "
                              f"{req.op.value}")
-        x = jnp.asarray(req.x)
-        if x.ndim == 3:
-            x = x[..., None]
-            req._squeeze = True
-        want = fam.a_spatial(req.op)
-        if x.ndim != 4 or x.shape[:3] != want:
-            raise ValueError(
-                f"request {req.rid} for layer {req.layer!r} ({req.op.value}) "
-                f"expects a [{want[0]}, {want[1]}, {want[2]}, b] tensor, "
-                f"got {tuple(req.x.shape)}")
-        if x.shape[3] > fam.ladder[-1]:
-            raise ValueError(
-                f"request {req.rid} batch {x.shape[3]} exceeds the top "
-                f"ladder bucket {fam.ladder[-1]} of layer {req.layer!r}; "
-                f"split it or raise max_batch")
-        if req.deadline_s is not None and req.deadline_s <= 0:
-            raise ValueError(f"request {req.rid} deadline_s must be "
-                             f"positive, got {req.deadline_s}")
+        with self.tracer.span("repro.serve.submit") as sp:
+            x = jnp.asarray(req.x)
+            if x.ndim == 3:
+                x = x[..., None]
+                req._squeeze = True
+            want = fam.a_spatial(req.op)
+            if x.ndim != 4 or x.shape[:3] != want:
+                raise ValueError(
+                    f"request {req.rid} for layer {req.layer!r} "
+                    f"({req.op.value}) expects a [{want[0]}, {want[1]}, "
+                    f"{want[2]}, b] tensor, got {tuple(req.x.shape)}")
+            if x.shape[3] > fam.ladder[-1]:
+                raise ValueError(
+                    f"request {req.rid} batch {x.shape[3]} exceeds the top "
+                    f"ladder bucket {fam.ladder[-1]} of layer "
+                    f"{req.layer!r}; split it or raise max_batch")
+            if req.deadline_s is not None and req.deadline_s <= 0:
+                raise ValueError(f"request {req.rid} deadline_s must be "
+                                 f"positive, got {req.deadline_s}")
+            if sp:
+                sp.set(b=x.shape[3])
+            return self._admit(req, x, fam)
+
+    def _admit(self, req: ConvRequest, x: jax.Array,
+               fam: _Family) -> ConvRequest:
+        """Cast a validated 4-D request tensor to the family's dtype, stamp
+        the request and enqueue it (the tail of every ``submit``)."""
         req.x = x.astype(jnp.dtype(fam.base.dtype))
         req._b = x.shape[3]
         req.out, req.done, req.error = None, False, None
@@ -406,13 +422,15 @@ class ConvServer:
         self._queue.append(req)
 
     # -- dispatch ----------------------------------------------------------
-    def _take_batch(self) -> List[ConvRequest]:
+    def _take_batch(self) -> Tuple[List[ConvRequest], Optional[str]]:
         """Pop the head request plus every queued request of the same
         (layer, op) that still fits under the family's top bucket — FIFO
-        fairness across families, maximal coalescing within one."""
+        fairness across families, maximal coalescing within one.  Returns
+        the group and why it flushes (``"demand"``: this server dispatches
+        whatever is queued)."""
         with self._lock:
             if not self._queue:
-                return []
+                return [], None
             head = self._queue.popleft()
             cap = self._layers[head.layer].ladder[-1]
             group, total = [head], head._b
@@ -423,7 +441,7 @@ class ConvServer:
                     group.append(r)
                     total += r._b
             self._g_queue.set(len(self._queue))
-            return group
+            return group, "demand"
 
     def _prewarm_sharded(self, fam: _Family) -> int:
         """Mesh-mode warm: jointly select (grain x partition) for every
@@ -485,17 +503,14 @@ class ConvServer:
 
     def step(self) -> int:
         """Coalesce and dispatch one micro-batch; returns requests served
-        (0 = queue empty).
-
-        With tracing enabled the dispatch runs under a
-        ``repro.serve.dispatch`` span, blocks on the result (honest
-        wall-clock), and streams the plan's (predicted, measured) pair into
-        the drift monitor; the finished span's args carry everything a
-        ``DispatchRecord`` holds and the span sink publishes it.  With
-        tracing disabled the dispatch stays async (the histograms then time
-        *enqueue*, not completion) and the record is published directly —
-        no span object is ever allocated on that path."""
-        return self._dispatch(self._take_batch())
+        (0 = queue empty).  Runs under a ``repro.serve.step`` span (args:
+        the flush reason) and never waits for the device: the group's
+        outputs are in flight when it returns."""
+        with self.tracer.span("repro.serve.step") as sp:
+            group, why = self._take_batch()
+            if sp and group:
+                sp.set(flush=why)
+            return self._dispatch(group)
 
     def _bucket_for(self, fam: _Family, op: ConvOp, total: int) -> int:
         """Padded batch for a coalesced group of ``total`` lanes: the
@@ -504,44 +519,40 @@ class ConvServer:
         model's per-bucket predictions."""
         return next(b for b in fam.ladder if b >= total)
 
-    def _dispatch(self, group: List[ConvRequest]) -> int:
-        """Execute one coalesced group (see ``step`` for the tracing
-        contract); returns requests served."""
-        enabled = self.tracer.enabled
-        if not group:
-            return 0
+    def _observe_wait(self, group: List[ConvRequest]) -> float:
+        """Record each request's queue wait; returns the dispatch start."""
         t_start = time.perf_counter()
         for r in group:
             if r._t_submit:
                 self._h_wait.observe(t_start - r._t_submit)
-        sp = (self.tracer.span("repro.serve.dispatch", server=self._sid)
-              if enabled else _NOOP_SPAN)
-        with sp:
-            try:
-                fam = self._layers[group[0].layer]
-                op = group[0].op
-                total = sum(r._b for r in group)
-                bucket = self._bucket_for(fam, op, total)
-                x = (group[0].x if len(group) == 1
-                     else jnp.concatenate([r.x for r in group], axis=3))
-                if bucket > total:
-                    x = jnp.pad(x,
-                                ((0, 0), (0, 0), (0, 0), (0, bucket - total)))
-                plan = self._plan(fam, op, bucket)
-                t_exec = time.perf_counter()
-                out = plan.execute(x, fam.flt)
-                if enabled:
-                    jax.block_until_ready(out)
-            except BaseException as e:  # noqa: BLE001 — propagated to every
-                # waiter in the group (r.error below), not swallowed
-                # the group is already off the queue: complete it with the
-                # error so a serve() waiting in another thread unblocks
-                for r in group:
-                    r.error, r.done = e, True
-                    if r._event is not None:
-                        r._event.set()
-                raise
-            exec_s = time.perf_counter() - t_exec
+        return t_start
+
+    def _gather(self, group: List[ConvRequest], bucket: int,
+                total: int) -> jax.Array:
+        """The group's tensors concatenated along B and zero-padded to
+        ``bucket`` lanes, under a ``repro.serve.glue`` span."""
+        with self.tracer.span("repro.serve.glue"):
+            x = (group[0].x if len(group) == 1
+                 else jnp.concatenate([r.x for r in group], axis=3))
+            if bucket > total:
+                x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, bucket - total)))
+            return x
+
+    @staticmethod
+    def _fail(group: List[ConvRequest], e: BaseException) -> None:
+        """Complete a group that is already off the queue with ``e``, so a
+        ``serve()``/``wait()`` blocked in another thread unblocks."""
+        for r in group:
+            r.error, r.done = e, True
+            if r._event is not None:
+                r._event.set()
+
+    def _complete(self, group: List[ConvRequest], out: jax.Array,
+                  bucket: int, total: int, t_start: float) -> None:
+        """Slice each request's lanes out of ``out`` (a
+        ``repro.serve.glue`` span), signal its waiters and record the
+        dispatch's counters; ``enqueue_s`` ends here, outputs in flight."""
+        with self.tracer.span("repro.serve.glue"):
             off = 0
             for r in group:
                 sl = out[..., off:off + r._b]
@@ -550,44 +561,40 @@ class ConvServer:
                 r.done = True
                 if r._event is not None:
                     r._event.set()
-            self._c_requests.inc(len(group))
-            self._c_dispatches.inc()
-            self._c_occupied.inc(total)
-            self._c_bucket.inc(bucket)
-            self._h_dispatch.observe(time.perf_counter() - t_start)
-            self._h_occupancy.observe(total / bucket)
-            if (enabled and plan.choice is not None
-                    and plan.exec_scene is not None):
-                # blocked above, so exec_s is an honest kernel wall-clock:
-                # audit the cost model with it
-                # plan.predicted_s, not choice.predicted_s: sharded plans
-                # predict the whole dispatch (collective + launch terms),
-                # and that is what exec_s measures
-                self.drift.observe(
-                    drift_mod.scene_class(plan.exec_scene, plan.choice),
-                    plan.predicted_s, exec_s)
-            # args only on success: a failed dispatch leaves the span with
-            # its error tag and never becomes a DispatchRecord
-            sp.set(layer=fam.layer, op=op.value, bucket=bucket,
-                   occupied=total, requests=len(group),
-                   schedule=plan.schedule, exec_s=exec_s)
-        if not enabled:
-            self._publish(DispatchRecord(
-                layer=fam.layer, op=op, bucket=bucket, occupied=total,
-                requests=len(group), schedule=plan.schedule))
-        return len(group)
+        self._h_enqueue.observe(time.perf_counter() - t_start)
+        self._c_requests.inc(len(group))
+        self._c_dispatches.inc()
+        self._c_occupied.inc(total)
+        self._c_bucket.inc(bucket)
+        self._h_occupancy.observe(total / bucket)
 
-    def _span_sink(self, span: Span) -> None:
-        """Span-stream subscriber: this server's finished dispatch spans
-        become ``DispatchRecord``s (tracing-enabled path)."""
-        a = span.args
-        if (span.name != "repro.serve.dispatch"
-                or a.get("server") != self._sid or "layer" not in a):
-            return
+    def _dispatch(self, group: List[ConvRequest]) -> int:
+        """Execute one coalesced group under a ``repro.serve.dispatch``
+        span; returns requests served."""
+        if not group:
+            return 0
+        t_start = self._observe_wait(group)
+        with self.tracer.span("repro.serve.dispatch") as sp:
+            try:
+                fam = self._layers[group[0].layer]
+                op = group[0].op
+                total = sum(r._b for r in group)
+                bucket = self._bucket_for(fam, op, total)
+                x = self._gather(group, bucket, total)
+                plan = self._plan(fam, op, bucket)
+                out = plan.execute(x, fam.flt)
+            except BaseException as e:  # noqa: BLE001 — propagated to every
+                # waiter in the group (r.error), then re-raised
+                self._fail(group, e)
+                raise
+            self._complete(group, out, bucket, total, t_start)
+            if sp:
+                sp.set(layer=fam.layer, op=op.value, bucket=bucket,
+                       occupied=total, requests=len(group))
         self._publish(DispatchRecord(
-            layer=a["layer"], op=ConvOp(a["op"]), bucket=a["bucket"],
-            occupied=a["occupied"], requests=a["requests"],
-            schedule=a.get("schedule")))
+            layer=fam.layer, op=op, bucket=bucket, occupied=total,
+            requests=len(group), schedule=plan.schedule))
+        return len(group)
 
     def _publish(self, rec: DispatchRecord) -> None:
         """Deliver one record to ``on_dispatch``; a raising hook is counted

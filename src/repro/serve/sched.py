@@ -43,9 +43,21 @@ padded lanes stay zero through the whole chain and each request's columns
 are bitwise identical (f32) to serving it layer-by-layer.
 
 Deadline accounting is honest-by-construction: a dispatched group carrying
-deadlines blocks on its result (even with tracing off) before
-``repro.serve.deadline_misses`` / ``deadline_slack_s`` are recorded, so a
-"met deadline" means the tensor was ready, not merely enqueued.
+deadlines blocks on its result before ``repro.serve.deadline_misses`` /
+``deadline_slack_s`` are recorded, so a "met deadline" means the tensor was
+ready, not merely enqueued.  Nothing else in the loop waits for the device,
+traced or not.
+
+Tracing follows ``ConvServer`` (``repro.serve.conv``): ``repro.serve.step``
+spans each scheduling decision and its dispatch (args: the flush reason),
+``repro.serve.poll`` each idle sleep of ``drain`` and the background loop
+(args: the queue depth; ``repro.serve.poll_s`` histograms its seconds), and
+a whole-model group runs under ``repro.serve.model_dispatch`` with a
+``repro.serve.glue`` span on each side of one ``repro.serve.layer_dispatch``
+span per layer (its ``plan.execute`` and activation).  So the scheduler's
+thread is under a ``repro.serve.*`` span at every moment but a few µs of
+loop overhead, and a profiler trace names what the host did while the
+device idled.
 """
 from __future__ import annotations
 
@@ -62,10 +74,7 @@ import jax.numpy as jnp
 
 from repro.core.scene import ConvScene
 from repro.models.cnn import validate_scene_chain
-from repro.obs import drift as drift_mod
 from repro.obs.metrics import snapshot_delta, snapshot_value
-from repro.obs.trace import _NOOP as _NOOP_SPAN
-from repro.obs.trace import Span
 from repro.plan import ConvOp
 from repro.serve.conv import (ConvRequest, ConvServer, DispatchRecord,
                               _Family, bucket_ladder, seeded_weights)
@@ -215,8 +224,9 @@ class ConvScheduler(ConvServer):
                 "repro.serve.gather_timeout_flushes"),
         }
         self._h_slack = self.metrics.histogram("repro.serve.deadline_slack_s")
-        self._h_layer = self.metrics.histogram(
-            "repro.serve.layer_dispatch_s")
+        # seconds of each idle sleep of the loop: the sum is the host's
+        # waiting time, the mean against poll_s its oversleep
+        self._h_poll = self.metrics.histogram("repro.serve.poll_s")
         self._stop_evt = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -273,28 +283,26 @@ class ConvScheduler(ConvServer):
         with self._lock:
             families = list(self._layers.values())
         for fam in families:
-            rungs = bucket_ladder(fam.base, self.max_batch,
-                                  min_bucket=self.min_bucket, slack=0.0)
-            built += self.registry.warm(
-                [fam.base], ops=fam.ops, buckets=rungs,
-                policy=self.policy, use_pallas=self.use_pallas)
-            for op in fam.ops:
-                for b in rungs:
-                    plan = self.registry.get(
-                        fam.base.with_batch(b), op, policy=self.policy,
-                        use_pallas=self.use_pallas)
-                    self._pred_s[(fam.layer, op, b)] = plan.predicted_s or 0.0
-            with self._lock:
-                self._flush_rungs[fam.layer] = rungs
+            with self._prewarm_span(fam, "flush_plans"):
+                rungs = bucket_ladder(fam.base, self.max_batch,
+                                      min_bucket=self.min_bucket, slack=0.0)
+                built += self.registry.warm(
+                    [fam.base], ops=fam.ops, buckets=rungs,
+                    policy=self.policy, use_pallas=self.use_pallas)
+                for op in fam.ops:
+                    for b in rungs:
+                        plan = self.registry.get(
+                            fam.base.with_batch(b), op, policy=self.policy,
+                            use_pallas=self.use_pallas)
+                        self._pred_s[(fam.layer, op, b)] = (
+                            plan.predicted_s or 0.0)
+                with self._lock:
+                    self._flush_rungs[fam.layer] = rungs
         if compile:
             for fam in families:
-                extra = [b for b in self._flush_rungs[fam.layer]
-                         if b not in fam.ladder]
-                for op, b in itertools.product(fam.ops, extra):
-                    plan = self._plan(fam, op, b)
-                    a_shape = fam.a_spatial(op) + (b,)
-                    jax.block_until_ready(plan.execute(
-                        jnp.zeros(a_shape, fam.base.dtype), fam.flt))
+                with self._prewarm_span(fam, "flush_compile"):
+                    self._compile(fam, [b for b in self._flush_rungs[fam.layer]
+                                        if b not in fam.ladder])
         with self._lock:
             self._warmed = True
         return built
@@ -344,34 +352,27 @@ class ConvScheduler(ConvServer):
         req.layer = "@" + req.net
         req.op = ConvOp.FPROP
         fam = self._layers[chain.layers[0]]
-        x = jnp.asarray(req.x)
-        if x.ndim == 3:
-            x = x[..., None]
-            req._squeeze = True
-        want = fam.a_spatial(ConvOp.FPROP)
-        if x.ndim != 4 or x.shape[:3] != want:
-            raise ValueError(
-                f"model request {req.rid} for net {req.net!r} expects a "
-                f"[{want[0]}, {want[1]}, {want[2]}, b] tensor, got "
-                f"{tuple(req.x.shape)}")
-        if x.shape[3] > self.max_batch:
-            raise ValueError(
-                f"model request {req.rid} batch {x.shape[3]} exceeds "
-                f"max_batch {self.max_batch}; split it")
-        if req.deadline_s is not None and req.deadline_s <= 0:
-            raise ValueError(f"request {req.rid} deadline_s must be "
-                             f"positive, got {req.deadline_s}")
-        req.x = x.astype(jnp.dtype(fam.base.dtype))
-        req._b = x.shape[3]
-        req.out, req.done, req.error = None, False, None
-        req._event = threading.Event()
-        req._t_submit = time.perf_counter()
-        req._t_deadline = (req._t_submit + req.deadline_s
-                           if req.deadline_s is not None else None)
-        with self._lock:
-            self._enqueue(req)
-            self._g_queue.set(len(self._queue))
-        return req
+        with self.tracer.span("repro.serve.submit") as sp:
+            x = jnp.asarray(req.x)
+            if x.ndim == 3:
+                x = x[..., None]
+                req._squeeze = True
+            want = fam.a_spatial(ConvOp.FPROP)
+            if x.ndim != 4 or x.shape[:3] != want:
+                raise ValueError(
+                    f"model request {req.rid} for net {req.net!r} expects "
+                    f"a [{want[0]}, {want[1]}, {want[2]}, b] tensor, got "
+                    f"{tuple(req.x.shape)}")
+            if x.shape[3] > self.max_batch:
+                raise ValueError(
+                    f"model request {req.rid} batch {x.shape[3]} exceeds "
+                    f"max_batch {self.max_batch}; split it")
+            if req.deadline_s is not None and req.deadline_s <= 0:
+                raise ValueError(f"request {req.rid} deadline_s must be "
+                                 f"positive, got {req.deadline_s}")
+            if sp:
+                sp.set(b=x.shape[3])
+            return self._admit(req, x, fam)
 
     # -- flush decision ------------------------------------------------------
     def _group_cap(self, head: ConvRequest) -> int:
@@ -439,14 +440,15 @@ class ConvScheduler(ConvServer):
             return "gather"
         return None
 
-    def _take_batch(self) -> List[ConvRequest]:
+    def _take_batch(self) -> Tuple[List[ConvRequest], Optional[str]]:
         """First flush-ready group in queue order (EDF policy keeps the
-        queue deadline-ordered, so "queue order" is urgency order there);
-        empty list when nothing should dispatch yet."""
+        queue deadline-ordered, so "queue order" is urgency order there)
+        and its flush reason; an empty list when nothing should dispatch
+        yet."""
         now = time.perf_counter()
         with self._lock:
             if not self._queue:
-                return []
+                return [], None
             seen = set()
             for head in list(self._queue):
                 key = (head.layer, head.op)
@@ -461,8 +463,8 @@ class ConvScheduler(ConvServer):
                     self._queue.remove(r)
                 self._g_queue.set(len(self._queue))
                 self._c_flush[why].inc()
-                return group
-            return []
+                return group, why
+            return [], None
 
     # -- dispatch ------------------------------------------------------------
     def _bucket_for(self, fam: _Family, op: ConvOp, total: int) -> int:
@@ -480,27 +482,30 @@ class ConvScheduler(ConvServer):
                                   b))
 
     def step(self) -> int:
-        """One scheduling decision + dispatch; returns requests served
-        (0 = nothing flush-ready)."""
-        group = self._take_batch()
-        if not group:
-            return 0
-        if isinstance(group[0], ModelRequest):
-            served = self._dispatch_model(group)
-        else:
-            served = self._dispatch(group)
-        self._account_deadlines(group)
-        return served
+        """One scheduling decision + dispatch under a ``repro.serve.step``
+        span (args: the flush reason); returns requests served (0 = nothing
+        flush-ready)."""
+        with self.tracer.span("repro.serve.step") as sp:
+            group, why = self._take_batch()
+            if not group:
+                return 0
+            if sp:
+                sp.set(flush=why)
+            if isinstance(group[0], ModelRequest):
+                served = self._dispatch_model(group)
+            else:
+                served = self._dispatch(group)
+            self._account_deadlines(group)
+            return served
 
     def _account_deadlines(self, group: List[ConvRequest]) -> None:
         deadlined = [r for r in group if r._t_deadline is not None]
         if not deadlined:
             return
-        enabled = self.tracer.enabled
-        if group[0].out is not None and not enabled:
-            # untraced dispatch is async; block on one lane (the group
-            # shares a dispatch) so miss accounting measures completion,
-            # not enqueue — deadline-carrying traffic opts into the sync
+        if group[0].out is not None:
+            # dispatch is async; block on one lane (the group shares a
+            # dispatch) so miss accounting measures completion, not
+            # enqueue — deadline-carrying traffic opts into the sync
             jax.block_until_ready(group[0].out)
         now = time.perf_counter()
         for r in deadlined:
@@ -526,86 +531,34 @@ class ConvScheduler(ConvServer):
         carry the activation through every layer in plan layout, slice
         lanes back at the end.  Mirrors ``ConvServer._dispatch``'s tracing
         and completion contract."""
-        enabled = self.tracer.enabled
-        t_start = time.perf_counter()
-        for r in group:
-            if r._t_submit:
-                self._h_wait.observe(t_start - r._t_submit)
+        t_start = self._observe_wait(group)
         chain = self._nets[group[0].net]
-        sp = (self.tracer.span("repro.serve.model_dispatch",
-                               server=self._sid)
-              if enabled else _NOOP_SPAN)
-        with sp:
+        with self.tracer.span("repro.serve.model_dispatch") as sp:
             try:
                 total = sum(r._b for r in group)
                 bucket = self._model_bucket(chain, total)
-                z = (group[0].x if len(group) == 1
-                     else jnp.concatenate([r.x for r in group], axis=3))
-                if bucket > total:
-                    z = jnp.pad(
-                        z, ((0, 0), (0, 0), (0, 0), (0, bucket - total)))
+                z = self._gather(group, bucket, total)
                 for lname in chain.layers:
                     fam = self._layers[lname]
                     plan = self._plan(fam, ConvOp.FPROP, bucket)
-                    t_l = time.perf_counter()
-                    lsp = (self.tracer.span("repro.serve.layer_dispatch",
-                                            server=self._sid, net=chain.name,
-                                            layer=lname, bucket=bucket)
-                           if enabled else _NOOP_SPAN)
-                    with lsp:
+                    with self.tracer.span("repro.serve.layer_dispatch") as lsp:
+                        if lsp:
+                            lsp.set(layer=lname, bucket=bucket)
                         z = plan.execute(z, fam.flt)
                         if chain.activation is not None:
                             z = chain.activation(z)
-                        if enabled:
-                            jax.block_until_ready(z)
-                    layer_s = time.perf_counter() - t_l
-                    self._h_layer.observe(layer_s)
-                    if (enabled and plan.choice is not None
-                            and plan.exec_scene is not None):
-                        self.drift.observe(
-                            drift_mod.scene_class(plan.exec_scene,
-                                                  plan.choice),
-                            plan.predicted_s, layer_s)
             except BaseException as e:  # noqa: BLE001 — propagated to every
-                # waiter in the group (r.error below), not swallowed
-                for r in group:
-                    r.error, r.done = e, True
-                    if r._event is not None:
-                        r._event.set()
+                # waiter in the group (r.error), then re-raised
+                self._fail(group, e)
                 raise
-            off = 0
-            for r in group:
-                sl = z[..., off:off + r._b]
-                off += r._b
-                r.out = sl[..., 0] if r._squeeze else sl
-                r.done = True
-                if r._event is not None:
-                    r._event.set()
-            self._c_requests.inc(len(group))
-            self._c_dispatches.inc()
-            self._c_occupied.inc(total)
-            self._c_bucket.inc(bucket)
-            self._h_dispatch.observe(time.perf_counter() - t_start)
-            self._h_occupancy.observe(total / bucket)
-            sp.set(layer=group[0].layer, op=ConvOp.FPROP.value,
-                   bucket=bucket, occupied=total, requests=len(group),
-                   schedule=None, net=chain.name, layers=len(chain.layers))
-        if not enabled:
-            self._publish(DispatchRecord(
-                layer=group[0].layer, op=ConvOp.FPROP, bucket=bucket,
-                occupied=total, requests=len(group), schedule=None))
+            self._complete(group, z, bucket, total, t_start)
+            if sp:
+                sp.set(net=chain.name, bucket=bucket, occupied=total,
+                       requests=len(group))
+        self._publish(DispatchRecord(
+            layer=group[0].layer, op=ConvOp.FPROP, bucket=bucket,
+            occupied=total, requests=len(group), schedule=None))
         return len(group)
-
-    def _span_sink(self, span: Span) -> None:
-        a = span.args
-        if (span.name == "repro.serve.model_dispatch"
-                and a.get("server") == self._sid and "layer" in a):
-            self._publish(DispatchRecord(
-                layer=a["layer"], op=ConvOp(a["op"]), bucket=a["bucket"],
-                occupied=a["occupied"], requests=a["requests"],
-                schedule=a.get("schedule")))
-            return
-        super()._span_sink(span)
 
     # -- serving loops -------------------------------------------------------
     def drain(self) -> int:
@@ -622,7 +575,18 @@ class ConvScheduler(ConvServer):
             with self._lock:
                 if not self._queue:
                     return served
+            self._poll()
+
+    def _poll(self) -> None:
+        """Sleep ``poll_s`` while nothing is flush-ready, under a
+        ``repro.serve.poll`` span (args: the queue depth); the seconds
+        slept go to ``repro.serve.poll_s``."""
+        with self.tracer.span("repro.serve.poll") as sp:
+            if sp:
+                sp.set(depth=len(self._queue))
+            t0 = time.perf_counter()
             time.sleep(self.config.poll_s)
+            self._h_poll.observe(time.perf_counter() - t0)
 
     def wait(self, requests: Sequence[ConvRequest], *,
              raise_on_error: bool = True) -> List[Optional[jax.Array]]:
@@ -660,7 +624,7 @@ class ConvScheduler(ConvServer):
         while not self._stop_evt.is_set():
             try:
                 if self.step() == 0:
-                    time.sleep(self.config.poll_s)
+                    self._poll()
             except Exception:  # noqa: BLE001 — the failed group's waiters
                 # already carry the error (step completed them before
                 # re-raising); the loop must keep serving everyone else

@@ -16,8 +16,8 @@ def _isolated_tune_artifacts(tmp_path, monkeypatch):
     tune.set_default_cache(None)
     tune.set_active_cost_model(None)
     plan.set_default_registry(None)
-    # fresh process-global obs state per test: counters from one test (or a
-    # lingering tracer subscriber) must never leak into another's assertions
+    # fresh process-global obs state per test: counters from one test (or an
+    # enabled default tracer) must never leak into another's assertions
     set_default_metrics(None)
     set_default_tracer(None)
     set_default_monitor(None)

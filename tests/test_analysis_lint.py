@@ -73,36 +73,43 @@ def test_dynamic_metric_name_not_checked():
 # hot-path-alloc
 # --------------------------------------------------------------------------
 def test_allocation_in_disabled_path_flagged():
+    # span args are evaluated before the span knows nothing records
     assert _codes("""
-        def _dispatch(self):
-            if not self.enabled:
-                tags = [1, 2]
-    """) == ["hot-path-alloc"]
+        def _dispatch(self, group):
+            with self.tracer.span("repro.serve.dispatch", tags=[1, 2],
+                                  what=f"{len(group)}"):
+                pass
+    """, hot_path=True) == ["hot-path-alloc", "hot-path-alloc"]
 
 
 def test_stray_call_and_lock_in_disabled_path_flagged():
     found = _codes("""
-        def _dispatch(self):
-            if not enabled:
-                with self._lock:
-                    self.log("x")
-    """)
-    assert found == ["hot-path-alloc", "hot-path-alloc"]
+        def _dispatch(self, group, extra):
+            with self.tracer.span("repro.serve.dispatch", n=len(group),
+                                  head=group[0], **extra) as sp:
+                pass
+    """, hot_path=True)
+    assert found == ["hot-path-alloc"] * 3
 
 
 def test_allowlisted_publish_in_disabled_path_passes():
+    # literals, names and attribute loads are free; computed args go on
+    # the live span
     assert _codes("""
-        def _dispatch(self):
-            if not self.enabled:
-                self._publish(DispatchRecord(n=len(group)))
-    """) == []
+        def _prewarm(self, fam, stage):
+            with self.tracer.span("repro.serve.prewarm", layer=fam.layer,
+                                  stage=stage, cold=True) as sp:
+                if sp:
+                    sp.set(n=len(fam.buckets), tags=[1, 2])
+    """, hot_path=True) == []
 
 
 def test_unguarded_branch_not_checked():
+    # outside the serving/plan layers span args are not checked
     assert _codes("""
-        def _dispatch(self):
-            if self.enabled:
-                tags = [1, 2]
+        def _tune(self, scene):
+            with self.tracer.span("repro.tune.scene", scene=scene.describe()):
+                pass
     """) == []
 
 

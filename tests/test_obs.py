@@ -1,7 +1,10 @@
-"""Observability layer: thread-safe metrics, span tracing with Chrome-trace
-export, cost-model drift flagging, and the serving integration — span-stream
-``DispatchRecord`` emission, hook-error containment, windowed stats, and the
-no-span-allocation guarantee of the disabled-tracing hot path."""
+"""Observability layer: thread-safe metrics, span tracing on the profiler's
+clock with Chrome-trace export, cost-model drift flagging, the profiler-trace
+report, and the serving integration — direct ``DispatchRecord`` emission,
+hook-error containment, windowed stats, no device sync under tracing, and
+the no-span-allocation guarantee of the untraced hot path."""
+import collections
+import glob
 import importlib.util
 import json
 import math
@@ -16,12 +19,15 @@ import repro.obs.trace as trace_mod
 from repro.core.mapping import ai_band, class_key, select_schedule
 from repro.core.scene import ConvScene
 from repro.obs import (DriftMonitor, MetricRegistry, Tracer, default_metrics,
-                       default_monitor, scene_class, set_default_tracer,
-                       snapshot_delta, snapshot_value)
+                       default_monitor, profiler_recording, scene_class,
+                       set_default_tracer, snapshot_delta, snapshot_value)
 from repro.obs.metrics import (DEFAULT_RATIO_BUCKETS, histogram_percentile,
                                summarize_histogram)
-from repro.serve import ConvRequest, server_from_scenes
+from repro.serve import (ConvRequest, ConvScheduler, SchedConfig,
+                         server_from_scenes)
 from repro.tune.autotune import error_summary
+
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
 def _load_script(name):
@@ -152,7 +158,7 @@ def test_dump_and_obsreport_metrics(tmp_path):
     m.counter("repro.serve.dispatches").inc(4)
     m.counter("repro.serve.occupied_lanes").inc(10)
     m.counter("repro.serve.bucket_lanes").inc(16)
-    m.histogram("repro.serve.dispatch_s").observe(2e-3)
+    m.histogram("repro.serve.enqueue_s").observe(2e-3)
     mon = DriftMonitor(threshold=0.5, min_samples=1,
                        metrics=MetricRegistry())
     mon.observe("TB88|compute|hi", 1.0, 10.0)
@@ -164,7 +170,7 @@ def test_dump_and_obsreport_metrics(tmp_path):
     assert report["serving"]["occupancy"] == pytest.approx(10 / 16)
     assert report["serving"]["pad_waste_pct"] == pytest.approx(100 * 6 / 16)
     assert report["drift"]["flagged"] == ["TB88|compute|hi"]
-    assert report["histograms"]["repro.serve.dispatch_s"]["count"] == 1
+    assert report["histograms"]["repro.serve.enqueue_s"]["count"] == 1
 
 
 # -- tracing -----------------------------------------------------------------
@@ -219,22 +225,82 @@ def test_tracer_disabled_is_shared_noop_and_decorator():
 
 
 def test_span_stream_subscribers_and_ring_buffer():
+    """The span stream is the buffer alone: there are no subscribers (the
+    serving layer publishes its records directly), and the ring buffer
+    keeps the newest spans, counting what fell off."""
+    assert not hasattr(Tracer, "subscribe")
     tr = Tracer(enabled=True, max_events=3)
-    seen = []
-    bad = tr.subscribe(lambda span: 1 / 0)   # a broken sink must be inert
-    tr.subscribe(seen.append)
     for i in range(5):
-        with tr.span("repro.test.s", i=i):
-            pass
-    assert [s.args["i"] for s in seen] == list(range(5))
-    assert all(s.dur >= 0 for s in seen)
-    # ring buffer keeps the newest, counts the drops
-    assert [e["args"]["i"] for e in tr.events()] == [2, 3, 4]
+        with tr.span("repro.test.s", i=i) as sp:
+            sp.set(square=i * i)
+    events = tr.events()
+    assert [e["args"]["i"] for e in events] == [2, 3, 4]
+    assert [e["args"]["square"] for e in events] == [4, 9, 16]
+    assert all(e["dur"] >= 0 for e in events)
+    # exported ts is µs on the wall clock, in start order
+    assert [e["ts"] for e in events] == sorted(e["ts"] for e in events)
     assert tr.dropped_events == 2
-    tr.unsubscribe(bad)
-    tr.unsubscribe(seen.append)   # not the same object: silently ignored
     tr.clear()
     assert len(tr) == 0 and tr.dropped_events == 0
+
+
+def _xplane(trace_dir):
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    start = next(dict(p.stats)["profile_start_time"] for p in pd.planes
+                 if p.name == "Task Environment")
+    host = [(i, ev) for p in pd.planes if p.name.startswith("/host:CPU")
+            for i, line in enumerate(p.lines) for ev in line.events]
+    return start, host
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_lands_on_profiler_host_plane(tmp_path, enabled):
+    """While a profiler session records, a span is a host event of the
+    trace on the calling thread, with its name and args, whether or not
+    the tracer buffers it; a buffered span's exported ts is the event's
+    start on the same clock (profile start + offset) to within 50 µs."""
+    tr = Tracer(enabled=enabled)
+    assert not profiler_recording()
+
+    def worker():
+        with tr.span("repro.test.worker", k=2):
+            with tr.span("repro.test.inner"):
+                pass
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert profiler_recording()
+        with tr.span("repro.test.main", k=1, tag="x") as sp:
+            sp.set(late=7)
+            th = threading.Thread(target=worker)
+            th.start()
+            th.join(timeout=30)
+    finally:
+        jax.profiler.stop_trace()
+    assert not th.is_alive()
+    assert not profiler_recording()
+    start, host = _xplane(tmp_path)
+    ev = {e.name: (line, e) for line, e in host
+          if e.name.startswith("repro.test.")}
+    assert set(ev) == {"repro.test.main", "repro.test.worker",
+                       "repro.test.inner"}
+    assert dict(ev["repro.test.main"][1].stats) == {"k": 1, "tag": "x",
+                                                    "late": 7}
+    assert dict(ev["repro.test.worker"][1].stats) == {"k": 2}
+    # the thread: the worker's spans share a line that is not main's
+    assert ev["repro.test.worker"][0] == ev["repro.test.inner"][0]
+    assert ev["repro.test.worker"][0] != ev["repro.test.main"][0]
+    if not enabled:
+        assert len(tr) == 0
+        return
+    exported = {e["name"]: e for e in tr.events()}
+    for name, (_, e) in ev.items():
+        t_ns = start + e.start_ns
+        assert abs(exported[name]["ts"] * 1e3 - t_ns) < 50e3, name
+        assert abs(exported[name]["dur"] * 1e3 - e.duration_ns) < 50e3, name
 
 
 # -- drift -------------------------------------------------------------------
@@ -285,19 +351,32 @@ def test_error_summary_excludes_nonfinite():
 
 # -- serving integration -----------------------------------------------------
 def test_traced_burst_spans_records_and_drift(tmp_path):
+    """A traced burst spans every stage (submit, step, dispatch, glue on
+    both sides, the plan's execute), the dispatch spans agree with the
+    published records, and serving feeds no drift monitor: without a
+    device sync there is no kernel time to audit the cost model with."""
     tr = Tracer(enabled=True)
+    set_default_tracer(tr)     # the plan's spans go to the default tracer
     records = []
     server = _server(tracer=tr, on_dispatch=records.append)
     outs = server.serve(_reqs(6))
     assert len(outs) == 6
-    # DispatchRecords arrived via the span stream; both agree on totals
     spans = [e for e in tr.events() if e["name"] == "repro.serve.dispatch"]
     assert len(spans) == len(records) >= 1
     assert sum(r.requests for r in records) == 6
-    assert all(e["args"]["schedule"] == records[0].schedule for e in spans)
-    assert all(e["args"]["exec_s"] > 0 for e in spans)
-    # honest (blocked) exec timings streamed into the drift monitor
-    assert sum(s.n for s in server.drift.stats().values()) == len(spans)
+    assert [(e["args"]["bucket"], e["args"]["occupied"],
+             e["args"]["requests"]) for e in spans] == [
+        (r.bucket, r.occupied, r.requests) for r in records]
+    count = collections.Counter(e["name"] for e in tr.events())
+    assert count["repro.serve.submit"] == 6
+    assert count["repro.serve.step"] == len(spans) + 1   # + the empty one
+    assert count["repro.serve.glue"] == 2 * len(spans)
+    assert count["repro.plan.execute"] == len(spans)
+    by_parent = {e["name"]: e["args"].get("parent") for e in tr.events()}
+    assert by_parent["repro.serve.dispatch"] == "repro.serve.step"
+    assert by_parent["repro.plan.execute"] == "repro.serve.dispatch"
+    assert sum(s.n for s in default_monitor().stats().values()) == 0
+    assert not hasattr(server, "drift")
     # the exported trace parses and covers the dispatch spans
     doc = json.loads(open(tr.export(str(tmp_path / "t.json"))).read())
     assert len([e for e in doc["traceEvents"]
@@ -307,6 +386,8 @@ def test_traced_burst_spans_records_and_drift(tmp_path):
 
 
 def test_two_traced_servers_do_not_cross_publish():
+    """Records are published by the server that dispatched, not by a span
+    stream two servers share."""
     tr = Tracer(enabled=True)
     rec_a, rec_b = [], []
     a = _server(tracer=tr, on_dispatch=rec_a.append)
@@ -315,6 +396,71 @@ def test_two_traced_servers_do_not_cross_publish():
     b.serve(_reqs(3))
     assert sum(r.requests for r in rec_a) == 2
     assert sum(r.requests for r in rec_b) == 3
+    dispatches = [e for e in tr.events()
+                  if e["name"] == "repro.serve.dispatch"]
+    assert len(dispatches) == len(rec_a) + len(rec_b)
+
+
+def _sched_net(tracer, records):
+    """A two-layer chain behind a scheduler that flushes every 4 lanes."""
+    s1 = TINY.with_batch(1)
+    sched = ConvScheduler(max_batch=4, ladder_slack=0.0, tracer=tracer,
+                          on_dispatch=records.append,
+                          config=SchedConfig(occupancy_target=4))
+    sched.register_net("net", {"a": s1, "b": s1}, activation=jax.nn.relu)
+    sched.prewarm()
+    return sched
+
+
+@pytest.mark.parametrize("mode", ["enabled", "profiler"])
+def test_tracing_never_blocks_dispatch(tmp_path, monkeypatch, mode):
+    """With the tracer enabled, or a profiler session recording, neither
+    dispatch path waits on the device, and the published DispatchRecords
+    are those of an untraced run."""
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(1)
+        return real(x)
+
+    def run(tracer):
+        records = []
+        server = _server(tracer=tracer, on_dispatch=records.append)
+        sched = _sched_net(tracer, records)
+        monkeypatch.setattr(jax, "block_until_ready", counting)
+        try:
+            server.serve(_reqs(6))
+            sess = sched.session("net")
+            sess.serve([r.x for r in _reqs(8, seed=20)])
+        finally:
+            monkeypatch.setattr(jax, "block_until_ready", real)
+        return records
+
+    untraced = run(Tracer(enabled=False))
+    assert calls == []
+    tr = Tracer(enabled=mode == "enabled")
+    set_default_tracer(tr)     # the plan's spans go to the default tracer
+    if mode == "profiler":
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        traced = run(tr)
+    finally:
+        if mode == "profiler":
+            jax.profiler.stop_trace()
+    assert calls == [], "a traced dispatch waited on the device"
+    assert traced == untraced
+    assert {r.layer for r in traced} == {"l0", "@net"}
+    if mode == "enabled":
+        names = {e["name"] for e in tr.events()}
+        assert {"repro.serve.model_dispatch", "repro.serve.layer_dispatch",
+                "repro.serve.dispatch", "repro.serve.glue",
+                "repro.plan.execute"} <= names
+    else:
+        _, host = _xplane(tmp_path)
+        layers = [e for _, e in host
+                  if e.name == "repro.serve.layer_dispatch"]
+        assert {dict(e.stats)["layer"] for e in layers} == {"a", "b"}
 
 
 @pytest.mark.parametrize("traced", [False, True])
@@ -358,9 +504,9 @@ def test_stats_windowing_replaces_manual_arithmetic():
 
 
 def test_disabled_tracing_serving_path_allocates_no_spans(monkeypatch):
-    """Overhead guard: with tracing disabled the serving path must not
-    construct a single span handle — the contract the <=2% overhead budget
-    rests on."""
+    """Overhead guard: with the tracer disabled and no profiler session the
+    serving paths (per-layer and whole-model, polls included) must not
+    construct a single span handle — every span site costs one check."""
     allocs = []
     real = trace_mod._SpanHandle
 
@@ -374,10 +520,16 @@ def test_disabled_tracing_serving_path_allocates_no_spans(monkeypatch):
     server = _server()
     baseline = len(allocs)   # prewarm may trace nothing either, but be exact
     server.serve(_reqs(6))
+    records = []
+    sched = _sched_net(None, records)
+    sched.session("net").serve([r.x for r in _reqs(3, seed=30)])
     assert len(allocs) == baseline == 0
     assert server.stats()["requests"] == 6
-    # cheap counters/histograms still work without tracing
-    assert server.snapshot()["repro.serve.dispatch_s"]["count"] >= 1
+    assert sum(r.requests for r in records) == 3
+    # cheap counters/histograms still work without tracing; three requests
+    # under an occupancy target of four wait out the gather in polls
+    assert server.snapshot()["repro.serve.enqueue_s"]["count"] >= 1
+    assert sched.snapshot()["repro.serve.poll_s"]["count"] >= 1
 
 
 def test_module_level_instrumentation_records_to_default_metrics():
@@ -399,3 +551,71 @@ def test_tune_drift_feed_via_autotune():
     mon = default_monitor()
     assert sum(s.n for s in mon.stats().values()) == 1
     assert default_metrics().value("repro.tune.scenes_tuned") == 1.0
+
+
+# -- profiler-trace report ---------------------------------------------------
+Plane = collections.namedtuple("Plane", "name lines")
+Line = collections.namedtuple("Line", "name events")
+Event = collections.namedtuple("Event", "name start_ns duration_ns")
+
+
+def _ev(name, s, e):
+    return Event(name, s, e - s)
+
+
+def test_obsreport_splits_idle_by_innermost_program_span():
+    """Idle time goes to the loop thread's innermost repro.* span, then to
+    any thread's, then to the shortest runtime event, then to "no host
+    event"; busy plus idle is the window."""
+    device = Plane("/device:TPU:0", [Line("XLA Ops", [
+        _ev("%a = f32[1] add(f32[1] %x)", 100, 200),
+        _ev("%b = f32[1] add(f32[1] %x)", 600, 700)])])
+    loop = Line("python", [
+        _ev("repro.serve.step", 150, 400),
+        _ev("repro.serve.glue", 220, 260),
+        _ev("repro.serve.poll", 400, 480)])
+    other = Line("python", [
+        _ev("repro.serve.submit", 250, 300),      # under loop's step: loses
+        _ev("repro.serve.submit", 480, 520)])     # nothing on loop: wins
+    runtime = Line("python", [
+        _ev("DevicePut", 500, 560), _ev("ExecuteHelper", 400, 590)])
+    host = Plane("/host:CPU", [Line("main", [
+        _ev("bench.window", 50, 800)]), other, loop, runtime])
+    r = _load_script("obsreport").xplane_report([device, host])
+    assert r["window_s"] == pytest.approx(750e-9)
+    assert r["busy_s"] == pytest.approx(200e-9)
+    assert r["idle_s"] + r["busy_s"] == pytest.approx(r["window_s"])
+    assert r["idle_by_span"] == pytest.approx({
+        "no host event": (100 - 50 + 800 - 700 + 600 - 590) * 1e-9,
+        "repro.serve.glue": 40e-9,
+        "repro.serve.step": (400 - 200 - 40) * 1e-9,
+        "repro.serve.poll": 80e-9,
+        "repro.serve.submit": 40e-9,
+        "DevicePut": 40e-9,
+        "ExecuteHelper": 30e-9})
+    assert r["unattributed_s"] == pytest.approx((160 + 70) * 1e-9)
+    assert r["loop_thread"] == "python"
+    assert sum(r["idle_by_span"].values()) == pytest.approx(r["idle_s"])
+
+
+def test_obsreport_on_recorded_chip_trace(capsys):
+    """On a trace recorded on a TPU v5e (VGG passes, before the program
+    had spans): busy is what the benchmark's reduction reads, busy plus
+    idle is the window, and no idle time is under a program span."""
+    obsreport = _load_script("obsreport")    # puts the repo root on the path
+    from bench import trace_reduce
+    path = os.path.join(ROOT, "bench", "tests", "data",
+                        "vgg16_fprop_v5e.xplane.pb")
+    r = obsreport.xplane_file_report(path)
+    red = trace_reduce.reduce_file(path)
+    assert r["busy_s"] == red["busy_s"]
+    assert r["window_s"] == pytest.approx(red["window_s"])
+    assert r["busy_s"] + r["idle_s"] == pytest.approx(r["window_s"])
+    assert r["idle_s"] > 0 and r["loop_thread"] is None
+    assert r["unattributed_s"] == pytest.approx(r["idle_s"])
+    assert sum(r["idle_by_span"].values()) == pytest.approx(r["idle_s"])
+    assert not any(n.startswith("repro.") for n in r["idle_by_span"])
+    # the directory form finds the same file; the CLI prints the split
+    assert obsreport.main([os.path.dirname(path), "--json"]) == 0
+    doc = json.loads(capsys.readouterr().out)
+    assert doc["busy_s"] == r["busy_s"]

@@ -378,7 +378,11 @@ def test_slo_report_and_layer_trace(tmp_path):
     slo = report["slo"]
     assert slo["deadline_requests"] == 3
     assert slo["flushes"]["deadline"] + slo["flushes"]["gather_timeout"] >= 1
-    assert "layer_dispatch" in slo and slo["layer_dispatch"]["count"] >= 2
+    # one enqueue per dispatch; the gather until the deadline flush polls
+    assert slo["enqueue"]["count"] == slo["flushes"]["deadline"] + \
+        slo["flushes"]["gather_timeout"] + slo["flushes"]["occupancy"]
+    assert slo["poll"]["count"] >= 1
+    assert "layer_dispatch" not in slo and "dispatch" not in slo
 
     tpath = tmp_path / "trace.json"
     tracer.export(str(tpath))
