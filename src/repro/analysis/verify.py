@@ -6,10 +6,15 @@ block shapes, index maps — see ``kernels/mg3m_conv.kernel_grid_spec``).
 This module abstractly evaluates that spec over the full grid, vectorized
 with numpy broadcasting over sparse coordinate axes, and checks:
 
-  (a) output coverage and disjointness — the output blocks written by the
-      parallel subgrid tile the output exactly once, and no reduction axis
-      moves the output block (a moved block means a lost accumulation);
-  (b) operand index maps in bounds, and — on lhs-dilated scenes — sentinel
+  (a) output coverage and disjointness — the output blocks (strips of
+      ``spec.strip`` columns on TB11/TB18) written by the parallel subgrid
+      tile the output exactly once, and no reduction axis moves the output
+      block (a moved block means a lost accumulation);
+  (b) operand index maps in bounds (element offsets, whether the map
+      returns block indices or ``pl.Element`` offsets), and every pixel of
+      a strip reads its own tap: pixel ``p`` reads window column
+      ``p * stdW``, which must be the specification's column for output
+      column ``strip * ow + p``.  On lhs-dilated scenes, sentinel
       resolution: every dilation-hole / out-of-range tap reads exactly the
       designated zero row/col, every live tap reads its real element.  The
       expected map is *recomputed here from the scene definition*, on
@@ -21,7 +26,8 @@ with numpy broadcasting over sparse coordinate axes, and checks:
       (fp32-or-wider float);
   (e) grid-step and MAC agreement with the cost model's closed forms
       (``mapping.grid_steps`` / ``scene.macs``), so the tuner's search
-      space, the cost model, and the kernels cannot silently disagree.
+      space, the cost model, and the kernels cannot silently disagree.  The
+      cost model counts pixel-steps: grid steps x strip width.
 
 Findings are data (``Finding``), never exceptions: the verifier's job is
 to report every violated property of a geometry, including geometries the
@@ -41,7 +47,7 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 import jax.numpy as jnp
 
-from repro.analysis.footprint import vmem_bytes
+from repro.analysis.footprint import launch_vmem_bytes
 from repro.core.mapping import VMEM_BUDGET, ScheduleChoice, grid_steps
 from repro.core.scene import ConvScene
 from repro.kernels.mg3m_conv import KernelGridSpec, kernel_grid_spec
@@ -152,6 +158,59 @@ def _table_on_grid(table: np.ndarray, grid: Tuple[int, ...],
     return np.broadcast_to(t.reshape(shape), grid)
 
 
+def _check_strip_pixel(out: List[Finding], finding, scene: ConvScene,
+                       p: int, *, got_h: np.ndarray, got_w: np.ndarray,
+                       want_h: np.ndarray, want_w: np.ndarray,
+                       live_g: np.ndarray) -> None:
+    """Compare the input row/col strip pixel ``p`` reads at every grid
+    step with the specification's, appending findings to ``out``.  Dense
+    route: exact agreement.  Sentinel route: a *live* tap (both axes land
+    on stored elements) must read exactly its real (row, col); a *dead* tap
+    (either axis is a dilation hole / out of range) must read zeros, i.e.
+    point at least one coordinate at the zero sentinel — matching the
+    kernel's combined H-and-W liveness is not required, matching zeroness
+    is."""
+    px = f" (strip pixel {p})" if p else ""
+    if scene.dilH == 1 and scene.dilW == 1:
+        for dim, got, want in ((0, got_h, want_h), (1, got_w, want_w)):
+            neq = got != want
+            if neq.any():
+                c = _first_coord(neq)
+                out.append(finding(
+                    "index-map-mismatch",
+                    f"input spatial index dim {dim} at grid{c}{px} is "
+                    f"{int(got[c])}, specification says {int(want[c])}"))
+        return
+    sent_h, sent_w = scene.inH, scene.inW
+    at_sent = (got_h == sent_h) | (got_w == sent_w)
+    dropped = live_g & at_sent
+    if dropped.any():
+        c = _first_coord(dropped)
+        out.append(finding(
+            "dropped-tap",
+            f"live tap at grid{c}{px} resolves to the zero sentinel "
+            f"({sent_h}, {sent_w}) instead of row/col "
+            f"({int(want_h[c])}, {int(want_w[c])}); its "
+            f"contribution is dropped"))
+    mism = live_g & ~at_sent & ((got_h != want_h) | (got_w != want_w))
+    if mism.any():
+        c = _first_coord(mism)
+        out.append(finding(
+            "index-map-mismatch",
+            f"live tap at grid{c}{px} reads "
+            f"({int(got_h[c])}, {int(got_w[c])}), specification "
+            f"says ({int(want_h[c])}, {int(want_w[c])})"))
+    miss = ~live_g & ~at_sent
+    if miss.any():
+        c = _first_coord(miss)
+        out.append(finding(
+            "sentinel-miss",
+            f"dilation-hole/out-of-range tap at grid{c}{px} reads "
+            f"live ({int(got_h[c])}, {int(got_w[c])}) instead of "
+            f"the zero sentinel row/col; the hole contributes "
+            f"garbage"))
+
+
 # --------------------------------------------------------------------------
 # the checks
 # --------------------------------------------------------------------------
@@ -191,12 +250,18 @@ def check_spec(spec: KernelGridSpec, *, vmem_budget: int = VMEM_BUDGET,
             f"reduction_extents {spec.reduction_extents} disagree with the "
             f"grid's reduction dims {got_red}; the kernel body would "
             f"init/store on the wrong reduction step"))
-    oh_ow = tuple(spec.grid[d] for d in spec.spatial_dims)
+    oh_ow = (spec.grid[spec.spatial_dims[0]],
+             spec.grid[spec.spatial_dims[1]] * spec.strip)
     if oh_ow != (scene.outH, scene.outW):
         out.append(finding(
             "grid-structure",
-            f"grid spatial extents {oh_ow} != scene output "
-            f"({scene.outH}, {scene.outW})"))
+            f"grid spatial extents {oh_ow} (strip {spec.strip}) != scene "
+            f"output ({scene.outH}, {scene.outW})"))
+    if spec.out_block[:2] != (1, spec.strip):
+        out.append(finding(
+            "grid-structure",
+            f"output spatial block {spec.out_block[:2]} is not one row of "
+            f"a {spec.strip}-column strip"))
     taps = tuple(spec.grid[d] for d in spec.tap_dims)
     if taps != (scene.fltH, scene.fltW):
         out.append(finding(
@@ -271,29 +336,29 @@ def check_spec(spec: KernelGridSpec, *, vmem_budget: int = VMEM_BUDGET,
                 f"only {uniq.size} of {n_tiles} output blocks are written; "
                 f"uncovered output stays uninitialized"))
 
-    # (b) operand bounds
-    for nm, idx, blocks, shape in (("input", i_idx, spec.in_block,
+    # (b) operand bounds, on element offsets
+    i_off = [c if spec.in_elements else c * b
+             for c, b in zip(i_idx, spec.in_block)]
+    f_off = [c * b for c, b in zip(f_idx, spec.flt_block)]
+    for nm, off, blocks, shape in (("input", i_off, spec.in_block,
                                     spec.in_shape),
-                                   ("filter", f_idx, spec.flt_block,
+                                   ("filter", f_off, spec.flt_block,
                                     spec.flt_shape)):
         for d in range(4):
-            bad = (idx[d] < 0) | (idx[d] * blocks[d] + blocks[d] > shape[d])
+            bad = (off[d] < 0) | (off[d] + blocks[d] > shape[d])
             if bad.any():
                 c = _first_coord(bad)
                 out.append(finding(
                     f"{'in' if nm == 'input' else 'flt'}-bounds",
-                    f"{nm} index map dim {d} reads block "
-                    f"{int(idx[d][c])} (x{blocks[d]}) outside the launched "
-                    f"dim {shape[d]} at grid{c}"))
+                    f"{nm} index map dim {d} reads elements "
+                    f"[{int(off[d][c])}, {int(off[d][c]) + blocks[d]}) "
+                    f"outside the launched dim {shape[d]} at grid{c}"))
 
     # (b) contraction / tiling alignment: the K slice both operands read,
     # and the M/N slices operands and output carry, must agree per step
-    pairs = (("contraction K", i_idx[2] * spec.in_block[2],
-              f_idx[2] * spec.flt_block[2]),
-             ("output M", o_idx[2] * spec.out_block[2],
-              f_idx[3] * spec.flt_block[3]),
-             ("output N", o_idx[3] * spec.out_block[3],
-              i_idx[3] * spec.in_block[3]))
+    pairs = (("contraction K", i_off[2], f_off[2]),
+             ("output M", o_idx[2] * spec.out_block[2], f_off[3]),
+             ("output N", o_idx[3] * spec.out_block[3], i_off[3]))
     for nm, a, b in pairs:
         neq = a != b
         if neq.any():
@@ -304,69 +369,27 @@ def check_spec(spec: KernelGridSpec, *, vmem_budget: int = VMEM_BUDGET,
                 f"{int(a[c])} vs {int(b[c])}; the step multiplies/stores "
                 f"mismatched slices"))
 
-    # (b) spatial map vs the recomputed specification.  Correctness
-    # criterion: a *live* tap (both axes land on stored elements) must read
-    # exactly its real (row, col); a *dead* tap (either axis is a dilation
-    # hole / out of range) must read zeros, i.e. point at least one
-    # coordinate at the zero sentinel — matching the kernel's combined
-    # H-and-W liveness is not required, matching zeroness is.
+    # (b) spatial map vs the recomputed specification, pixel by pixel of
+    # the strip (``_check_strip_pixel``).
     want_h_tab, live_h = _expected_spatial(scene, "h")
     want_w_tab, live_w = _expected_spatial(scene, "w")
     live_tabs = (live_h, live_w)
-    spatial_blocks_ok = True
-    for dim in (0, 1):
-        if spec.in_block[dim] != 1:
-            out.append(finding(
-                "grid-structure",
-                f"input spatial block dim {dim} is {spec.in_block[dim]}, "
-                f"expected 1 (one tap row/col per step)"))
-            spatial_blocks_ok = False
-    if spatial_blocks_ok:
+    win = (spec.strip - 1) * scene.stdW + 1
+    if spec.in_block[:2] != (1, win):
+        out.append(finding(
+            "grid-structure",
+            f"input spatial block {spec.in_block[:2]} != (1, {win}): one "
+            f"tap row and the {spec.strip}-column strip's window"))
+    else:
         place = lambda tab, dim: _table_on_grid(  # noqa: E731
             tab, spec.grid, spec.spatial_dims[dim], spec.tap_dims[dim])
-        want_h, want_w = place(want_h_tab, 0), place(want_w_tab, 1)
-        got_h, got_w = i_idx[0], i_idx[1]
-        if scene.dilH == 1 and scene.dilW == 1:
-            for dim, got, want in ((0, got_h, want_h), (1, got_w, want_w)):
-                neq = got != want
-                if neq.any():
-                    c = _first_coord(neq)
-                    out.append(finding(
-                        "index-map-mismatch",
-                        f"input spatial index dim {dim} at grid{c} is "
-                        f"{int(got[c])}, specification says "
-                        f"{int(want[c])}"))
-        else:
-            sent_h, sent_w = scene.inH, scene.inW
-            live_g = place(live_h, 0) & place(live_w, 1)
-            at_sent = (got_h == sent_h) | (got_w == sent_w)
-            dropped = live_g & at_sent
-            if dropped.any():
-                c = _first_coord(dropped)
-                out.append(finding(
-                    "dropped-tap",
-                    f"live tap at grid{c} resolves to the zero sentinel "
-                    f"({sent_h}, {sent_w}) instead of row/col "
-                    f"({int(want_h[c])}, {int(want_w[c])}); its "
-                    f"contribution is dropped"))
-            mism = live_g & ~at_sent & ((got_h != want_h)
-                                        | (got_w != want_w))
-            if mism.any():
-                c = _first_coord(mism)
-                out.append(finding(
-                    "index-map-mismatch",
-                    f"live tap at grid{c} reads "
-                    f"({int(got_h[c])}, {int(got_w[c])}), specification "
-                    f"says ({int(want_h[c])}, {int(want_w[c])})"))
-            miss = ~live_g & ~at_sent
-            if miss.any():
-                c = _first_coord(miss)
-                out.append(finding(
-                    "sentinel-miss",
-                    f"dilation-hole/out-of-range tap at grid{c} reads "
-                    f"live ({int(got_h[c])}, {int(got_w[c])}) instead of "
-                    f"the zero sentinel row/col; the hole contributes "
-                    f"garbage"))
+        want_h = place(want_h_tab, 0)
+        for p in range(spec.strip):
+            _check_strip_pixel(
+                out, finding, scene, p, got_h=i_off[0],
+                got_w=i_off[1] + p * scene.stdW, want_h=want_h,
+                want_w=place(want_w_tab[p::spec.strip], 1),
+                live_g=place(live_h, 0) & place(live_w[p::spec.strip], 1))
 
     # (b) every tap's filter row/col must be inside the fetched flt block
     for dim in (0, 1):
@@ -381,7 +404,8 @@ def check_spec(spec: KernelGridSpec, *, vmem_budget: int = VMEM_BUDGET,
                 f"fetched filter block"))
 
     # (c) VMEM budget — the one shared footprint formula
-    need = vmem_bytes(scene, spec.schedule, *spec.blocks)
+    need = launch_vmem_bytes(scene, spec.schedule, *spec.blocks,
+                             spec.strip)
     if need > vmem_budget:
         out.append(finding(
             "vmem-overshoot",
@@ -400,12 +424,13 @@ def check_spec(spec: KernelGridSpec, *, vmem_budget: int = VMEM_BUDGET,
             f"reduction steps"))
 
     # (e) agreement with the cost model's closed forms
-    steps = int(np.prod(spec.grid))
+    steps = int(np.prod(spec.grid)) * spec.strip
     want_steps = grid_steps(scene, *spec.blocks)
     if steps != want_steps:
         out.append(finding(
             "grid-steps-disagree",
-            f"grid walk has {steps} steps, cost model's closed form says "
+            f"grid walk has {steps} pixel-steps ({spec.strip} a grid "
+            f"step), cost model's closed form says "
             f"{want_steps}; predicted overhead/compute diverge from the "
             f"launch"))
     walk_macs = (scene.M * scene.N * scene.K
@@ -439,7 +464,7 @@ def _spec_for(scene: ConvScene, choice: ScheduleChoice,
     try:
         kspec = kernel_grid_spec(scene, choice.schedule, in_shape=in_shape,
                                  flt_shape=flt_shape, bm=spec.bm, bn=spec.bn,
-                                 bk=spec.bk, vmem_budget=0)
+                                 bk=spec.bk, bw=spec.bw, vmem_budget=0)
     except ValueError as e:
         return None, Finding(
             code="spec-invalid", severity="error", message=str(e),

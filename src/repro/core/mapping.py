@@ -35,7 +35,8 @@ from typing import Dict, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.analysis.footprint import vmem_bytes as _vmem_bytes
+from repro.analysis.footprint import (LANE, SUBLANE, VMEM_BUDGET,
+                                      vmem_bytes as _vmem_bytes)
 from repro.core.scene import ConvScene, ceil_div, round_up
 
 # TPU v5e per-chip peaks (Google Cloud documentation, "TPU v5e": 197
@@ -44,12 +45,7 @@ MXU_FLOPS_BF16 = 197e12
 MXU_FLOPS_FP32 = MXU_FLOPS_BF16 / 2
 HBM_BW = 819e9  # bytes/s
 STEP_OVERHEAD_S = 150e-9 * 0.05  # amortized per-grid-step issue overhead
-VMEM_BYTES = 16 * 2 ** 20
-# Leave headroom for Mosaic's double buffering (the paper's Alg.3 analogue
-# happens automatically: in-flight copies need the second buffer).
-VMEM_BUDGET = 12 * 2 ** 20
-LANE = 128    # minor-dim tile
-SUBLANE = 8   # second-minor tile (fp32)
+VMEM_BYTES = 16 * 2 ** 20  # VMEM_BUDGET (footprint) leaves headroom
 MXU_DIM = 128
 
 # Interconnect constants for mesh-sharded execution (repro.shard).  The
@@ -186,7 +182,10 @@ def _dtype_bytes(dtype: str) -> int:
 
 
 def grid_steps(scene: ConvScene, bm: int, bn: int, bk: int) -> int:
-    """Total Pallas grid steps of a blocked schedule over one scene.
+    """Total pixel-steps of a blocked schedule over one scene: Pallas grid
+    steps at one output pixel a step.  TB11/TB18 launch a strip of ``bw``
+    pixels a step (``footprint.strip_width``), so their grids are ``bw``
+    times shorter; the model still prices pixel-steps.
 
     Deliberately counts *all* ``fltH x fltW`` taps, not the dilation-reduced
     useful taps (``scene.taps_h/taps_w``): the kernels iterate every tap and

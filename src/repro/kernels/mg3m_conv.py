@@ -3,14 +3,24 @@
 Three grid schedules mirror the paper's TB granularities (see
 core/mapping.py for the selection model):
 
-  TB11: grid (outH, outW, fltH, fltW); whole FLT resident in VMEM (fetched
-        from HBM exactly once = the paper's outLen->max filter reuse), IN
-        window streamed per output pixel, fp32 VMEM accumulator revisited
-        across the (fh, fw) reduction steps.
-  TB18: grid (n_m, outH, outW, fltH, fltW); an OC-slice of FLT stays
+  TB11: grid (outH, outW/bw, fltH, fltW); whole FLT resident in VMEM
+        (fetched from HBM exactly once = the paper's outLen->max filter
+        reuse), IN window streamed per strip of ``bw`` output columns, fp32
+        VMEM accumulator revisited across the (fh, fw) reduction steps.
+  TB18: grid (n_m, outH, outW/bw, fltH, fltW); an OC-slice of FLT stays
         resident while the grid sweeps every spatial task.
   TB88: grid (outH, outW, n_m, n_n, fltH, fltW, n_k); classic 2D+K tiled
         GEMM per output pixel.
+
+TB11 and TB18 compute a strip of ``bw`` adjacent output columns per grid
+step (``analysis.footprint.strip_width``: the largest divisor of outW up
+to 32 whose working set fits VMEM).  The step fetches the strip's input
+window for its tap — (bw-1)*stdW + 1 columns addressed by element offset —
+and pixel ``p`` of the strip contracts window column ``p*stdW``, so every
+output pixel accumulates the same dots in the same tap order as a
+one-pixel step; the strip only divides the fixed cost of a grid step by
+``bw``.  The sentinel route keeps ``bw=1``: its holes make a strip's taps
+non-contiguous.
 
 Each launch is described first as a ``KernelGridSpec`` — grid extents,
 block shapes, index maps, dimension semantics — built by
@@ -52,8 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis.footprint import vmem_bytes
-from repro.core.mapping import VMEM_BUDGET
+from repro.analysis.footprint import (VMEM_BUDGET, launch_vmem_bytes,
+                                      strip_width)
 from repro.core.scene import ConvScene, ceil_div
 from repro.kernels import interpret_mode
 
@@ -102,7 +112,12 @@ class KernelGridSpec:
     must not move it) and ``reduction_extents`` (the sizes the kernel body
     compares ``program_id`` against to detect the first/last reduction
     step).  The index maps take grid coordinates in grid order and return
-    *block* indices (Pallas convention: element offset = index * block)."""
+    *block* indices (Pallas convention: element offset = index * block),
+    except ``in_index`` when ``in_elements``: then it returns the element
+    offsets of the strip's input window (``pl.Element`` block dims).
+    ``strip`` is the number of output columns one step computes (``bw``):
+    the output block is ``strip`` columns wide and pixel ``p`` reads input
+    window column ``p * scene.stdW``."""
 
     schedule: str
     scene: ConvScene
@@ -121,8 +136,16 @@ class KernelGridSpec:
     reduction_extents: Tuple[int, ...]
     spatial_dims: Tuple[int, int]   # grid axes carrying (oh, ow)
     tap_dims: Tuple[int, int]       # grid axes carrying the (i, j) filter tap
-    acc_shape: Tuple[int, int]
+    acc_shape: Tuple[int, ...]
     acc_dtype: Any = jnp.float32
+    strip: int = 1
+
+    @property
+    def in_elements(self) -> bool:
+        """The input window is addressed by element offset: the dense
+        route of TB11/TB18 (the sentinel route and TB88 take blocks)."""
+        return (self.schedule != "TB88" and self.scene.dilH == 1
+                and self.scene.dilW == 1)
 
     @property
     def blocks(self) -> Tuple[int, int, int]:
@@ -140,12 +163,14 @@ def _require(cond: bool, msg: str) -> None:
 
 def kernel_grid_spec(scene: ConvScene, schedule: str, *, in_shape: Shape4,
                      flt_shape: Shape4, bm: int = 0, bn: int = 0,
-                     bk: int = 0,
+                     bk: int = 0, bw: int = 0,
                      vmem_budget: int = 0) -> KernelGridSpec:
     """Build the launch geometry for ``schedule`` over ``scene`` given the
     operand shapes exactly as they will be passed to the kernel (spatially
     pre-padded or sentinel-extended input, channel/batch-aligned dims — see
-    ``plan/build._conv_body``).
+    ``plan/build._conv_body``).  ``bw`` is the TB11/TB18 strip width (0:
+    ``footprint.strip_width``'s choice); TB88 and the sentinel route only
+    take 1.
 
     Validates divisibility of the launched dims by the blocking and, when
     ``vmem_budget`` > 0, that the blocking's working set fits it (the same
@@ -159,42 +184,63 @@ def kernel_grid_spec(scene: ConvScene, schedule: str, *, in_shape: Shape4,
              f"{scene.describe()}")
     at = _in_index_map(scene)
     oh_ow = (scene.outH, scene.outW)
+    dense = scene.dilH == 1 and scene.dilW == 1
+
+    if schedule in ("TB11", "TB18"):
+        if schedule == "TB11":
+            bm = m
+        bw = bw or strip_width(scene, schedule, bm, n, k)
+        _require(bw == 1 or dense,
+                 f"strip width {bw} on the sentinel route of "
+                 f"{scene.describe()}: only 1 is contiguous")
+        _require(scene.outW % bw == 0,
+                 f"strip width {bw} must divide outW={scene.outW} for "
+                 f"{scene.describe()}")
+        # Dense: the strip's window by element offset, (bw-1)*stdW + 1
+        # columns from the strip's first pixel's tap.  Sentinel (bw=1):
+        # one column, block index = element offset.
+        in_block = (1, (bw - 1) * scene.stdW + 1, k, n)
+
+        def strip_in(oh, ow, i, j):
+            return (*at(oh, ow * bw, i, j), 0, 0)
+
+        strip_fields = dict(strip=bw, acc_shape=(bw, bm, n))
+    else:
+        _require(bw in (0, 1), f"{schedule} takes no strip (bw={bw})")
 
     if schedule == "TB11":
         spec = KernelGridSpec(
             schedule="TB11", scene=scene,
-            grid=(*oh_ow, fh, fw),
+            grid=(scene.outH, scene.outW // bw, fh, fw),
             in_shape=in_shape, flt_shape=flt_shape,
             out_shape=(*oh_ow, m, n),
-            in_block=(1, 1, k, n), flt_block=(fh, fw, k, m),
-            out_block=(1, 1, m, n),
-            in_index=lambda oh, ow, i, j: (*at(oh, ow, i, j), 0, 0),
+            in_block=in_block, flt_block=(fh, fw, k, m),
+            out_block=(1, bw, m, n),
+            in_index=strip_in,
             flt_index=lambda oh, ow, i, j: (0, 0, 0, 0),
             out_index=lambda oh, ow, i, j: (oh, ow, 0, 0),
             dimension_semantics=("parallel", "parallel",
                                  "arbitrary", "arbitrary"),
             reduction_dims=(2, 3), reduction_extents=(fh, fw),
-            spatial_dims=(0, 1), tap_dims=(2, 3),
-            acc_shape=(m, n))
+            spatial_dims=(0, 1), tap_dims=(2, 3), **strip_fields)
     elif schedule == "TB18":
         _require(bm > 0 and m % bm == 0,
                  f"TB18 OC slice bm={bm} must divide the launched OC dim "
                  f"{m} for {scene.describe()}")
         spec = KernelGridSpec(
             schedule="TB18", scene=scene,
-            grid=(m // bm, *oh_ow, fh, fw),
+            grid=(m // bm, scene.outH, scene.outW // bw, fh, fw),
             in_shape=in_shape, flt_shape=flt_shape,
             out_shape=(*oh_ow, m, n),
-            in_block=(1, 1, k, n), flt_block=(fh, fw, k, bm),
-            out_block=(1, 1, bm, n),
-            in_index=lambda mm, oh, ow, i, j: (*at(oh, ow, i, j), 0, 0),
+            in_block=in_block, flt_block=(fh, fw, k, bm),
+            out_block=(1, bw, bm, n),
+            in_index=lambda mm, oh, ow, i, j: strip_in(oh, ow, i, j),
             flt_index=lambda mm, oh, ow, i, j: (0, 0, 0, mm),
             out_index=lambda mm, oh, ow, i, j: (oh, ow, mm, 0),
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary"),
             reduction_dims=(3, 4), reduction_extents=(fh, fw),
-            spatial_dims=(1, 2), tap_dims=(3, 4),
-            acc_shape=(bm, n))
+            spatial_dims=(1, 2), tap_dims=(3, 4), **strip_fields)
     elif schedule == "TB88":
         _require(bm > 0 and bn > 0 and bk > 0
                  and m % bm == 0 and n % bn == 0 and k % bk == 0,
@@ -222,22 +268,36 @@ def kernel_grid_spec(scene: ConvScene, schedule: str, *, in_shape: Shape4,
         raise ValueError(f"unknown schedule {schedule!r}")
 
     if vmem_budget > 0:
-        need = vmem_bytes(scene, schedule, *spec.blocks)
+        need = launch_vmem_bytes(scene, schedule, *spec.blocks,
+                                 spec.strip)
         _require(need <= vmem_budget,
-                 f"{schedule} blocking {spec.blocks} needs {need} B of VMEM "
-                 f"(budget {vmem_budget} B) for {scene.describe()}")
+                 f"{schedule} blocking {spec.blocks} x strip {spec.strip} "
+                 f"needs {need} B of VMEM (budget {vmem_budget} B) for "
+                 f"{scene.describe()}")
     return spec
 
 
-def _launch(spec: KernelGridSpec, kernel, inp: jax.Array, flt: jax.Array, *,
+def _launch(spec: KernelGridSpec, inp: jax.Array, flt: jax.Array, *,
             interpret: bool) -> jax.Array:
     """One ``pl.pallas_call`` from a ``KernelGridSpec`` — the only place
     the three schedules turn geometry into a launch."""
+    if spec.schedule == "TB88":
+        kernel = functools.partial(_tb88_kernel,
+                                   red_dims=spec.reduction_extents,
+                                   out_dtype=inp.dtype)
+    else:
+        kernel = functools.partial(_strip_kernel, tap_dims=spec.tap_dims,
+                                   flt_hw=spec.reduction_extents,
+                                   col_stride=spec.scene.stdW,
+                                   out_dtype=inp.dtype)
+    in_block = spec.in_block
+    if spec.in_elements:  # every dim an Element, as Mosaic requires
+        in_block = tuple(pl.Element(d) for d in in_block)
     return pl.pallas_call(
         kernel,
         grid=spec.grid,
         in_specs=[
-            pl.BlockSpec(spec.in_block, spec.in_index),
+            pl.BlockSpec(in_block, spec.in_index),
             pl.BlockSpec(spec.flt_block, spec.flt_index),
         ],
         out_specs=pl.BlockSpec(spec.out_block, spec.out_index),
@@ -267,12 +327,16 @@ def _dot_kt(flt_blk: jax.Array, in_blk: jax.Array) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# TB11: whole-FLT residency
+# TB11 (whole-FLT residency) and TB18 (OC-sliced FLT residency): one body
 # --------------------------------------------------------------------------
-def _tb11_kernel(in_ref, flt_ref, out_ref, acc_ref, *, flt_hw: Tuple[int, int],
-                 out_dtype):
-    fh = pl.program_id(2)
-    fw = pl.program_id(3)
+def _strip_kernel(in_ref, flt_ref, out_ref, acc_ref, *,
+                  tap_dims: Tuple[int, int], flt_hw: Tuple[int, int],
+                  col_stride: int, out_dtype):
+    """One (strip, tap) step: pixel ``p`` of the strip contracts input
+    window column ``p * col_stride`` against the tap's (K, bm) filter
+    slice into its own accumulator tile."""
+    fh = pl.program_id(tap_dims[0])
+    fw = pl.program_id(tap_dims[1])
     first = jnp.logical_and(fh == 0, fw == 0)
     last = jnp.logical_and(fh == flt_hw[0] - 1, fw == flt_hw[1] - 1)
 
@@ -280,59 +344,36 @@ def _tb11_kernel(in_ref, flt_ref, out_ref, acc_ref, *, flt_hw: Tuple[int, int],
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    flt_blk = flt_ref[fh, fw]          # (K, M) dynamic-sliced from resident FLT
-    in_blk = in_ref[0, 0]              # (K, N)
-    acc_ref[...] += _dot_kt(flt_blk, in_blk)
+    flt_blk = flt_ref[fh, fw]          # (K, bm) dynamic-sliced from resident FLT
+    # Unrolled on purpose: as a rolled fori_loop the strip ran VGG-16 at
+    # B=128 2.6x slower on a TPU v5e, and Mosaic takes no partial unroll.
+    for p in range(acc_ref.shape[0]):
+        acc_ref[p] += _dot_kt(flt_blk, in_ref[0, p * col_stride])
 
     @pl.when(last)
     def _store():
-        out_ref[0, 0] = acc_ref[...].astype(out_dtype)
+        out_ref[0] = acc_ref[...].astype(out_dtype)
 
 
 def conv_tb11(inp: jax.Array, flt: jax.Array, scene: ConvScene, *,
-              interpret: Optional[bool] = None) -> jax.Array:
+              bw: int = 0, interpret: Optional[bool] = None) -> jax.Array:
     """inp pre-padded (or compact+sentinel when lhs-dilated, see module doc);
-    returns [outH, outW, M, N].  ``interpret`` None derives the kernel mode
-    from the platform (``repro.kernels.interpret_mode``); tests pass False
-    to compile for a described chip."""
+    returns [outH, outW, M, N].  ``bw`` is the strip width (0: the
+    footprint's choice).  ``interpret`` None derives the kernel mode from
+    the platform (``repro.kernels.interpret_mode``); tests pass False to
+    compile for a described chip."""
     spec = kernel_grid_spec(scene, "TB11", in_shape=inp.shape,
-                            flt_shape=flt.shape, vmem_budget=VMEM_BUDGET)
-    kernel = functools.partial(_tb11_kernel, flt_hw=spec.reduction_extents,
-                               out_dtype=inp.dtype)
-    return _launch(spec, kernel, inp, flt,
-                   interpret=interpret_mode(interpret))
-
-
-# --------------------------------------------------------------------------
-# TB18: OC-sliced FLT residency
-# --------------------------------------------------------------------------
-def _tb18_kernel(in_ref, flt_ref, out_ref, acc_ref, *, flt_hw: Tuple[int, int],
-                 out_dtype):
-    fh = pl.program_id(3)
-    fw = pl.program_id(4)
-    first = jnp.logical_and(fh == 0, fw == 0)
-    last = jnp.logical_and(fh == flt_hw[0] - 1, fw == flt_hw[1] - 1)
-
-    @pl.when(first)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += _dot_kt(flt_ref[fh, fw], in_ref[0, 0])
-
-    @pl.when(last)
-    def _store():
-        out_ref[0, 0] = acc_ref[...].astype(out_dtype)
+                            flt_shape=flt.shape, bw=bw,
+                            vmem_budget=VMEM_BUDGET)
+    return _launch(spec, inp, flt, interpret=interpret_mode(interpret))
 
 
 def conv_tb18(inp: jax.Array, flt: jax.Array, scene: ConvScene, *, bm: int,
-              interpret: Optional[bool] = None) -> jax.Array:
+              bw: int = 0, interpret: Optional[bool] = None) -> jax.Array:
     spec = kernel_grid_spec(scene, "TB18", in_shape=inp.shape,
-                            flt_shape=flt.shape, bm=bm,
+                            flt_shape=flt.shape, bm=bm, bw=bw,
                             vmem_budget=VMEM_BUDGET)
-    kernel = functools.partial(_tb18_kernel, flt_hw=spec.reduction_extents,
-                               out_dtype=inp.dtype)
-    return _launch(spec, kernel, inp, flt,
-                   interpret=interpret_mode(interpret))
+    return _launch(spec, inp, flt, interpret=interpret_mode(interpret))
 
 
 # --------------------------------------------------------------------------
@@ -363,7 +404,4 @@ def conv_tb88(inp: jax.Array, flt: jax.Array, scene: ConvScene, *, bm: int,
     spec = kernel_grid_spec(scene, "TB88", in_shape=inp.shape,
                             flt_shape=flt.shape, bm=bm, bn=bn, bk=bk,
                             vmem_budget=VMEM_BUDGET)
-    kernel = functools.partial(_tb88_kernel, red_dims=spec.reduction_extents,
-                               out_dtype=inp.dtype)
-    return _launch(spec, kernel, inp, flt,
-                   interpret=interpret_mode(interpret))
+    return _launch(spec, inp, flt, interpret=interpret_mode(interpret))

@@ -51,6 +51,7 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.footprint import strip_width
 from repro.core.mapping import ScheduleChoice, select_schedule
 from repro.core.scene import ConvScene, round_up
 from repro.kernels import mg3m_conv as kernels
@@ -151,6 +152,7 @@ class ExecSpec:
     sentinel: bool = False  # lhs-dilated: compact input + zero sentinel
     out_h: int = 0         # spatial slice-back extents (0 = full output;
     out_w: int = 0         # wgrad trims stride-remainder rows/cols)
+    bw: int = 1            # output columns per grid step (TB11/TB18 strip)
 
 
 def derive_exec_spec(scene: ConvScene, choice: ScheduleChoice,
@@ -167,11 +169,13 @@ def derive_exec_spec(scene: ConvScene, choice: ScheduleChoice,
                  out_h=oh, out_w=ow)
     if choice.schedule == "TB11":
         return ExecSpec("TB11", m, n, k, scene.padH, scene.padW, m, n, k,
-                        m, n, **extra)
+                        m, n, bw=strip_width(scene, "TB11", m, n, k),
+                        **extra)
     if choice.schedule == "TB18":
         bm = min(choice.bm, m)
         return ExecSpec("TB18", bm, n, k, scene.padH, scene.padW,
-                        round_up(m, bm), n, k, m, n, **extra)
+                        round_up(m, bm), n, k, m, n,
+                        bw=strip_width(scene, "TB18", bm, n, k), **extra)
     bm, bn, bk = min(choice.bm, m), min(choice.bn, n), min(choice.bk, k)
     return ExecSpec("TB88", bm, bn, bk, scene.padH, scene.padW,
                     round_up(m, bm), round_up(n, bn), round_up(k, bk),
@@ -327,11 +331,11 @@ def _conv_body(inp: jax.Array, flt: jax.Array, scene: ConvScene,
                               (spec.pad_w, spec.pad_w + spec.apad_w),
                               (0, 0), (0, 0)))
     if spec.schedule == "TB11":
-        out = kernels.conv_tb11(inp_p, flt, scene)
+        out = kernels.conv_tb11(inp_p, flt, scene, bw=spec.bw)
     elif spec.schedule == "TB18":
         flt_a = _pad_axis(flt, 3, spec.mp)
-        out = kernels.conv_tb18(inp_p, flt_a, scene,
-                                bm=spec.bm)[:, :, :spec.m, :]
+        out = kernels.conv_tb18(inp_p, flt_a, scene, bm=spec.bm,
+                                bw=spec.bw)[:, :, :spec.m, :]
     else:
         inp_a = _pad_axis(_pad_axis(inp_p, 2, spec.kp), 3, spec.np_)
         flt_a = _pad_axis(_pad_axis(flt, 2, spec.kp), 3, spec.mp)
@@ -428,7 +432,8 @@ class ConvPlan:
     def execute(self, a: jax.Array, b: jax.Array) -> jax.Array:
         """Run the planned op: (inp, flt) for FPROP, (d_out, flt) for DGRAD,
         (inp, d_out) for WGRAD.  Enqueues the kernel under a
-        ``repro.plan.execute`` span (args: the op) and returns without
+        ``repro.plan.execute`` span (args: the op and, on a Pallas plan,
+        ``strip``, the output columns per grid step) and returns without
         waiting for it."""
         a_shape, b_shape, _ = self.io_shapes()
         if a.shape != a_shape or b.shape != b_shape:
@@ -438,6 +443,8 @@ class ConvPlan:
         with default_tracer().span("repro.plan.execute") as sp:
             if sp:
                 sp.set(op=self.op.value)
+                if self.spec is not None:
+                    sp.set(strip=self.spec.bw)
             if self.uses_reference:
                 fn = {ConvOp.FPROP: _ref_fprop, ConvOp.DGRAD: _ref_dgrad,
                       ConvOp.WGRAD: _ref_wgrad}[self.op]
@@ -476,7 +483,8 @@ class ConvPlan:
     def describe(self) -> str:
         how = ("jnp-reference" if self.uses_reference else
                f"{self.choice.schedule}"
-               f"({self.spec.bm}/{self.spec.bn}/{self.spec.bk})")
+               f"({self.spec.bm}/{self.spec.bn}/{self.spec.bk} "
+               f"strip={self.spec.bw})")
         return (f"plan({self.op.value} {how} policy={self.policy} "
                 f"{self.scene.describe()})")
 

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.analysis.verify as verify_mod
 import repro.kernels.mg3m_conv as mg
 from repro.analysis import footprint
 from repro.analysis.verify import (_spec_for, check_spec, sweep_scene,
@@ -97,6 +98,36 @@ def test_mutation_collapsed_output_tiles_overlap():
     assert "out-overlap" in codes
 
 
+def _unscaled_strip_window(spec):
+    """The strip's input window placed at column ``ow * stdW`` instead of
+    ``ow * bw * stdW``: strip ``ow`` overlaps its neighbour's window."""
+    sc = spec.scene
+    return dataclasses.replace(spec, in_index=lambda oh, ow, i, j: (
+        oh * sc.stdH + i * sc.fdilH, ow * sc.stdW + j * sc.fdilW, 0, 0))
+
+
+def _overlapping_strip_store(spec):
+    """The last strip stored over its neighbour's output columns."""
+    last = spec.grid[1] - 1
+    return dataclasses.replace(spec, out_index=lambda oh, ow, i, j: (
+        oh, np.minimum(ow, last - 1), 0, 0))
+
+
+@pytest.mark.parametrize("plant,code", [
+    (_unscaled_strip_window, "index-map-mismatch"),
+    (_overlapping_strip_store, "out-overlap"),
+], ids=["input-window", "output-strip"])
+def test_mutation_strip_overlaps_neighbour(plant, code):
+    sc = ConvScene(B=4, IC=8, OC=16, inH=6, inW=16, fltH=3, fltW=3,
+                   padH=1, padW=1, stdH=2, stdW=2)  # outW 8: two strips of 4
+    spec = mg.kernel_grid_spec(sc, "TB11",
+                               in_shape=(sc.inH + 2, sc.inW + 2, sc.K, sc.N),
+                               flt_shape=sc.flt_shape(), bw=4)
+    assert spec.grid[1] == 2
+    assert check_spec(spec) == []
+    assert code in _codes(check_spec(plant(spec)))
+
+
 def test_mutation_output_moves_with_reduction():
     spec = _spec(DENSE, "TB11")
     bad = dataclasses.replace(
@@ -182,9 +213,12 @@ def test_findings_name_scene_and_schedule():
 # --------------------------------------------------------------------------
 def test_single_footprint_source():
     # selection, tuning-space filter, kernel guard, verifier: same function
+    # (the kernel guard and the verifier through the launch's count, which
+    # adds the strip)
     assert mapping._vmem_bytes is footprint.vmem_bytes
     assert tune_space.vmem_bytes is footprint.vmem_bytes
-    assert mg.vmem_bytes is footprint.vmem_bytes
+    assert mg.launch_vmem_bytes is footprint.launch_vmem_bytes
+    assert verify_mod.launch_vmem_bytes is footprint.launch_vmem_bytes
 
 
 def test_footprint_pinned_bytes():
@@ -204,7 +238,6 @@ def test_footprint_pinned_bytes():
 def test_flagged_geometry_really_diverges():
     # a geometry the verifier rejects computes a wrong answer when it does
     # run — the flag is about real miscomputation, not style
-    import functools
 
     import jax
 
@@ -221,10 +254,7 @@ def test_flagged_geometry_really_diverges():
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     inp = jax.random.normal(k1, sc.in_shape(), jnp.float32)
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
-    kernel = functools.partial(mg._tb11_kernel,
-                               flt_hw=spec.reduction_extents,
-                               out_dtype=inp.dtype)
-    got = mg._launch(bad, kernel, inp, flt, interpret=True)
+    got = mg._launch(bad, inp, flt, interpret=True)
     want = ref.conv_ref(inp, flt, sc)
     assert not np.allclose(np.asarray(got), np.asarray(want),
                            rtol=2e-4, atol=2e-4)

@@ -1,14 +1,22 @@
 """Per-kernel allclose sweeps: every Pallas kernel x shapes x dtypes x
 schedules against the pure-jnp oracle (interpret mode)."""
+import dataclasses
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.footprint import strip_width
 from repro.core.conv import mg3m_conv, mg3m_conv_nhwc
+from repro.core.mapping import ScheduleChoice
 from repro.core.scene import ConvScene
+from repro.kernels import mg3m_conv as K
 from repro.kernels import ref
 from repro.kernels.ops import causal_conv1d_op
+from repro.plan import ConvOp, build, make_plan
 
 SCENES = [
     # (B, IC, OC, inHW, flt, pad, std)
@@ -140,3 +148,114 @@ def test_kernel_dot_precision_follows_dtype(schedule, dtype):
     assert precs
     highest = (jax.lax.Precision.HIGHEST,) * 2
     assert all((p == highest) == (dtype == "float32") for p in precs), precs
+
+
+# --------------------------------------------------------------------------
+# strips of output columns per TB11/TB18 grid step
+# --------------------------------------------------------------------------
+# (schedule, B, IC, OC, inHW, flt, pad, std, fdil, strip widths to launch)
+STRIP_CASES = {
+    "tb11_w14": ("TB11", 8, 8, 16, 14, 3, 1, 1, 1, (14, 7, 2)),
+    "tb11_w16_n1": ("TB11", 1, 8, 16, 16, 3, 1, 1, 1, (16, 4)),
+    "tb11_prime_w7": ("TB11", 8, 8, 16, 7, 3, 1, 1, 1, (7,)),
+    "tb11_stride2": ("TB11", 8, 8, 16, 15, 3, 1, 2, 1, (8, 2)),
+    "tb11_fdil2": ("TB11", 8, 8, 16, 12, 3, 2, 1, 2, (12, 3)),
+    "tb11_k3": ("TB11", 8, 3, 16, 14, 3, 1, 1, 1, (14,)),
+    "tb18_w14": ("TB18", 8, 8, 16, 14, 3, 1, 1, 1, (14, 7)),
+    "tb18_stride2": ("TB18", 8, 8, 16, 15, 3, 1, 2, 1, (8, 4)),
+    "tb18_fdil2_k3": ("TB18", 8, 3, 16, 12, 3, 2, 1, 2, (12,)),
+    "tb18_n128": ("TB18", 128, 8, 16, 6, 3, 1, 1, 1, (6, 3)),
+}
+
+
+def _run_strip(sc, schedule, bw, inp, flt):
+    """The plan's launch of ``sc`` under ``schedule`` at strip width
+    ``bw`` (TB18 slices OC in halves so the slice loop runs too)."""
+    policy = (ScheduleChoice("TB18", sc.M // 2, sc.N, sc.K, 0.0, 0.0, 0.0, 0)
+              if schedule == "TB18" else schedule)
+    spec = dataclasses.replace(make_plan(sc, policy=policy).spec, bw=bw)
+    return np.asarray(build._exec_fprop(inp, flt, sc, spec))
+
+
+@pytest.mark.parametrize("case", sorted(STRIP_CASES))
+def test_strip_matches_oracle_and_one_pixel_steps(case):
+    """A strip computes each pixel with the same dots in the same order as
+    a one-pixel step: bit-identical to ``bw=1``, and the oracle's answer."""
+    schedule, b, ic, oc, hw, f, pad, std, fdil, widths = STRIP_CASES[case]
+    sc = ConvScene(B=b, IC=ic, OC=oc, inH=hw, inW=hw, fltH=f, fltW=f,
+                   padH=pad, padW=pad, stdH=std, stdW=std, fdilH=fdil,
+                   fdilW=fdil)
+    assert sc.outW == widths[0]
+    k1, k2 = jax.random.split(jax.random.PRNGKey(sum(map(ord, case))))
+    inp = jax.random.normal(k1, sc.in_shape(), jnp.float32)
+    flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
+    one = _run_strip(sc, schedule, 1, inp, flt)
+    np.testing.assert_allclose(one, ref.conv_ref(inp, flt, sc),
+                               rtol=1e-5, atol=1e-5)
+    for bw in widths:
+        np.testing.assert_array_equal(_run_strip(sc, schedule, bw, inp, flt),
+                                      one)
+
+
+def test_sentinel_route_keeps_one_pixel_steps():
+    """An lhs-dilated scene (the dgrad of a strided conv) reads a compact
+    input through the zero sentinel: its taps are not contiguous columns,
+    so it launches ``bw=1`` and refuses a strip."""
+    fwd = _scene(4, 8, 16, 9, 3, 1, 2)
+    plan = make_plan(fwd, ConvOp.DGRAD)
+    sc = plan.exec_scene
+    assert sc.dilW == 2 and plan.spec.sentinel and plan.spec.bw == 1
+    assert strip_width(sc, plan.schedule, plan.spec.bm, sc.N, sc.K) == 1
+    in_shape = (sc.inH + 1, sc.inW + 1, sc.K, sc.N)
+    with pytest.raises(ValueError, match="sentinel"):
+        K.kernel_grid_spec(sc, "TB11", in_shape=in_shape,
+                           flt_shape=sc.flt_shape(), bw=sc.outW)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(7))
+    d_out = jax.random.normal(k1, fwd.out_shape(), jnp.float32)
+    flt = jax.random.normal(k2, fwd.flt_shape(), jnp.float32)
+    zero = jnp.zeros(fwd.in_shape(), jnp.float32)
+    _, vjp = jax.vjp(lambda i: ref.conv_ref(i, flt, fwd), zero)
+    np.testing.assert_allclose(plan.execute(d_out, flt), vjp(d_out)[0],
+                               rtol=1e-5, atol=1e-5)
+
+
+# (schedule, bm, bn, bk, strip) of each benchmark layer: the selector's
+# choices are the per-pixel ones, the strip is the footprint's.
+_BENCH_CHOICES = {
+    ("vgg16-fig13", 128): (
+        ("TB11", 64, 128, 3, 32), ("TB11", 64, 128, 64, 32),
+        ("TB11", 128, 128, 64, 28), ("TB11", 128, 128, 128, 28),
+        ("TB11", 256, 128, 128, 14), ("TB11", 256, 128, 256, 8),
+        ("TB11", 512, 128, 256, 2), ("TB18", 256, 128, 512, 2),
+        ("TB18", 256, 128, 512, 2)),
+    **{("allcnn-c", b): (
+        ("TB11", 96, b, 3, 32), ("TB11", 96, b, 96, 32),
+        ("TB11", 96, b, 96, 16), ("TB11", 192, b, 96, 16),
+        ("TB11", 192, b, 192, 16), ("TB11", 192, b, 192, 8),
+        ("TB11", 192, b, 192, 6), ("TB11", 192, b, 192, 6),
+        ("TB11", 10, b, 192, 6)) for b in (1, 8)},
+}
+
+
+def _bench_layer(config, batch, index):
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "configs" / f"{config}.json")
+    layer = json.loads(path.read_text())["layers"][index]
+    return ConvScene(B=batch, IC=layer["IC"], OC=layer["OC"],
+                     inH=layer["in_hw"], inW=layer["in_hw"],
+                     fltH=layer["flt"], fltW=layer["flt"],
+                     padH=layer["pad"], padW=layer["pad"],
+                     stdH=layer["stride"], stdW=layer["stride"])
+
+
+@pytest.mark.parametrize("config,batch,index", [
+    (c, b, i) for (c, b), rows in sorted(_BENCH_CHOICES.items())
+    for i in range(len(rows))])
+def test_bench_layer_choice_and_strip(config, batch, index):
+    """The strip leaves schedule selection alone: every benchmark layer
+    keeps its (schedule, bm, bn, bk), and launches the strip its output
+    width and VMEM allow."""
+    plan = make_plan(_bench_layer(config, batch, index))
+    c = plan.choice
+    assert ((c.schedule, c.bm, c.bn, c.bk, plan.spec.bw)
+            == _BENCH_CHOICES[(config, batch)][index])

@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.autodiff import ModelPlans
 from repro.core.mapping import DEVICE_COST_MODELS
+from repro.core.scene import ConvScene
 from repro.kernels import mg3m_conv as K
 from repro.models.cnn import cnn_chain_scenes, cnn_scenes
 from repro.plan import ConvOp, make_plan
@@ -42,9 +43,10 @@ def _kernel_call(plan):
     """The plan's kernel launch with the kernel mode forced to compile."""
     sc, spec = plan.exec_scene, plan.spec
     if spec.schedule == "TB11":
-        return lambda a, b: K.conv_tb11(a, b, sc, interpret=False)
+        return lambda a, b: K.conv_tb11(a, b, sc, bw=spec.bw,
+                                        interpret=False)
     if spec.schedule == "TB18":
-        return lambda a, b: K.conv_tb18(a, b, sc, bm=spec.bm,
+        return lambda a, b: K.conv_tb18(a, b, sc, bm=spec.bm, bw=spec.bw,
                                         interpret=False)
     return lambda a, b: K.conv_tb88(a, b, sc, bm=spec.bm, bn=spec.bn,
                                     bk=spec.bk, interpret=False)
@@ -54,25 +56,45 @@ def _resnet(layer, batch=8):
     return cnn_chain_scenes("resnet", batch)[f"resnet/L{layer}"]
 
 
-# (case, scene, op, schedule the selector must pick, lhs-dilated exec scene)
+def _conv3(b, ic, oc, hw, stride=1):
+    """A 3x3, pad-1 layer of the benchmark's VGG-16 or All-CNN-C."""
+    return ConvScene(B=b, IC=ic, OC=oc, inH=hw, inW=hw, fltH=3, fltW=3,
+                     padH=1, padW=1, stdH=stride, stdW=stride)
+
+
+# (case, scene, op, schedule the selector must pick, lhs-dilated exec scene,
+#  strip width it must launch or None)
 CASES = {
-    "resnet_L0_fprop": (lambda: _resnet(0), ConvOp.FPROP, "TB11", False),
-    "resnet_L0_dgrad": (lambda: _resnet(0), ConvOp.DGRAD, "TB11", True),
-    "resnet_L0_wgrad": (lambda: _resnet(0), ConvOp.WGRAD, "TB88", False),
-    "resnet_L9_fprop": (lambda: _resnet(9), ConvOp.FPROP, "TB18", False),
+    "resnet_L0_fprop": (lambda: _resnet(0), ConvOp.FPROP, "TB11", False,
+                        None),
+    "resnet_L0_dgrad": (lambda: _resnet(0), ConvOp.DGRAD, "TB11", True, 1),
+    "resnet_L0_wgrad": (lambda: _resnet(0), ConvOp.WGRAD, "TB88", False,
+                        None),
+    "resnet_L9_fprop": (lambda: _resnet(9), ConvOp.FPROP, "TB18", False,
+                        None),
     "alexnet_L0_b128": (lambda: cnn_scenes(128)["alexnet"][0],
-                        ConvOp.FPROP, None, False),
+                        ConvOp.FPROP, None, False, None),
+    "vgg_L1_b128": (lambda: _conv3(128, 64, 64, 224), ConvOp.FPROP, "TB11",
+                    False, 32),
+    "vgg_L5_b128": (lambda: _conv3(128, 256, 256, 56), ConvOp.FPROP, "TB11",
+                    False, 8),
+    "vgg_L7_b128": (lambda: _conv3(128, 512, 512, 28), ConvOp.FPROP, "TB18",
+                    False, 2),
+    "allcnn_L2_b8": (lambda: _conv3(8, 96, 96, 32, stride=2), ConvOp.FPROP,
+                     "TB11", False, 16),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip):
-    scene_fn, op, schedule, dilated = CASES[case]
+    scene_fn, op, schedule, dilated, strip = CASES[case]
     plan = make_plan(scene_fn(), op)
     assert not plan.uses_reference
     if schedule:
         assert plan.schedule == schedule
     assert (plan.exec_scene.dilH > 1) == dilated
+    if strip is not None:
+        assert plan.spec.bw == strip
     shapes = launched_shapes(plan.exec_scene, plan.spec)
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
