@@ -46,8 +46,8 @@ from repro.data.pipeline import SyntheticImages  # noqa: E402
 from repro.kernels.ref import conv_ref  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import data_devices, make_mesh_for  # noqa: E402
-from repro.models.cnn import (cnn_chain_scenes, init_cnn_from_scenes,  # noqa: E402
-                              nhwc_to_plan)
+from repro.models.cnn import (chain_graph, cnn_chain_scenes,  # noqa: E402
+                              init_cnn_from_scenes, nhwc_to_plan)
 from repro.plan import ConvOp, make_plan  # noqa: E402
 from repro.serve.conv import seeded_weights  # noqa: E402
 from repro.serve.sched import ConvScheduler, SchedConfig  # noqa: E402
@@ -286,7 +286,8 @@ def train_phase(args) -> None:
     batches = _batches(scenes, args.seed, 3)
 
     # step 0's gradients, before the jitted step donates the parameters
-    g_plan = _grads(lambda p, b: tc.cnn_loss_fn(p, b, plans, order)[0],
+    graph = chain_graph(order)
+    g_plan = _grads(lambda p, b: tc.cnn_loss_fn(p, b, plans, graph)[0],
                     params, batches[0])
     masks = jax.jit(lambda p, x: relu_masks(p, x, plans, order))(
         params, batches[0]["images"])
@@ -300,7 +301,7 @@ def train_phase(args) -> None:
 
     step = tc.jit_train_step(tc.build_cnn_train_step(
         plans, AdamWConfig(lr=1e-4, warmup_steps=1, total_steps=3),
-        layer_order=order))
+        graph=graph))
     state = tc.init_train_state(params)
     # All three steps run with no schedule resolution (plan-once); step 0
     # compiles the step, steps 1-2 are the steady state.
@@ -371,11 +372,11 @@ def four_chip_phase(args) -> None:
         print(f"shard: train {layer} tags {sharded[layer].shard_tags}")
     n_sharded = sum(t != "-" and not t.endswith(":1")
                     for layer in sharded for t in sharded[layer].shard_tags)
-    order = one.names()
     batches = _batches(scenes, args.seed, 1)
-    g_one = _grads(lambda p, b: tc.cnn_loss_fn(p, b, one, order)[0],
+    graph = chain_graph(one.names())
+    g_one = _grads(lambda p, b: tc.cnn_loss_fn(p, b, one, graph)[0],
                    params, batches[0])
-    g_sh = _grads(lambda p, b: tc.cnn_loss_fn(p, b, sharded, order)[0],
+    g_sh = _grads(lambda p, b: tc.cnn_loss_fn(p, b, sharded, graph)[0],
                   params, batches[0])
     errs = _grad_errs(g_sh, g_one)
     print(f"shard: {n_sharded} directions sharded; grads vs one-chip plans "
@@ -386,7 +387,7 @@ def four_chip_phase(args) -> None:
     losses = []
     for p in (one, sharded):
         step = tc.jit_train_step(tc.build_cnn_train_step(
-            p, opt_cfg, layer_order=order))
+            p, opt_cfg, graph=graph))
         _, metrics = step(tc.init_train_state(
             jax.tree.map(jnp.copy, params)), batches[0])
         losses.append(float(metrics["loss"]))

@@ -2,6 +2,12 @@
 
     python -m repro.launch.train_cnn --smoke [--steps N] [--sharded] \
         [--ckpt-dir DIR] [--metrics-out PATH] [--check-loss]
+    python -m repro.launch.train_cnn --model resnet50 --width 64 --res 224 \
+        --classes 1000 --batch 128 --microbatches 1
+
+The second line trains ResNet-50 v1.5 at its published widths
+(``--width`` is the stem's and the first stage's width; the stages double
+it); smaller ``--width`` and ``--res`` keep its 53-conv depth.
 
 Every fprop/dgrad/wgrad in the run dispatches through a prewarmed
 ``ConvPlan`` (``repro.train.cnn`` over a ``ModelPlans``): plans are built
@@ -35,8 +41,9 @@ from repro.train.optimizer import AdamWConfig
 
 
 def build_model(args):
-    """(params, plans, layer_order) for the requested model/geometry —
-    plans built for the *microbatch* batch size."""
+    """(params, plans, graph) for the requested model/geometry — plans
+    built for the *microbatch* batch size; ``graph`` is the layer graph
+    (None: the relu chain of the plans' layers)."""
     from repro.core.autodiff import make_model_plans
     from repro.models import cnn as M
     mb = args.batch // args.microbatches
@@ -47,6 +54,13 @@ def build_model(args):
                                   n_classes=args.classes, width=args.width)
         plans = M.small_cnn_plans(params, mb, args.res,
                                   policy=args.policy, devices=devices)
+    elif args.model == "resnet50":
+        scenes = M.resnet_scenes(
+            mb, args.res, in_ch=args.channels, stem=args.width,
+            widths=tuple(args.width * 2 ** i for i in range(4)))
+        params = M.init_resnet(key, scenes, n_classes=args.classes)
+        plans = make_model_plans(scenes, policy=args.policy, devices=devices)
+        return params, plans, M.resnet_graph()
     else:
         scenes = M.vgg_style_scenes(
             mb, res=args.res, in_ch=args.channels,
@@ -54,12 +68,13 @@ def build_model(args):
                     (args.width * 4, 2)))
         params = M.init_cnn_from_scenes(key, scenes, n_classes=args.classes)
         plans = make_model_plans(scenes, policy=args.policy, devices=devices)
-    return params, plans, plans.names()
+    return params, plans, None
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", default="small", choices=("small", "vgg"))
+    ap.add_argument("--model", default="small",
+                    choices=("small", "vgg", "resnet50"))
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--res", type=int, default=8)
@@ -90,7 +105,7 @@ def main() -> None:
                          f"--microbatches {args.microbatches}")
 
     m = default_metrics()
-    params, plans, layer_order = build_model(args)
+    params, plans, graph = build_model(args)
     ref_ops = plans.reference_ops
     if ref_ops:
         print(f"reference fallbacks: {ref_ops}")
@@ -99,8 +114,7 @@ def main() -> None:
     buckets = tc.make_grad_buckets(params)
     step_fn = tc.build_cnn_train_step(plans, opt_cfg,
                                       n_microbatches=args.microbatches,
-                                      buckets=buckets,
-                                      layer_order=layer_order)
+                                      buckets=buckets, graph=graph)
     jstep = tc.jit_train_step(step_fn)
     state = tc.init_train_state(params)
     data = SyntheticImages(args.batch, args.res, args.channels,
@@ -117,7 +131,7 @@ def main() -> None:
     def run_step(i):
         batch = jax.tree.map(jnp.asarray, data.batch_at(i))
         t0 = time.perf_counter()
-        new_state, metrics = jstep(state, batch)
+        new_state, metrics = tc.dispatch_step(jstep, state, batch)
         jax.block_until_ready(metrics["loss"])
         tc.observe_step(time.perf_counter() - t0, metrics["loss"],
                         args.batch, m)
@@ -159,8 +173,7 @@ def main() -> None:
         mb_batch = {k: v[:mb] for k, v in
                     jax.tree.map(jnp.asarray, data.batch_at(0)).items()}
         breakdown = tc.profile_step_breakdown(state, mb_batch, plans,
-                                              opt_cfg,
-                                              layer_order=layer_order,
+                                              opt_cfg, graph=graph,
                                               metrics=m)
         fed = tc.feed_drift_from_plans(plans)
         print(f"plan_hit_rate={hit_rate:.3f} "

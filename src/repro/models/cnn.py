@@ -1,19 +1,24 @@
 """CNN zoo for the paper's own evaluation (Fig. 13): AlexNet, VGG, GoogLeNet,
 ResNet, SqueezeNet, YOLO — as lists of convolution *scenes* (the paper
 benchmarks per-layer conv hardware efficiency, not end-to-end accuracy),
-plus runnable trainable classifiers (a small 3-conv CNN and a scenes-backed
-VGG-style net) whose every convolution dispatches through prewarmed
-``ConvPlan`` triples.
+plus runnable trainable classifiers (a small 3-conv CNN, a scenes-backed
+VGG-style net and ResNet-50 v1.5 at its published widths) whose every
+convolution dispatches through prewarmed ``ConvPlan`` triples.
+
+A trainable net is a *layer graph* (``Conv``, ``MaxPool``, ``Bottleneck``,
+``Head`` nodes) walked by one forward, ``cnn_forward_planned``; a relu
+chain of convs is the graph's linear case (``chain_graph``).
 
 Layout discipline: the plan path converts NHWC to the paper's plan layout
-``[H, W, C, B]`` exactly once at model entry and back never — relu, the
-global average pool, and the head all speak plan layout — so a forward or
-training step performs zero per-layer transposes (the seed code transposed
-twice per layer per step).
+``[H, W, C, B]`` exactly once at model entry and back never — relu, batch
+norm, max-pool, the residual adds, the global average pool and the head
+all speak plan layout — so a forward or training step performs zero
+per-layer transposes.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Mapping, Sequence, Tuple
+import dataclasses
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -234,26 +239,217 @@ def small_cnn_forward(p: Params, x: jax.Array, *, use_pallas: bool = False,
     if plans is None:
         plans = small_cnn_plans(p, x.shape[0], x.shape[1],
                                 dtype=str(x.dtype), policy=schedule)
-    return cnn_forward_planned(p, x, plans, layer_order=tuple(_LAYER_STRIDES))
+    return cnn_forward_planned(p, x, plans,
+                               graph=chain_graph(tuple(_LAYER_STRIDES)))
+
+
+# ---------------------------------------------------------------------------
+# Layer graphs and the one plan-layout forward that walks them
+# ---------------------------------------------------------------------------
+BN_EPS = 1e-5
+
+
+@dataclasses.dataclass(frozen=True)
+class Conv:
+    """Conv ``name`` through its plan triple, then training-mode batch norm
+    (params ``<name>.gamma``/``<name>.beta``) when ``bn``, then ReLU when
+    ``relu``."""
+
+    name: str
+    bn: bool = False
+    relu: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
+class MaxPool:
+    """``window``x``window`` max-pool, stride ``stride``, ``pad`` rows and
+    columns of -inf on every side."""
+
+    window: int = 3
+    stride: int = 2
+    pad: int = 1
+
+
+@dataclasses.dataclass(frozen=True)
+class Bottleneck:
+    """Residual block: ``convs`` in sequence, ``proj`` (or the identity) on
+    the block's input, their sum, then ReLU."""
+
+    name: str
+    convs: Tuple[Conv, ...]
+    proj: Optional[Conv] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Head:
+    """Global average pool, then the linear classifier ``head`` (plus the
+    bias ``head_b`` when ``bias``)."""
+
+    bias: bool = False
+
+
+Node = Union[Conv, MaxPool, Bottleneck, Head]
+
+
+def chain_graph(names: Sequence[str]) -> Tuple[Node, ...]:
+    """The relu chain of convs ``names`` and a bias-free head: the graph
+    of ``small_cnn`` and the scenes-backed nets."""
+    return tuple(Conv(n) for n in names) + (Head(),)
+
+
+def batch_norm(z: jax.Array, gamma: jax.Array, beta: jax.Array,
+               eps: float = BN_EPS) -> jax.Array:
+    """Training-mode batch norm in plan layout ``[H, W, C, B]``: per-channel
+    mean and biased variance over H, W and B (no running statistics)."""
+    axes = (0, 1, 3)
+    mean = z.mean(axis=axes, keepdims=True)
+    centred = z - mean
+    var = jnp.square(centred).mean(axis=axes, keepdims=True)
+    scale = gamma.reshape(1, 1, -1, 1) * jax.lax.rsqrt(var + eps)
+    return centred * scale + beta.reshape(1, 1, -1, 1)
+
+
+def max_pool(z: jax.Array, window: int = 3, stride: int = 2,
+             pad: int = 1) -> jax.Array:
+    """Spatial max-pool in plan layout with -inf padding."""
+    return jax.lax.reduce_window(
+        z, -jnp.inf, jax.lax.max,
+        (window, window, 1, 1), (stride, stride, 1, 1),
+        ((pad, pad), (pad, pad), (0, 0), (0, 0)))
+
+
+def _walk(node: Node, p: Params, z: jax.Array, plans) -> jax.Array:
+    """Apply one graph node to a plan-layout activation.  Non-conv ops sit
+    under ``repro.graph.{bn,pool,add,head}`` named scopes, each block
+    under ``repro.graph.<block>``, so a device trace can attribute them."""
+    from repro.core.autodiff import apply_conv
+    if isinstance(node, Conv):
+        z = apply_conv(z, p[node.name], plans[node.name])
+        if node.bn:
+            with jax.named_scope("repro.graph.bn"):
+                z = batch_norm(z, p[node.name + ".gamma"],
+                               p[node.name + ".beta"])
+                return jax.nn.relu(z) if node.relu else z
+        return jax.nn.relu(z) if node.relu else z
+    if isinstance(node, MaxPool):
+        with jax.named_scope("repro.graph.pool"):
+            return max_pool(z, node.window, node.stride, node.pad)
+    if isinstance(node, Bottleneck):
+        with jax.named_scope(f"repro.graph.{node.name}"):
+            y = z
+            for conv in node.convs:
+                y = _walk(conv, p, y, plans)
+            short = z if node.proj is None else _walk(node.proj, p, z, plans)
+            with jax.named_scope("repro.graph.add"):
+                return jax.nn.relu(y + short)
+    if isinstance(node, Head):
+        with jax.named_scope("repro.graph.head"):
+            pooled = z.mean(axis=(0, 1))              # [C, B], plan layout
+            logits = jnp.dot(pooled.T, p["head"],
+                             precision=jax.lax.Precision.HIGHEST)
+            return logits + p["head_b"] if node.bias else logits
+    raise TypeError(f"unknown graph node {node!r}")
 
 
 def cnn_forward_planned(p: Params, x: jax.Array, plans,
-                        layer_order: Sequence[str] = ()) -> jax.Array:
+                        graph: Optional[Sequence[Node]] = None) -> jax.Array:
     """Plan-layout forward shared by every trainable CNN here: one NHWC ->
-    [H,W,C,B] transpose at entry, per-layer ``apply_conv`` + relu with the
-    activation held in plan layout across the whole stack, global average
-    pool over the leading spatial dims, then the linear head.
+    [H,W,C,B] transpose at entry, then the layer ``graph`` walked with the
+    activation held in plan layout, ending in its ``Head``.
 
-    ``plans`` is a ``ModelPlans`` (or any name -> triple mapping);
-    ``layer_order`` defaults to the plans' own layer order.
+    ``plans`` is a ``ModelPlans`` (or any name -> triple mapping).  Without
+    ``graph`` the net is the relu chain of the plans' layers in their own
+    order (``chain_graph``).
     """
-    from repro.core.autodiff import apply_conv
-    names = tuple(layer_order) or tuple(plans)
+    nodes = graph if graph is not None else chain_graph(tuple(plans))
     z = nhwc_to_plan(x)
-    for name in names:
-        z = jax.nn.relu(apply_conv(z, p[name], plans[name]))
-    pooled = z.mean(axis=(0, 1))                  # [C, B] — still plan layout
-    return pooled.T @ p["head"]
+    for node in nodes:
+        z = _walk(node, p, z, plans)
+    return z
+
+
+# ---------------------------------------------------------------------------
+# ResNet v1.5 (He et al., arXiv:1512.03385, Table 1; stride on the 3x3)
+# ---------------------------------------------------------------------------
+RESNET50_BLOCKS = (3, 4, 6, 3)
+RESNET50_WIDTHS = (64, 128, 256, 512)
+
+
+def resnet_scenes(batch: int, res: int = 224, *, in_ch: int = 3,
+                  stem: int = 64,
+                  widths: Sequence[int] = RESNET50_WIDTHS,
+                  blocks: Sequence[int] = RESNET50_BLOCKS,
+                  expansion: int = 4,
+                  dtype: str = "float32") -> Dict[str, ConvScene]:
+    """Every conv scene of a bottleneck ResNet v1.5, in forward order:
+    the 7x7/2 ``stem`` (then a 3x3/2 max-pool), and per stage ``i`` (from
+    1) and block ``j`` (from 0) the 1x1 ``s<i>b<j>.a``, the 3x3
+    ``s<i>b<j>.b`` (stride 2 in the first block of stages 2-4), the 1x1
+    ``s<i>b<j>.c`` to ``expansion`` x the width, and in each stage's first
+    block the 1x1 projection ``s<i>b<j>.proj`` (strided as ``.b``).  The
+    defaults are ResNet-50: 53 convs."""
+    scenes: Dict[str, ConvScene] = {}
+
+    def add(name, ic, oc, hw, f, pad, std):
+        scenes[name] = ConvScene(B=batch, IC=ic, OC=oc, inH=hw, inW=hw,
+                                 fltH=f, fltW=f, padH=pad, padW=pad,
+                                 stdH=std, stdW=std, dtype=dtype)
+        return scenes[name].outH
+
+    hw = add("stem", in_ch, stem, res, 7, 3, 2)
+    hw = (hw + 2 - 3) // 2 + 1                       # the 3x3/2 max-pool
+    ic = stem
+    for i, (n, width) in enumerate(zip(blocks, widths), start=1):
+        for j in range(n):
+            name, std = f"s{i}b{j}", 2 if i > 1 and j == 0 else 1
+            add(f"{name}.a", ic, width, hw, 1, 0, 1)
+            out_hw = add(f"{name}.b", width, width, hw, 3, 1, std)
+            add(f"{name}.c", width, width * expansion, out_hw, 1, 0, 1)
+            if j == 0:
+                add(f"{name}.proj", ic, width * expansion, hw, 1, 0, std)
+            hw, ic = out_hw, width * expansion
+    return scenes
+
+
+def resnet_graph(blocks: Sequence[int] = RESNET50_BLOCKS
+                 ) -> Tuple[Node, ...]:
+    """The layer graph over ``resnet_scenes``' names: stem conv + BN +
+    ReLU, max-pool, the bottleneck blocks (BN after every conv, no ReLU
+    before the residual add), and the biased head."""
+    nodes: List[Node] = [Conv("stem", bn=True), MaxPool()]
+    for i, n in enumerate(blocks, start=1):
+        for j in range(n):
+            b = f"s{i}b{j}"
+            nodes.append(Bottleneck(
+                b, (Conv(f"{b}.a", bn=True), Conv(f"{b}.b", bn=True),
+                    Conv(f"{b}.c", bn=True, relu=False)),
+                Conv(f"{b}.proj", bn=True, relu=False) if j == 0 else None))
+    nodes.append(Head(bias=True))
+    return tuple(nodes)
+
+
+def init_resnet(key, scenes: Mapping[str, ConvScene], n_classes: int = 1000,
+                dtype=jnp.float32) -> Params:
+    """ResNet parameters as torchvision initialises them: He-normal filters
+    (fan-out, ``FLT[h,w,IC,OC]``), BN ``gamma`` 1 and ``beta`` 0, and a
+    head (``head`` ``[C, n_classes]``, ``head_b``) uniform in
+    +-1/sqrt(C)."""
+    items = list(scenes.items())
+    ks = jax.random.split(key, len(items) + 2)
+    p: Params = {}
+    for k, (name, sc) in zip(ks, items):
+        std = (2.0 / (sc.fltH * sc.fltW * sc.OC)) ** 0.5
+        p[name] = (jax.random.normal(k, sc.flt_shape(), jnp.float32)
+                   * std).astype(dtype)
+        p[name + ".gamma"] = jnp.ones((sc.OC,), dtype)
+        p[name + ".beta"] = jnp.zeros((sc.OC,), dtype)
+    width = items[-1][1].OC
+    bound = width ** -0.5
+    p["head"] = jax.random.uniform(ks[-2], (width, n_classes), dtype,
+                                   -bound, bound)
+    p["head_b"] = jax.random.uniform(ks[-1], (n_classes,), dtype,
+                                     -bound, bound)
+    return p
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,8 @@ substrate predated all of it — no CNN training step ever touched a
 
   * ``build_cnn_train_step``: one jittable ``(TrainState, batch) ->
     (TrainState, metrics)`` over a ``ModelPlans`` — forward through
-    ``models.cnn.cnn_forward_planned`` (activations stay in plan layout
-    across the stack), backward through each layer's prewarmed
+    ``models.cnn.cnn_forward_planned`` over a layer graph (activations stay
+    in plan layout across the stack), backward through each layer's prewarmed
     dgrad/wgrad plans via the ``conv_with_plans`` custom_vjp, update via
     the existing pytree-agnostic ``optimizer.adamw_update``.  Microbatch
     gradient accumulation reuses the ``lax.scan`` shape of
@@ -21,7 +21,8 @@ substrate predated all of it — no CNN training step ever touched a
     ``lax.scan(step, state, data, unroll=2)`` with the ``TrainState``
     carry donated — the olmax train-loop shape — so steady state is one
     dispatch per K steps.
-  * host-side instrumentation: ``observe_step`` / ``observe_plan_hit_rate``
+  * host-side instrumentation: ``dispatch_step`` enqueues a step under a
+    ``repro.train.step`` span; ``observe_step`` / ``observe_plan_hit_rate``
     / ``profile_step_breakdown`` record the ``repro.train.*`` metrics, and
     ``feed_drift_from_plans`` streams each plan's (predicted, measured)
     dispatch seconds into the cost-model drift monitor, extending the
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 
 from repro.models.cnn import cnn_forward_planned
 from repro.obs.metrics import MetricRegistry, default_metrics
+from repro.obs.trace import default_tracer
 from repro.train import optimizer as opt
 from repro.train.step import TrainState
 
@@ -60,11 +62,11 @@ def softmax_cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 
 def cnn_loss_fn(params, batch: Dict[str, jax.Array], plans,
-                layer_order: Sequence[str] = ()) -> Tuple[jax.Array, Dict]:
-    """CE loss of the plan-layout forward; batch = {"images" NHWC,
+                graph: Optional[Sequence] = None) -> Tuple[jax.Array, Dict]:
+    """CE loss of the plan-layout forward over the layer ``graph`` (the
+    relu chain of the plans' layers when None); batch = {"images" NHWC,
     "labels" int}.  ``plans`` is nondiff (closed over / static)."""
-    logits = cnn_forward_planned(params, batch["images"], plans,
-                                 layer_order=layer_order)
+    logits = cnn_forward_planned(params, batch["images"], plans, graph=graph)
     loss = softmax_cross_entropy(logits, batch["labels"])
     acc = (logits.argmax(-1) == batch["labels"]).mean()
     return loss, {"accuracy": acc}
@@ -151,10 +153,14 @@ def build_cnn_train_step(plans, opt_cfg: opt.AdamWConfig, *,
                          n_microbatches: int = 1,
                          buckets: Optional[GradBuckets] = None,
                          grad_reduce: Optional[Callable] = None,
-                         layer_order: Sequence[str] = (),
-                         loss_fn: Optional[Callable] = None):
+                         graph: Optional[Sequence] = None,
+                         loss_fn: Optional[Callable] = None,
+                         with_grads: bool = False):
     """Build ``train_step(state, batch) -> (state, metrics)`` over a
-    ``ModelPlans``.
+    ``ModelPlans``; the forward walks ``graph`` (``models.cnn`` layer
+    graph; the relu chain of the plans' layers when None).  ``with_grads``
+    also returns the (reduced, unclipped) gradients the update applied as
+    ``metrics["grads"]``.
 
     Plans are fixed-geometry: build ``plans`` for the *microbatch* size
     (``global_batch // n_microbatches``) — the forward only ever sees one
@@ -171,7 +177,7 @@ def build_cnn_train_step(plans, opt_cfg: opt.AdamWConfig, *,
         raise ValueError(
             f"n_microbatches must be >= 1, got {n_microbatches}")
     lfn = loss_fn if loss_fn is not None else functools.partial(
-        cnn_loss_fn, plans=plans, layer_order=tuple(layer_order))
+        cnn_loss_fn, plans=plans, graph=graph)
 
     def one_microbatch(params, mb):
         (loss, stats), grads = jax.value_and_grad(
@@ -235,6 +241,8 @@ def build_cnn_train_step(plans, opt_cfg: opt.AdamWConfig, *,
         new_params, new_opt, om = opt.adamw_update(
             opt_cfg, state.params, grads, state.opt)
         metrics = dict(om, loss=loss, **stats)
+        if with_grads:
+            metrics["grads"] = grads
         return TrainState(new_params, new_opt), metrics
 
     return train_step
@@ -244,6 +252,13 @@ def jit_train_step(step_fn):
     """One-step jit with the ``TrainState`` buffers donated — params and
     moments update in place instead of doubling live memory."""
     return jax.jit(step_fn, donate_argnums=(0,))
+
+
+def dispatch_step(jstep, state: TrainState, batch):
+    """Enqueue one jitted (or compiled) step under a ``repro.train.step``
+    span and return its outputs without waiting for them."""
+    with default_tracer().span("repro.train.step"):
+        return jstep(state, batch)
 
 
 def build_cnn_train_loop(step_fn, *, unroll: int = 2):
@@ -291,7 +306,7 @@ def observe_plan_hit_rate(registry=None,
 
 def profile_step_breakdown(state: TrainState, batch, plans,
                            opt_cfg: opt.AdamWConfig, *,
-                           layer_order: Sequence[str] = (),
+                           graph: Optional[Sequence] = None,
                            metrics: Optional[MetricRegistry] = None
                            ) -> Dict[str, float]:
     """Time the two halves the fused step welds together — value_and_grad
@@ -300,8 +315,7 @@ def profile_step_breakdown(state: TrainState, batch, plans,
     after warmup; the fused step itself cannot be split from outside jit.
     """
     m = metrics if metrics is not None else default_metrics()
-    lfn = functools.partial(cnn_loss_fn, plans=plans,
-                            layer_order=tuple(layer_order))
+    lfn = functools.partial(cnn_loss_fn, plans=plans, graph=graph)
     grads_fn = jax.jit(lambda p, b: jax.value_and_grad(
         lfn, has_aux=True)(p, b))
     (_, _), grads = grads_fn(state.params, batch)          # compile
