@@ -20,7 +20,11 @@ The model per schedule (see ``core/mapping`` for the schedule semantics):
   TB11  whole FLT + the input window of a strip of ``bw`` output columns
         ((bw-1)*stdW + 1 columns of (K, N)) + (bw, M, N) output;
   TB18  an OC-slice of FLT (bm wide) + the same window + (bw, bm, N) output;
-  TB88  classic (bm x bk) x (bk x bn) GEMM tiles, one pixel a step.
+  TB88  classic (bm x bk) x (bk x bn) GEMM tiles, one pixel a step;
+  WBL   the batch-on-lanes weight gradient (``batch_lanes_vmem_bytes``):
+        the whole dFLT as a resident (taps x IC rows, OC) accumulator, the
+        input window of a strip of ``bw`` dOUT columns and the dOUT strip,
+        with the batch on the lanes.
 
 Streamed operands are double-buffered (x2, the paper's Alg. 3 analogue —
 Mosaic overlaps the next block's DMA with compute), plus a persistent fp32
@@ -41,7 +45,8 @@ import jax.numpy as jnp
 from repro.core.scene import ConvScene, round_up
 
 __all__ = ["VMEM_BUDGET", "STRIP_CAP", "vmem_bytes", "launch_vmem_bytes",
-           "strip_width"]
+           "strip_width", "sublane_rows", "batch_lanes_blocks",
+           "batch_lanes_vmem_bytes", "batch_lanes_strip"]
 
 # Leave headroom for Mosaic's double buffering (the paper's Alg.3 analogue
 # happens automatically: in-flight copies need the second buffer).
@@ -52,13 +57,18 @@ LANE = 128    # minor-dim tile
 SUBLANE = 8   # second-minor tile (fp32)
 
 
+def sublane_rows(itemsize: int) -> int:
+    """Rows of one sublane tile: a sublane holds 32 bits, so 8 rows of
+    f32 and 16 of bf16."""
+    return SUBLANE * 4 // itemsize
+
+
 def _block_bytes(lead: int, rows: int, cols: int, itemsize: int,
                  tiled: bool) -> int:
     """Bytes of a ``(lead, rows, cols)`` block, its minor two dims rounded
-    to the (sublane, lane) tile when ``tiled`` (a sublane holds 32 bits:
-    8 rows of f32, 16 of bf16)."""
+    to the (sublane, lane) tile when ``tiled``."""
     if tiled:
-        rows = round_up(rows, SUBLANE * 4 // itemsize)
+        rows = round_up(rows, sublane_rows(itemsize))
         cols = round_up(cols, LANE)
     return lead * rows * cols * itemsize
 
@@ -110,3 +120,35 @@ def strip_width(scene: ConvScene, schedule: str, bm: int, bn: int,
                 <= VMEM_BUDGET):
             return bw
     return 1
+
+
+def batch_lanes_blocks(scene: ConvScene, bn: int, bw: int):
+    """``(lead, rows, cols)`` of the input window, dOUT strip and resident
+    dFLT blocks of one WBL step over the *forward* ``scene``: IC padded to
+    a sublane tile (``kp``), ``bn`` batch lanes, a strip of ``bw`` dOUT
+    columns whose window spans ``(bw-1)*stdW + fltW`` input columns."""
+    kp = round_up(scene.IC, sublane_rows(jnp.dtype(scene.dtype).itemsize))
+    win = (bw - 1) * scene.stdW + scene.fltW
+    taps = scene.fltH * scene.fltW
+    return ((scene.fltH * win, kp, bn), (bw, scene.OC, bn),
+            (1, taps * kp, scene.OC))
+
+
+def batch_lanes_vmem_bytes(scene: ConvScene, bn: int, bw: int) -> int:
+    """Tiled VMEM bytes of one WBL step: the three blocks double-buffered
+    plus the fp32 dFLT accumulator."""
+    it = jnp.dtype(scene.dtype).itemsize
+    blocks = batch_lanes_blocks(scene, bn, bw)
+    streamed = sum(_block_bytes(*blk, it, True) for blk in blocks)
+    return 2 * streamed + _block_bytes(*blocks[2], 4, True)
+
+
+def batch_lanes_strip(scene: ConvScene, bn: int) -> int:
+    """dOUT columns one WBL step contracts: the largest divisor of
+    ``outW`` up to ``STRIP_CAP`` whose working set fits ``VMEM_BUDGET``;
+    0 when not even one column fits."""
+    for bw in range(min(STRIP_CAP, scene.outW), 0, -1):
+        if (scene.outW % bw == 0
+                and batch_lanes_vmem_bytes(scene, bn, bw) <= VMEM_BUDGET):
+            return bw
+    return 0

@@ -29,6 +29,12 @@ with numpy broadcasting over sparse coordinate axes, and checks:
       space, the cost model, and the kernels cannot silently disagree.  The
       cost model counts pixel-steps: grid steps x strip width.
 
+The WBL weight gradient launches a ``BatchLanesGridSpec`` instead, whose
+grid walks only the contraction; ``check_batch_lanes_spec`` proves that
+every (oh, ow, lane) term of dOUT is visited once and meets every filter
+tap at the input element the definition names, inside the fetched window
+and the launched input, and that the VMEM count is the footprint's.
+
 Findings are data (``Finding``), never exceptions: the verifier's job is
 to report every violated property of a geometry, including geometries the
 kernel constructors would refuse to build.
@@ -47,17 +53,20 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 import jax.numpy as jnp
 
-from repro.analysis.footprint import launch_vmem_bytes
-from repro.core.mapping import VMEM_BUDGET, ScheduleChoice, grid_steps
+from repro.analysis.footprint import (_block_bytes, batch_lanes_vmem_bytes,
+                                      launch_vmem_bytes, sublane_rows)
+from repro.core.mapping import (BATCH_LANES, VMEM_BUDGET, ScheduleChoice,
+                                grid_steps)
 from repro.core.scene import ConvScene
-from repro.kernels.mg3m_conv import KernelGridSpec, kernel_grid_spec
+from repro.kernels.mg3m_conv import (BatchLanesGridSpec, KernelGridSpec,
+                                     batch_lanes_grid_spec, kernel_grid_spec)
 from repro.plan.build import (ConvOp, ConvPlan, derive_exec_spec,
                               grad_filter_scene, grad_input_scene,
                               launched_shapes, _dgrad_blocker, _wgrad_blocker)
 
 __all__ = ["Finding", "verify_point", "verify_choice", "verify_plan",
            "verify_sharded_plan", "sweep_scene", "sweep_scenes",
-           "check_spec"]
+           "check_spec", "check_batch_lanes_spec"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -453,6 +462,150 @@ def check_spec(spec: KernelGridSpec, *, vmem_budget: int = VMEM_BUDGET,
     return out
 
 
+def check_batch_lanes_spec(spec: BatchLanesGridSpec, *,
+                           vmem_budget: int = VMEM_BUDGET,
+                           op: str = "wgrad") -> List[Finding]:
+    """Verify a WBL launch over its forward scene.  Every grid axis is a
+    contraction, so the output block must never move; the dOUT blocks
+    must tile dOUT (every (oh, ow, lane) term once); strip pixel ``p``'s
+    tap (i, j) — window row i, column ``p*col_stride + j`` — must lie in
+    the fetched window and be input element
+    ``(oh*stdH + i, ow*stdW + j)`` on the dOUT pixel's lanes, recomputed
+    here from the scene; the window must stay inside the launched input;
+    and the blocks' VMEM bytes must be ``footprint``'s count, within
+    budget."""
+    scene = spec.scene
+    bn, bw = spec.in_block[3], spec.strip
+    where = f"{BATCH_LANES}@{bn}x{bw} on {scene.describe()}"
+    blocks = (scene.OC, bn, spec.in_block[2])
+
+    def finding(code: str, msg: str) -> Finding:
+        return Finding(code=code, severity="error",
+                       message=f"{msg} [{where}]", scene=scene.describe(),
+                       schedule=BATCH_LANES, blocks=blocks, op=op)
+
+    out: List[Finding] = []
+    kp = spec.in_shape[2]
+    if (spec.taps != (scene.fltH, scene.fltW)
+            or spec.out_shape != (scene.fltH * scene.fltW * kp, scene.OC)
+            or kp < scene.IC):
+        out.append(finding(
+            "dropped-tap",
+            f"taps {spec.taps} into a {spec.out_shape} dFLT at {kp} "
+            f"channel rows do not cover the ({scene.fltH}, {scene.fltW}) "
+            f"filter's {scene.IC} channels"))
+    if spec.dout_block[:2] != (1, bw) or spec.col_stride != scene.stdW:
+        out.append(finding(
+            "grid-structure",
+            f"dOUT block {spec.dout_block[:2]} with column stride "
+            f"{spec.col_stride} is not one row of a {bw}-column strip "
+            f"read {scene.stdW} input columns apart"))
+    if out:
+        return out
+
+    coords = _sparse_coords(spec.grid)
+    o_idx = _eval_map(spec.out_index, coords, spec.grid)
+    if any((c != 0).any() for c in o_idx):
+        out.append(finding(
+            "reduction-dependence",
+            "the dFLT block moves across the grid; every axis contracts "
+            "into it"))
+    d_idx = _eval_map(spec.dout_index, coords, spec.grid)
+    i_off = _eval_map(spec.in_index, coords, spec.grid)
+
+    # every (oh, ow-strip, lane block) of dOUT exactly once
+    exts = tuple(s // b for s, b in zip(spec.dout_shape, spec.dout_block))
+    oob = np.zeros(spec.grid, dtype=bool)
+    for d in range(4):
+        oob |= (d_idx[d] < 0) | (d_idx[d] >= exts[d])
+    if oob.any():
+        out.append(finding(
+            "out-coverage",
+            f"dOUT block index out of range {exts} at "
+            f"grid{_first_coord(oob)}"))
+        return out
+    lin = d_idx[0].astype(np.int64)
+    for d in range(1, 4):
+        lin = lin * exts[d] + d_idx[d]
+    uniq = np.unique(lin)
+    if uniq.size != lin.size or uniq.size != int(np.prod(exts)):
+        out.append(finding(
+            "out-coverage",
+            f"the grid visits {uniq.size} distinct of {int(np.prod(exts))} "
+            f"dOUT blocks in {lin.size} steps; a (pixel, lane) term is "
+            f"dropped or counted twice"))
+
+    # the window inside the launched input
+    for d in range(4):
+        bad = (i_off[d] < 0) | (i_off[d] + spec.in_block[d]
+                                > spec.in_shape[d])
+        if bad.any():
+            c = _first_coord(bad)
+            out.append(finding(
+                "in-bounds",
+                f"input window dim {d} reads elements [{int(i_off[d][c])}, "
+                f"{int(i_off[d][c]) + spec.in_block[d]}) outside the "
+                f"launched dim {spec.in_shape[d]} at grid{c}"))
+    if (i_off[2] != 0).any() or spec.in_block[2] != kp:
+        out.append(finding(
+            "operand-misalign",
+            "the window does not carry every channel row of the input"))
+    lanes = i_off[3] != d_idx[3] * spec.dout_block[3]
+    if lanes.any() or spec.in_block[3] != spec.dout_block[3]:
+        c = _first_coord(lanes) if lanes.any() else ()
+        out.append(finding(
+            "operand-misalign",
+            f"input and dOUT lanes disagree at grid{c}: the batch terms "
+            f"of the contraction are mismatched"))
+
+    # every tap of every strip pixel at the element the definition names:
+    # pixel p reads window rows 0..fh-1 and columns p*col_stride + 0..fw-1
+    fh, fw = spec.taps
+    span = (bw - 1) * spec.col_stride + fw
+    if fh > spec.in_block[0] or span > spec.in_block[1]:
+        out.append(finding(
+            "window-bounds",
+            f"the strip's taps span {fh} rows x {span} columns, the "
+            f"fetched window holds {spec.in_block[:2]}; the last taps "
+            f"read past it"))
+    checks = [("row", 0, i_off[0], d_idx[0] * scene.stdH)]
+    checks += [("column", p, i_off[1] + p * spec.col_stride,
+                (d_idx[1] * bw + p) * scene.stdW) for p in range(bw)]
+    for axis, p, got, want in checks:
+        bad = got != want
+        if bad.any():
+            c = _first_coord(bad)
+            out.append(finding(
+                "index-map-mismatch",
+                f"strip pixel {p}'s tap 0 {axis} at grid{c} is input "
+                f"{axis} {int(got[c])}, the definition says "
+                f"{int(want[c])}"))
+
+    # (c) the blocks' VMEM bytes are the footprint's count
+    it = jnp.dtype(scene.dtype).itemsize
+    streamed = (_block_bytes(spec.in_block[0] * spec.in_block[1],
+                             *spec.in_block[2:], it, True)
+                + _block_bytes(bw, *spec.dout_block[2:], it, True)
+                + _block_bytes(1, *spec.out_block, it, True))
+    got = 2 * streamed + _block_bytes(1, *spec.acc_shape, 4, True)
+    want = batch_lanes_vmem_bytes(scene, bn, bw)
+    if got != want:
+        out.append(finding(
+            "vmem-disagree",
+            f"the launched blocks need {got} B of VMEM, footprint counts "
+            f"{want} B"))
+    if max(got, want) > vmem_budget:
+        out.append(finding(
+            "vmem-overshoot",
+            f"needs {max(got, want)} B of VMEM, budget is {vmem_budget} B"))
+    if kp % sublane_rows(it):
+        out.append(finding(
+            "operand-misalign",
+            f"{kp} channel rows are not whole sublane tiles: the taps' "
+            f"slabs do not stack"))
+    return out
+
+
 # --------------------------------------------------------------------------
 # entry points
 # --------------------------------------------------------------------------
@@ -513,6 +666,20 @@ def verify_plan(plan: ConvPlan, *, vmem_budget: int = VMEM_BUDGET
                      f"choice (got {want_spec}) for {plan.describe()}"),
             scene=scene.describe(), schedule=choice.schedule,
             blocks=(spec.bm, spec.bn, spec.bk), op=plan.op.value)]
+    if choice.schedule == BATCH_LANES:
+        in_shape, dout_shape = launched_shapes(scene, spec)
+        try:
+            kspec = batch_lanes_grid_spec(scene, in_shape=in_shape,
+                                          dout_shape=dout_shape, bn=spec.bn,
+                                          bw=spec.bw)
+        except ValueError as e:
+            return [Finding(code="spec-invalid", severity="error",
+                            message=str(e), scene=scene.describe(),
+                            schedule=BATCH_LANES,
+                            blocks=(spec.bm, spec.bn, spec.bk),
+                            op=plan.op.value)]
+        return check_batch_lanes_spec(kspec, vmem_budget=vmem_budget,
+                                      op=plan.op.value)
     return verify_choice(scene, choice, vmem_budget=vmem_budget,
                          op=plan.op.value)
 

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.footprint import (LANE, SUBLANE, VMEM_BUDGET,
+                                      batch_lanes_blocks, batch_lanes_strip,
+                                      batch_lanes_vmem_bytes,
                                       vmem_bytes as _vmem_bytes)
 from repro.core.scene import ConvScene, ceil_div, round_up
 
@@ -58,6 +60,9 @@ ICI_LATENCY_S = 1e-6             # per collective round (ppermute/psum hop)
 SHARD_LAUNCH_OVERHEAD_S = 5e-6   # per sharded dispatch (shard_map glue)
 
 SCHEDULES = ("TB11", "TB18", "TB88")
+# The batch-on-lanes weight gradient: a route of WGRAD plans over the
+# forward scene, not a grain the selector weighs (``batch_lanes_choice``).
+BATCH_LANES = "WBL"
 
 # Arithmetic-intensity band edges (FLOPs/byte) for cost-model scene classes.
 AI_BAND_EDGES = (8.0, 64.0, 512.0)
@@ -267,6 +272,47 @@ def _score(scene: ConvScene, schedule: str, bm: int, bn: int, bk: int,
     overhead_s = grid_steps(scene, bm, bn, bk) * per_step
     total = max(compute_s, hbm_s) + overhead_s
     return ScheduleChoice(schedule, bm, bn, bk, total, compute_s, hbm_s, vmem)
+
+
+def batch_lanes_choice(scene: ConvScene,
+                       model: Optional[CostModel] = None
+                       ) -> Optional[ScheduleChoice]:
+    """The WBL route for the weight gradient of the *forward* ``scene``,
+    or None when the scene does not take it.
+
+    The swapped wgrad scene of an image-input stem puts IC (3) on the
+    lanes, one output pixel a grid step.  WBL keeps the forward layout and
+    contracts the batch on the lanes instead, so it serves scenes whose IC
+    is under one sublane tile, with dense taps (no dilation), when the
+    resident dFLT and a strip of dOUT columns fit ``VMEM_BUDGET``.  The
+    choice carries bm=OC, bn=the batch lanes of a step (one lane tile),
+    bk=IC padded to a sublane tile; its terms price the grid the kernel
+    launches."""
+    if (scene.IC >= SUBLANE or scene.dilH > 1 or scene.dilW > 1
+            or scene.fdilH > 1 or scene.fdilW > 1
+            or scene.apadH or scene.apadW):
+        return None
+    # Whole lane tiles: Mosaic cannot address the strip's window by
+    # element offset in an array whose minor dim is under 128, so a
+    # smaller batch is zero-padded to one lane tile.
+    bn = LANE
+    bw = batch_lanes_strip(scene, bn)
+    if not bw:
+        return None
+    model = model if model is not None else device_cost_model()
+    it = _dtype_bytes(scene.dtype)
+    (in_lead, kp, _), _, (_, rows, _) = batch_lanes_blocks(scene, bn, bw)
+    nb = ceil_div(scene.B, bn)
+    steps = nb * scene.outH * (scene.outW // bw)
+    pixels = nb * scene.outH * scene.outW
+    macs = pixels * rows * bn * round_up(scene.OC, MXU_DIM)
+    compute_s = 2 * macs / model.mxu_rate(scene.dtype)
+    hbm_s = (steps * in_lead * kp * bn * it + scene.bytes_out()
+             + rows * scene.OC * it) / model.hbm_bw
+    return ScheduleChoice(
+        BATCH_LANES, scene.OC, bn, kp,
+        max(compute_s, hbm_s) + steps * model.step_overhead_s,
+        compute_s, hbm_s, batch_lanes_vmem_bytes(scene, bn, bw))
 
 
 def candidate_blocks(scene: ConvScene, schedule: str) -> Tuple[Tuple[int, int, int], ...]:

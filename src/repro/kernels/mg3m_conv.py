@@ -11,6 +11,10 @@ core/mapping.py for the selection model):
         resident while the grid sweeps every spatial task.
   TB88: grid (outH, outW, n_m, n_n, fltH, fltW, n_k); classic 2D+K tiled
         GEMM per output pixel.
+  WBL:  grid (B/bn, outH, outW/bw); the weight gradient of a forward scene
+        whose IC is under a sublane tile, the dual of TB11: the whole dFLT
+        is a resident accumulator and the grid walks only the contraction
+        (see ``batch_lanes_grid_spec``).
 
 TB11 and TB18 compute a strip of ``bw`` adjacent output columns per grid
 step (``analysis.footprint.strip_width``: the largest divisor of outW up
@@ -62,8 +66,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis.footprint import (VMEM_BUDGET, launch_vmem_bytes,
-                                      strip_width)
+from repro.analysis.footprint import (VMEM_BUDGET, batch_lanes_blocks,
+                                      batch_lanes_vmem_bytes,
+                                      launch_vmem_bytes, strip_width)
 from repro.core.scene import ConvScene, ceil_div
 from repro.kernels import interpret_mode
 
@@ -154,6 +159,80 @@ class KernelGridSpec:
         bn = self.out_block[3]
         bk = self.in_block[2]
         return bm, bn, bk
+
+
+@dataclasses.dataclass(frozen=True)
+class BatchLanesGridSpec:
+    """Launch geometry of the batch-on-lanes weight gradient (WBL) over a
+    *forward* scene, read by ``pl.pallas_call`` and by the static verifier
+    alike (``analysis.verify.check_batch_lanes_spec``).
+
+    Operands keep the forward's plan layout: IN ``[Hp, Wp, kp, Np]``
+    spatially pre-padded with IC padded to ``kp`` (a sublane tile), dOUT
+    ``[outH, outW, OC, Np]``; the output is dFLT as ``[taps*kp, OC]``, rows
+    ordered (fh, fw, ic).  Every grid axis is a contraction: (lane block,
+    dOUT row, strip of ``strip`` dOUT columns).  ``in_index`` returns the
+    element offsets of the strip's input window; ``dout_index`` and
+    ``out_index`` return block indices.  The body contracts, for strip
+    pixel ``p``, window rows ``0..taps[0]-1`` and columns
+    ``p*col_stride .. p*col_stride + taps[1]-1`` against dOUT column
+    ``p``."""
+
+    scene: ConvScene
+    grid: Tuple[int, int, int]
+    in_shape: Shape4
+    dout_shape: Shape4
+    out_shape: Tuple[int, int]
+    in_block: Shape4
+    dout_block: Shape4
+    out_block: Tuple[int, int]
+    in_index: Callable[..., Tuple]
+    dout_index: Callable[..., Tuple]
+    out_index: Callable[..., Tuple]
+    taps: Tuple[int, int]
+    col_stride: int
+    strip: int
+    acc_shape: Tuple[int, int]
+    acc_dtype: Any = jnp.float32
+    dimension_semantics: Tuple[str, ...] = ("arbitrary",) * 3
+
+
+def batch_lanes_grid_spec(scene: ConvScene, *, in_shape: Shape4,
+                          dout_shape: Shape4, bn: int, bw: int,
+                          vmem_budget: int = 0) -> BatchLanesGridSpec:
+    """The WBL launch over forward ``scene`` for operands launched at
+    ``in_shape``/``dout_shape`` (``plan/build.launched_shapes``), ``bn``
+    batch lanes and ``bw`` dOUT columns a step.  Validates divisibility
+    and, when ``vmem_budget`` > 0, the ``analysis.footprint`` count."""
+    kp, n = in_shape[2:]
+    _require(dout_shape == (scene.outH, scene.outW, scene.OC, n),
+             f"dOUT shape {dout_shape} does not match the output of "
+             f"{scene.describe()} at {n} lanes")
+    _require(n % bn == 0 and scene.outW % bw == 0,
+             f"WBL blocking bn={bn}, bw={bw} must divide the launched "
+             f"batch {n} and outW={scene.outW} of {scene.describe()}")
+    (lead, _, _), _, (_, rows, _) = batch_lanes_blocks(scene, bn, bw)
+    _require(kp * scene.fltH * scene.fltW == rows,
+             f"input IC dim {kp} is not IC={scene.IC} padded to a sublane "
+             f"tile for {scene.describe()}")
+    sh, sw = scene.stdH, scene.stdW
+    spec = BatchLanesGridSpec(
+        scene=scene, grid=(n // bn, scene.outH, scene.outW // bw),
+        in_shape=in_shape, dout_shape=dout_shape,
+        out_shape=(rows, scene.OC),
+        in_block=(scene.fltH, lead // scene.fltH, kp, bn),
+        dout_block=(1, bw, scene.OC, bn), out_block=(rows, scene.OC),
+        in_index=lambda nb, oh, st: (oh * sh, st * bw * sw, 0, nb * bn),
+        dout_index=lambda nb, oh, st: (oh, st, 0, nb),
+        out_index=lambda nb, oh, st: (0, 0),
+        taps=(scene.fltH, scene.fltW), col_stride=sw, strip=bw,
+        acc_shape=(rows, scene.OC))
+    if vmem_budget > 0:
+        need = batch_lanes_vmem_bytes(scene, bn, bw)
+        _require(need <= vmem_budget,
+                 f"WBL strip {bw} x {bn} lanes needs {need} B of VMEM "
+                 f"(budget {vmem_budget} B) for {scene.describe()}")
+    return spec
 
 
 def _require(cond: bool, msg: str) -> None:
@@ -405,3 +484,85 @@ def conv_tb88(inp: jax.Array, flt: jax.Array, scene: ConvScene, *, bm: int,
                             flt_shape=flt.shape, bm=bm, bn=bn, bk=bk,
                             vmem_budget=VMEM_BUDGET)
     return _launch(spec, inp, flt, interpret=interpret_mode(interpret))
+
+
+# --------------------------------------------------------------------------
+# WBL: the batch-on-lanes weight gradient
+# --------------------------------------------------------------------------
+def _dot_nt(in_blk: jax.Array, dout_blk: jax.Array) -> jax.Array:
+    """(R, N) x (M, N) -> (R, M) contracting the lane dim N (the batch):
+    the stationary dOUT tile serves every tap row of ``in_blk``."""
+    f32 = in_blk.dtype == jnp.float32 and dout_blk.dtype == jnp.float32
+    return jax.lax.dot_general(
+        in_blk, dout_blk,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _batch_lanes_kernel(in_ref, dout_ref, out_ref, acc_ref, *,
+                        taps: Tuple[int, int], col_stride: int, out_dtype):
+    """One (lane block, dOUT row, strip) step: pixel ``p`` stacks its
+    taps' (kp, bn) input slabs into one (taps*kp, bn) operand and
+    contracts it with dOUT column ``p`` over the batch lanes."""
+    steps = [(pl.program_id(d), pl.num_programs(d)) for d in range(3)]
+    first = functools.reduce(jnp.logical_and, [i == 0 for i, _ in steps])
+    last = functools.reduce(jnp.logical_and,
+                            [i == e - 1 for i, e in steps])
+    fh, fw = taps
+    kp, bn = in_ref.shape[2:]
+
+    @pl.when(first)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # Rolled: each pixel is one (taps*kp, bn) x (bn, OC) dot, large enough
+    # that the loop costs little, and a wide strip compiles quickly.
+    def pixel(p, carry):
+        win = in_ref[pl.ds(0, fh), pl.ds(p * col_stride, fw)]
+        acc_ref[...] += _dot_nt(win.reshape(fh * fw * kp, bn),
+                                dout_ref[0, p])
+        return carry
+
+    jax.lax.fori_loop(0, dout_ref.shape[1], pixel, 0)
+
+    @pl.when(last)
+    def _store():
+        out_ref[...] = acc_ref[...].astype(out_dtype)
+
+
+def _launch_batch_lanes(spec: BatchLanesGridSpec, inp: jax.Array,
+                        d_out: jax.Array, *, interpret: bool) -> jax.Array:
+    kernel = functools.partial(_batch_lanes_kernel, taps=spec.taps,
+                               col_stride=spec.col_stride,
+                               out_dtype=inp.dtype)
+    return pl.pallas_call(
+        kernel,
+        grid=spec.grid,
+        in_specs=[
+            pl.BlockSpec(tuple(pl.Element(d) for d in spec.in_block),
+                         spec.in_index),
+            pl.BlockSpec(spec.dout_block, spec.dout_index),
+        ],
+        out_specs=pl.BlockSpec(spec.out_block, spec.out_index),
+        out_shape=jax.ShapeDtypeStruct(spec.out_shape, inp.dtype),
+        scratch_shapes=[pltpu.VMEM(spec.acc_shape, spec.acc_dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=spec.dimension_semantics),
+        interpret=interpret,
+    )(inp, d_out)
+
+
+def wgrad_batch_lanes(inp: jax.Array, d_out: jax.Array, scene: ConvScene,
+                      *, bn: int, bw: int,
+                      interpret: Optional[bool] = None) -> jax.Array:
+    """dFLT of forward ``scene`` as ``[fltH*fltW*kp, OC]`` from IN
+    ``[Hp, Wp, kp, Np]`` (spatially pre-padded, IC zero-padded to ``kp``)
+    and dOUT ``[outH, outW, OC, Np]``: the sum over (oh, ow, b) of
+    IN[oh*stdH + fh, ow*stdW + fw, ic, b] * dOUT[oh, ow, oc, b]."""
+    spec = batch_lanes_grid_spec(scene, in_shape=inp.shape,
+                                 dout_shape=d_out.shape, bn=bn, bw=bw,
+                                 vmem_budget=VMEM_BUDGET)
+    return _launch_batch_lanes(spec, inp, d_out,
+                               interpret=interpret_mode(interpret))

@@ -33,6 +33,11 @@ forwards included, via the scene's dilation axes):
          (``fdilH/fdilW`` on the wgrad scene); a stride remainder grows
          the conv's spatial output past fltHxfltW, sliced back by the
          executor (``ExecSpec.out_h/out_w``).
+         Forward scenes with IC under a sublane tile (image-input stems)
+         take the WBL route instead under the analytic and tuned policies
+         (``mapping.batch_lanes_choice``): the swap would put IC on the
+         lanes, so WBL keeps the forward layout, contracts the batch on
+         the lanes and executes over the forward scene itself.
 
   The only genuinely inexpressible adjoint left is padding exceeding the
   dilated filter extent minus one (the adjoint's padding would be
@@ -51,8 +56,9 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.analysis.footprint import strip_width
-from repro.core.mapping import ScheduleChoice, select_schedule
+from repro.analysis.footprint import batch_lanes_strip, strip_width
+from repro.core.mapping import (BATCH_LANES, ScheduleChoice,
+                                batch_lanes_choice, select_schedule)
 from repro.core.scene import ConvScene, round_up
 from repro.kernels import mg3m_conv as kernels
 from repro.kernels import ref
@@ -152,7 +158,8 @@ class ExecSpec:
     sentinel: bool = False  # lhs-dilated: compact input + zero sentinel
     out_h: int = 0         # spatial slice-back extents (0 = full output;
     out_w: int = 0         # wgrad trims stride-remainder rows/cols)
-    bw: int = 1            # output columns per grid step (TB11/TB18 strip)
+    bw: int = 1            # output columns per grid step (TB11/TB18 strip,
+                           # WBL: dOUT columns)
 
 
 def derive_exec_spec(scene: ConvScene, choice: ScheduleChoice,
@@ -163,6 +170,11 @@ def derive_exec_spec(scene: ConvScene, choice: ScheduleChoice,
     conv output can exceed the true dFLT spatial dims by the forward's
     stride remainder)."""
     m, n, k = scene.M, scene.N, scene.K
+    if choice.schedule == BATCH_LANES:   # scene is the forward scene
+        bn = choice.bn
+        return ExecSpec(BATCH_LANES, m, bn, choice.bk, scene.padH,
+                        scene.padW, m, round_up(n, bn), choice.bk, m, n,
+                        bw=batch_lanes_strip(scene, bn))
     oh, ow = out_hw if out_hw is not None else (scene.outH, scene.outW)
     extra = dict(apad_h=scene.apadH, apad_w=scene.apadW,
                  sentinel=scene.dilH > 1 or scene.dilW > 1,
@@ -194,6 +206,9 @@ def launched_shapes(scene: ConvScene, spec: ExecSpec
     else:
         ih = scene.inH + 2 * spec.pad_h + spec.apad_h
         iw = scene.inW + 2 * spec.pad_w + spec.apad_w
+    if spec.schedule == BATCH_LANES:     # (IN, dOUT) of the forward scene
+        return ((ih, iw, spec.kp, spec.np_),
+                (scene.outH, scene.outW, scene.OC, spec.np_))
     if spec.schedule == "TB11":
         return ((ih, iw, scene.K, scene.N),
                 (scene.fltH, scene.fltW, scene.K, scene.M))
@@ -360,13 +375,29 @@ def _exec_dgrad(d_out, flt, scene: ConvScene, spec: ExecSpec):
     return _conv_body(a, b, scene, spec)
 
 
+def _batch_lanes_body(inp: jax.Array, d_out: jax.Array, scene: ConvScene,
+                      spec: ExecSpec) -> jax.Array:
+    """WBL dispatch over the forward scene: one pad of IN (spatial, IC to
+    a sublane tile, batch to the lane blocks), the kernel, and the
+    ``[taps*kp, OC]`` result viewed as FLT with the IC padding dropped."""
+    inp_p = jnp.pad(inp, ((spec.pad_h, spec.pad_h), (spec.pad_w, spec.pad_w),
+                          (0, spec.kp - scene.IC), (0, spec.np_ - scene.B)))
+    out = kernels.wgrad_batch_lanes(inp_p, _pad_axis(d_out, 3, spec.np_),
+                                    scene, bn=spec.bn, bw=spec.bw)
+    return out.reshape(scene.fltH, scene.fltW, spec.kp,
+                       scene.OC)[:, :, :scene.IC]
+
+
 @functools.partial(jax.jit, static_argnames=("scene", "spec"))
 def _exec_wgrad(inp, d_out, scene: ConvScene, spec: ExecSpec):
     # scene/spec describe the *wgrad* scene (grad_filter_scene): input with
     # (IC, B) swapped, filter = dOUT with (OC, B) swapped (rhs-dilated by
     # the forward stride), output [fltH(+r), fltW(+r), OC, IC] sliced back
     # to the true filter dims (spec.out_h/out_w, inside _conv_body) and
-    # transposed to the FLT layout.
+    # transposed to the FLT layout.  The WBL route's scene is the forward
+    # scene and takes the operands as they come.
+    if spec.schedule == BATCH_LANES:
+        return _batch_lanes_body(inp, d_out, scene, spec)
     a, b = wgrad_operands(inp, d_out)
     return wgrad_finish(_conv_body(a, b, scene, spec))
 
@@ -433,8 +464,8 @@ class ConvPlan:
         """Run the planned op: (inp, flt) for FPROP, (d_out, flt) for DGRAD,
         (inp, d_out) for WGRAD.  Enqueues the kernel under a
         ``repro.plan.execute`` span (args: the op and, on a Pallas plan,
-        ``strip``, the output columns per grid step) and returns without
-        waiting for it."""
+        ``schedule`` and ``strip``, the output columns per grid step) and
+        returns without waiting for it."""
         a_shape, b_shape, _ = self.io_shapes()
         if a.shape != a_shape or b.shape != b_shape:
             raise ValueError(
@@ -444,7 +475,7 @@ class ConvPlan:
             if sp:
                 sp.set(op=self.op.value)
                 if self.spec is not None:
-                    sp.set(strip=self.spec.bw)
+                    sp.set(schedule=self.spec.schedule, strip=self.spec.bw)
             if self.uses_reference:
                 fn = {ConvOp.FPROP: _ref_fprop, ConvOp.DGRAD: _ref_dgrad,
                       ConvOp.WGRAD: _ref_wgrad}[self.op]
@@ -498,7 +529,10 @@ def make_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
     ``policy``: "analytic" (roofline/calibrated selection), "tuned"
     (schedule-cache resolution, analytic on miss), a forced "TB11"/"TB18"/
     "TB88", or an exact ``ScheduleChoice``.  The legacy spellings ``None``
-    and ``"auto"`` alias "analytic" and "tuned".
+    and ``"auto"`` alias "analytic" and "tuned".  A WGRAD plan whose
+    forward scene qualifies (``mapping.batch_lanes_choice``) takes the WBL
+    route under "analytic" and "tuned", or pinned by a WBL choice; a
+    forced TB grain keeps the swapped scene.
 
     Strided forwards resolve for all three ops (the backward scenes are
     dilated, not reference fallbacks).  A forced policy on an op that
@@ -524,7 +558,11 @@ def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
         notes.append(f"{op.value}: use_pallas=False; jnp reference")
 
     out_hw = None
-    exec_scene: Optional[ConvScene] = scene if op is ConvOp.FPROP else None
+    spec = None
+    choice = _batch_lanes_route(scene, op, policy, tag)
+    # FPROP and the WBL route execute over the forward scene itself
+    exec_scene: Optional[ConvScene] = (
+        scene if op is ConvOp.FPROP or choice is not None else None)
     if op is ConvOp.DGRAD:
         why = _dgrad_blocker(scene)
         if why is None:
@@ -537,7 +575,7 @@ def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
                     f"be honored for this op")
             uses_reference = True
             notes.append(f"dgrad: {why}; exact jnp adjoint instead of Pallas")
-    elif op is ConvOp.WGRAD:
+    elif op is ConvOp.WGRAD and choice is None:
         why = _wgrad_blocker(scene)
         if why is None:
             exec_scene = grad_filter_scene(scene)
@@ -551,12 +589,15 @@ def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
             uses_reference = True
             notes.append(f"wgrad: {why}; exact jnp adjoint instead of Pallas")
 
-    choice = spec = None
-    if not uses_reference:
-        choice = resolve_policy(exec_scene, policy)
+    if uses_reference:
+        choice = None
+    else:
+        choice = choice or resolve_policy(exec_scene, policy)
         spec = derive_exec_spec(exec_scene, choice, out_hw)
     m = default_metrics()
     m.counter("repro.plan.builds").inc()
+    if choice is not None and choice.schedule == BATCH_LANES:
+        m.counter("repro.plan.wgrad_batch_lanes").inc()
     if uses_reference:
         m.counter("repro.plan.reference_fallbacks").inc()
     m.histogram("repro.plan.build_s").observe(time.perf_counter() - t_build)
@@ -564,6 +605,28 @@ def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
                     uses_reference=uses_reference, notes=tuple(notes),
                     exec_scene=None if uses_reference else exec_scene,
                     choice=choice, spec=spec)
+
+
+def _batch_lanes_route(scene: ConvScene, op: ConvOp, policy: PolicySpec,
+                       tag: str) -> Optional[ScheduleChoice]:
+    """The WBL choice when this plan takes that route: a wgrad under the
+    analytic and tuned policies whenever the forward scene qualifies
+    (``mapping.batch_lanes_choice``), and a pinned WBL choice (the
+    registry's reload), which raises where the route does not serve.
+    None otherwise: every forced grain keeps the swapped scene."""
+    pinned = (isinstance(policy, ScheduleChoice)
+              and policy.schedule == BATCH_LANES)
+    if not pinned and (op is not ConvOp.WGRAD
+                       or tag not in ("analytic", "tuned")):
+        return None
+    choice = None
+    if op is ConvOp.WGRAD and _wgrad_blocker(scene) is None:
+        choice = batch_lanes_choice(scene, model=_active_cost_model())
+    if pinned and choice is None:
+        raise ValueError(f"{BATCH_LANES} does not serve the {op.value} of "
+                         f"{scene.describe()}: it computes the wgrad of "
+                         f"IC under a sublane tile with dense taps")
+    return policy if pinned else choice
 
 
 def assemble_plan(scene: ConvScene, op: Union[ConvOp, str], policy: str,

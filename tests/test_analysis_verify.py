@@ -11,13 +11,15 @@ import pytest
 import repro.analysis.verify as verify_mod
 import repro.kernels.mg3m_conv as mg
 from repro.analysis import footprint
-from repro.analysis.verify import (_spec_for, check_spec, sweep_scene,
-                                   sweep_scenes, verify_plan, verify_point)
+from repro.analysis.verify import (_spec_for, check_batch_lanes_spec,
+                                   check_spec, sweep_scene, sweep_scenes,
+                                   verify_plan, verify_point)
 from repro.core import mapping
 from repro.core.mapping import ScheduleChoice
 from repro.core.scene import ConvScene
 from repro.models.cnn import cnn_layer_scenes
 from repro.plan import ConvOp, make_plan
+from repro.plan.build import launched_shapes
 from repro.tune import space as tune_space
 
 DENSE = ConvScene(B=4, IC=8, OC=16, inH=8, inW=8, fltH=3, fltW=3,
@@ -55,6 +57,26 @@ def test_verify_point_clean(scene, schedule):
 @pytest.mark.parametrize("op", list(ConvOp))
 def test_verify_plan_clean_all_ops(op):
     assert verify_plan(make_plan(STRIDED, op)) == []
+
+
+# an image-input stem: its wgrad takes the WBL route (outW 8: two strips)
+STEM = ConvScene(B=8, IC=3, OC=16, inH=16, inW=16, fltH=7, fltW=7,
+                 padH=3, padW=3, stdH=2, stdW=2)
+
+
+def _wbl_spec(scene=STEM, bw=4):
+    plan = make_plan(scene, ConvOp.WGRAD)
+    in_shape, dout_shape = launched_shapes(plan.exec_scene, plan.spec)
+    return mg.batch_lanes_grid_spec(scene, in_shape=in_shape,
+                                    dout_shape=dout_shape, bn=plan.spec.bn,
+                                    bw=bw)
+
+
+def test_verify_plan_clean_batch_lanes():
+    plan = make_plan(STEM, ConvOp.WGRAD)
+    assert plan.schedule == "WBL" and verify_plan(plan) == []
+    for bw in (1, 2, 4, 8):
+        assert check_batch_lanes_spec(_wbl_spec(bw=bw)) == []
 
 
 def test_sweep_scene_covers_all_ops_and_points():
@@ -126,6 +148,69 @@ def test_mutation_strip_overlaps_neighbour(plant, code):
     assert spec.grid[1] == 2
     assert check_spec(spec) == []
     assert code in _codes(check_spec(plant(spec)))
+
+
+def _wbl_window_short(spec):
+    h, w, k, n = spec.in_block
+    return dataclasses.replace(spec, in_block=(h, w - 1, k, n))
+
+
+def _wbl_window_shifted(spec):
+    sc, bw, orig = spec.scene, spec.strip, spec.in_index
+    return dataclasses.replace(spec, in_index=lambda nb, oh, st: (
+        orig(nb, oh, st)[0], st * bw * sc.stdW + 1, 0, nb * spec.in_block[3]))
+
+
+def _wbl_row_off_by_one(spec):
+    orig = spec.in_index
+    return dataclasses.replace(spec, in_index=lambda nb, oh, st: (
+        orig(nb, oh, st)[0] + 1, *orig(nb, oh, st)[1:]))
+
+
+@pytest.mark.parametrize("plant,codes", [
+    (_wbl_window_short, {"window-bounds"}),
+    (_wbl_window_shifted, {"index-map-mismatch"}),
+    (_wbl_row_off_by_one, {"index-map-mismatch"}),
+    (lambda s: dataclasses.replace(
+        s, dout_index=lambda nb, oh, st: (oh, 0 * st, 0, nb)),
+     {"out-coverage"}),
+    (lambda s: dataclasses.replace(s, taps=(s.taps[0], s.taps[1] - 1)),
+     {"dropped-tap"}),
+    (lambda s: dataclasses.replace(
+        s, out_index=lambda nb, oh, st: (0 * nb + oh % 2, 0)),
+     {"reduction-dependence"}),
+], ids=["window-one-short", "window-one-right", "row-one-down",
+        "strip-revisited", "tap-dropped", "dflt-moves"])
+def test_mutation_batch_lanes_geometry(plant, codes):
+    spec = _wbl_spec()
+    assert spec.grid[2] == 2
+    assert codes <= _codes(check_batch_lanes_spec(plant(spec)))
+
+
+def test_flagged_batch_lanes_window_really_diverges():
+    # the window one column to the right computes a wrong dFLT
+    import jax
+
+    from repro.plan.build import _ref_wgrad
+
+    sc, good = STEM, _wbl_spec()
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    real = jax.random.normal(k1, sc.in_shape(), jnp.float32)
+    d_real = jax.random.normal(k2, sc.out_shape(), jnp.float32)
+    kp, n = good.in_shape[2:]
+    inp = jnp.pad(real, ((sc.padH,) * 2, (sc.padW,) * 2, (0, kp - sc.IC),
+                         (0, n - sc.B)))
+    d_out = jnp.pad(d_real, ((0, 0), (0, 0), (0, 0), (0, n - sc.B)))
+    want = np.asarray(_ref_wgrad(real, d_real, sc))
+
+    def launch(spec):
+        out = mg._launch_batch_lanes(spec, inp, d_out, interpret=True)
+        return np.asarray(out).reshape(sc.fltH, sc.fltW, kp,
+                                       sc.OC)[:, :, :sc.IC]
+
+    np.testing.assert_allclose(launch(good), want, rtol=1e-4, atol=1e-4)
+    assert not np.allclose(launch(_wbl_window_shifted(good)), want,
+                           rtol=1e-3, atol=1e-2)
 
 
 def test_mutation_output_moves_with_reduction():

@@ -259,3 +259,43 @@ def test_bench_layer_choice_and_strip(config, batch, index):
     c = plan.choice
     assert ((c.schedule, c.bm, c.bn, c.bk, plan.spec.bw)
             == _BENCH_CHOICES[(config, batch)][index])
+
+
+# (B, IC, OC, inHW, flt, pad, std, dtype) of forward scenes whose wgrad
+# takes the batch-on-lanes route (WBL); the remainder column is the
+# stride remainder the swapped scene would grow its output by.
+WBL_CASES = {
+    "ic3_7x7_s2_p3_rem": ((8, 3, 8, 16, 7, 3, 2, "float32"), 1),
+    "ic1_7x7_s2_p3": ((8, 1, 4, 11, 7, 3, 2, "float32"), 0),
+    "ic1_3x3_s1_p0": ((8, 1, 8, 9, 3, 0, 1, "float32"), 0),
+    "ic3_3x3_s2_p0_odd": ((8, 3, 16, 10, 3, 0, 2, "float32"), 1),
+    "ic3_7x7_s1_p3": ((8, 3, 8, 9, 7, 3, 1, "float32"), 0),
+    "ic3_3x3_b256": ((256, 3, 8, 7, 3, 1, 2, "float32"), 0),
+    "ic3_3x3_bf16": ((8, 3, 8, 9, 3, 1, 1, "bfloat16"), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WBL_CASES))
+def test_wgrad_batch_lanes_matches_reference(case):
+    """The WBL weight gradient equals the reference's, and is bit-identical
+    at every strip width: each dOUT pixel adds its taps in the same order
+    whatever the strip."""
+    args, rem = WBL_CASES[case]
+    sc = _scene(*args)
+    assert (sc.inW + 2 * sc.padW - sc.fltW) % sc.stdW == rem
+    plan = make_plan(sc, ConvOp.WGRAD)
+    assert plan.schedule == "WBL" and plan.exec_scene == sc
+    assert plan.spec.np_ % 128 == 0 and plan.spec.kp % 8 == 0
+    k1, k2 = jax.random.split(jax.random.PRNGKey(sum(map(ord, case))))
+    inp = jax.random.normal(k1, sc.in_shape(), jnp.float32).astype(sc.dtype)
+    cot = jax.random.normal(k2, sc.out_shape(), jnp.float32).astype(sc.dtype)
+    got = np.asarray(plan.execute(inp, cot), np.float32)
+    assert got.shape == sc.flt_shape()
+    want = np.asarray(build._ref_wgrad(inp, cot, sc), np.float32)
+    tol = 2e-2 if sc.dtype == "bfloat16" else 1e-4
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol * 2)
+    for bw in (1, sc.outW):
+        spec = dataclasses.replace(plan.spec, bw=bw)
+        np.testing.assert_array_equal(
+            np.asarray(build._exec_wgrad(inp, cot, sc, spec), np.float32),
+            got)

@@ -11,12 +11,17 @@ import pytest
 
 import repro.plan.build as build_mod
 import repro.tune.cache as cache_mod
+from repro.analysis.verify import verify_plan
 from repro.core.conv import mg3m_conv_nhwc
+from repro.core.mapping import ScheduleChoice
 from repro.core.scene import ConvScene
 from repro.kernels import ops, ref
+from repro.models.cnn import resnet_scenes
+from repro.obs.metrics import default_metrics
 from repro.plan import (ConvOp, PlanRegistry, default_registry, get_plan,
                         grad_filter_scene, grad_input_scene, make_plan,
                         plan_from_dict, plan_to_dict)
+from repro.plan.build import policy_tag
 
 SCENES = {
     "plain":     (4, 8, 12, 9, 3, 1, 1),
@@ -24,6 +29,7 @@ SCENES = {
     "remainder": (3, 5, 7, 9, 3, 0, 1),   # awkward primes
     "strided":   (2, 8, 4, 10, 3, 1, 2),  # backward -> dilated Pallas scenes
     "unpadded":  (2, 4, 6, 8, 3, 0, 1),
+    "stem":      (4, 3, 8, 12, 7, 3, 2),  # wgrad on the WBL route
 }
 
 # padding > dilated-filter-extent-1: the one genuinely inexpressible adjoint
@@ -77,6 +83,47 @@ def test_backward_scenes_go_through_the_selector():
         plan = make_plan(sc, op)
         assert not plan.uses_reference
         assert plan.choice is not None and plan.spec is not None
+
+
+def test_wgrad_route_follows_the_forward_ic():
+    """ResNet-50 at B=128: the stem's wgrad (IC=3) takes the WBL route
+    over the forward scene and counts once; an IC=64 wgrad keeps the
+    swapped scene and TB88."""
+    scenes = resnet_scenes(128)
+    count = lambda: default_metrics().value("repro.plan.wgrad_batch_lanes")
+    before = count()
+    stem = make_plan(scenes["stem"], ConvOp.WGRAD)
+    assert count() == before + 1
+    assert stem.schedule == "WBL" and stem.exec_scene == scenes["stem"]
+    assert (stem.spec.bn, stem.spec.kp, stem.spec.bw) == (128, 8, 28)
+    assert verify_plan(stem) == []
+    wide = make_plan(scenes["s1b0.b"], ConvOp.WGRAD)
+    assert wide.schedule == "TB88"
+    assert wide.exec_scene == grad_filter_scene(scenes["s1b0.b"])
+    for policy in ("tuned", "analytic"):
+        assert make_plan(scenes["stem"], ConvOp.WGRAD,
+                         policy=policy).schedule == "WBL"
+    assert count() == before + 3
+
+
+def test_forced_grains_keep_the_swapped_wgrad():
+    """A forced TB88, or an exact TB88 choice, gets exactly TB88 on the
+    swapped scene; a pinned WBL choice (the registry's reload) stays WBL
+    and is refused where WBL cannot serve."""
+    sc = _scene(*SCENES["stem"])
+    forced = make_plan(sc, ConvOp.WGRAD, policy="TB88")
+    assert forced.schedule == "TB88"
+    assert forced.exec_scene == grad_filter_scene(sc)
+    exact = ScheduleChoice("TB88", 8, 8, 128, 0.0, 0.0, 0.0, 0)
+    assert make_plan(sc, ConvOp.WGRAD, policy=exact).choice == exact
+    wbl = make_plan(sc, ConvOp.WGRAD)
+    pinned = make_plan(sc, ConvOp.WGRAD, policy=wbl.choice)
+    assert pinned == dataclasses.replace(wbl, policy=policy_tag(wbl.choice))
+    assert plan_from_dict(plan_to_dict(wbl)) == wbl
+    with pytest.raises(ValueError, match="does not serve the fprop"):
+        make_plan(sc, ConvOp.FPROP, policy=wbl.choice)
+    with pytest.raises(ValueError, match="does not serve the wgrad"):
+        make_plan(_scene(*SCENES["plain"]), ConvOp.WGRAD, policy=wbl.choice)
 
 
 def test_forced_policy_is_pinned_and_recorded():

@@ -19,10 +19,11 @@ import numpy as np
 import pytest
 
 from repro.analysis import verify_sharded_plan
-from repro.core.mapping import SHARD_LAUNCH_OVERHEAD_S, select_schedule
+from repro.core.mapping import (SCHEDULES, SHARD_LAUNCH_OVERHEAD_S,
+                                select_schedule)
 from repro.core.scene import ConvScene, ceil_div, pow2_floor
 from repro.models.cnn import cnn_layer_scenes
-from repro.plan import ConvOp, make_plan
+from repro.plan import ConvOp, grad_filter_scene, make_plan
 from repro.plan.registry import PlanRegistry, plan_signature
 from repro.shard import (PARTITION_AXES, collective_bytes, halo_geometry,
                          make_sharded_plan, make_sharded_training_plans,
@@ -75,7 +76,10 @@ def _assert_parity(scene: ConvScene, op: ConvOp, axis: str, n: int):
     assert plan.shard_tag == f"{axis}:{n}"
     assert not verify_sharded_plan(plan)
     a, b = _rand_io(scene, op)
-    want = np.asarray(make_plan(scene, op).execute(a, b))
+    # the single-device plan over the same exec scene: a sharded wgrad
+    # keeps the swapped scene where the unsharded one may take WBL
+    policy = select_schedule(plan.exec_scene)
+    want = np.asarray(make_plan(scene, op, policy=policy).execute(a, b))
     got = np.asarray(plan.execute(a, b))
     if axis == "ic":
         np.testing.assert_allclose(got, want, rtol=RTOL, atol=ATOL)
@@ -204,6 +208,24 @@ def test_n1_fallback_executes_and_matches():
     np.testing.assert_array_equal(
         np.asarray(plan.execute(a, b)),
         np.asarray(make_plan(SC, ConvOp.FPROP).execute(a, b)))
+
+
+def test_sharded_wgrad_keeps_the_swapped_route():
+    """WBL is an in-process route: the sharded wgrad of an IC=3 stem keeps
+    the swapped exec scene and a TB grain, and agrees with the unsharded
+    plan, which takes WBL."""
+    stem = ConvScene(B=8, IC=3, OC=16, inH=12, inW=12, fltH=7, fltW=7,
+                     padH=3, padW=3, stdH=2, stdW=2)
+    plan = make_sharded_plan(stem, ConvOp.WGRAD)
+    assert plan.exec_scene == grad_filter_scene(stem)
+    assert plan.inner.schedule in SCHEDULES
+    assert not verify_sharded_plan(plan)
+    single = make_plan(stem, ConvOp.WGRAD)
+    assert single.schedule == "WBL"
+    a, b = _rand_io(stem, ConvOp.WGRAD)
+    np.testing.assert_allclose(np.asarray(plan.execute(a, b)),
+                               np.asarray(single.execute(a, b)),
+                               rtol=RTOL, atol=ATOL)
 
 
 def test_make_mesh_for_clamps():

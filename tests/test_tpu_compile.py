@@ -18,7 +18,7 @@ from repro.core.autodiff import ModelPlans
 from repro.core.mapping import DEVICE_COST_MODELS
 from repro.core.scene import ConvScene
 from repro.kernels import mg3m_conv as K
-from repro.models.cnn import cnn_chain_scenes, cnn_scenes
+from repro.models.cnn import cnn_chain_scenes, cnn_scenes, resnet_scenes
 from repro.plan import ConvOp, make_plan
 from repro.plan.build import launched_shapes
 from repro.shard import PARTITION_AXES, make_sharded_training_plans
@@ -42,6 +42,9 @@ def one_chip(topo):
 def _kernel_call(plan):
     """The plan's kernel launch with the kernel mode forced to compile."""
     sc, spec = plan.exec_scene, plan.spec
+    if spec.schedule == "WBL":
+        return lambda a, b: K.wgrad_batch_lanes(a, b, sc, bn=spec.bn,
+                                                bw=spec.bw, interpret=False)
     if spec.schedule == "TB11":
         return lambda a, b: K.conv_tb11(a, b, sc, bw=spec.bw,
                                         interpret=False)
@@ -68,8 +71,9 @@ CASES = {
     "resnet_L0_fprop": (lambda: _resnet(0), ConvOp.FPROP, "TB11", False,
                         None),
     "resnet_L0_dgrad": (lambda: _resnet(0), ConvOp.DGRAD, "TB11", True, 1),
-    "resnet_L0_wgrad": (lambda: _resnet(0), ConvOp.WGRAD, "TB88", False,
-                        None),
+    "resnet_L0_wgrad": (lambda: _resnet(0), ConvOp.WGRAD, "WBL", False, 28),
+    "resnet50_stem_wgrad_b128": (lambda: resnet_scenes(128)["stem"],
+                                 ConvOp.WGRAD, "WBL", False, 28),
     "resnet_L9_fprop": (lambda: _resnet(9), ConvOp.FPROP, "TB18", False,
                         None),
     "alexnet_L0_b128": (lambda: cnn_scenes(128)["alexnet"][0],
