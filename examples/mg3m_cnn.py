@@ -11,7 +11,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.models.cnn import init_small_cnn, small_cnn_forward, small_cnn_plans
+from repro.models.cnn import (init_small_cnn, small_cnn_forward,
+                              small_cnn_plans, small_cnn_reference)
 
 
 def make_data(key, n, res=16):
@@ -29,19 +30,14 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--res", type=int, default=16)
     ap.add_argument("--lr", type=float, default=1e-2)
-    ap.add_argument("--pallas", action="store_true",
-                    help="train through the Pallas plans (slow on CPU "
-                         "interpret mode; the default trains on the jnp "
-                         "reference)")
     args = ap.parse_args()
 
     key = jax.random.PRNGKey(0)
     params = init_small_cnn(key)
 
     # Plan every layer ONCE, all three directions; training then never
-    # re-runs schedule resolution.  (The jnp-reference training path below
-    # doesn't consume these plans, but a --pallas run would — and the table
-    # shows what the selector picked per layer and direction.)
+    # re-runs schedule resolution.  The table shows what the selector
+    # picked per layer and direction.
     plans = small_cnn_plans(params, args.batch, args.res)
     for name, triple in plans.items():
         print(f"{name}: fprop={triple.fprop.schedule} "
@@ -51,8 +47,7 @@ def main() -> None:
     xs, ys = make_data(jax.random.PRNGKey(1), 512, args.res)
 
     def loss_fn(p, x, y):
-        logits = small_cnn_forward(p, x, use_pallas=args.pallas,
-                                   plans=plans if args.pallas else None)
+        logits = small_cnn_forward(p, x, plans=plans)
         lp = jax.nn.log_softmax(logits)
         return -jnp.take_along_axis(lp, y[:, None], 1).mean()
 
@@ -79,7 +74,13 @@ def main() -> None:
             print(f"step {i:3d} loss={float(loss):.4f} "
                   f"({(time.time()-t0)*1e3:.0f}ms)")
 
-    logits = small_cnn_forward(params, xs[:256])
+    # the planned forward against the plain reference, then accuracy
+    got = small_cnn_forward(params, xs[:args.batch], plans=plans)
+    want = small_cnn_reference(params, xs[:args.batch])
+    err = float(jnp.max(jnp.abs(got - want)))
+    print(f"planned forward vs reference: max |err| = {err:.2e}")
+    assert err < 1e-3
+    logits = small_cnn_reference(params, xs[:256])
     acc = float((jnp.argmax(logits, -1) == ys[:256]).mean())
     print(f"train accuracy: {acc:.1%}")
     assert acc > 0.2, "should beat 10% chance comfortably"

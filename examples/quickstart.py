@@ -5,7 +5,7 @@
 import jax
 import jax.numpy as jnp
 
-from repro.core.conv import ConvOp, ConvScene, make_plan, mg3m_conv
+from repro.core.conv import ConvOp, ConvScene, make_plan
 from repro.core.mapping import predicted_efficiency
 from repro.kernels import ref
 
@@ -37,8 +37,7 @@ err = float(jnp.max(jnp.abs(out - want)))
 print(f"output {out.shape}, max |err| vs oracle = {err:.2e}")
 assert err < 1e-3
 
-# 5. The legacy one-shot call still works (it builds a plan under the hood);
-#    the backward directions are plans too — see ConvOp.DGRAD / WGRAD.
-one_shot = mg3m_conv(inp, flt, scene)
-assert float(jnp.max(jnp.abs(one_shot - out))) < 1e-5
+# 5. The backward directions are plans too: dL/dIN from a DGRAD plan.
+d_in = make_plan(scene, ConvOp.DGRAD).execute(out, flt)
+assert d_in.shape == scene.in_shape()
 print("OK")

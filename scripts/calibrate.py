@@ -11,7 +11,7 @@ execution, fits per-scene-class correction factors (effective MXU rate,
 effective HBM bandwidth, per-grid-step overhead — ``repro.tune.calibrate``),
 prints the per-class error report (median |predicted-measured|/measured
 before -> after), and writes the versioned calibration artifact that
-``mg3m_conv(schedule=None)`` and ``schedule="auto"`` cache misses pick up
+``make_plan(scene)`` and ``policy="tuned"`` cache misses pick up
 automatically (path resolution: --out / $REPRO_CALIBRATION /
 ~/.cache/repro/calibration.json).
 

@@ -12,7 +12,7 @@ On a TPU the kernels compile for the chip; drop the proxy caps:
 Each scene is tuned through ``repro.tune.autotune_scene`` (analytic top-k
 pruning -> wall-clock measurement through the real kernel dispatch) and the
 winners land in the JSON cache (``--cache`` / $REPRO_TUNE_CACHE /
-~/.cache/repro/tune_cache.json), where ``mg3m_conv(..., schedule="auto")``
+~/.cache/repro/tune_cache.json), where ``make_plan(scene, policy="tuned")``
 resolves them.  Measured-vs-predicted error is reported per scene and
 summarized — the audit trail for the analytic roofline model.
 """

@@ -12,7 +12,7 @@ exceeding the dilated filter extent minus one blocks dgrad only) falls
 back alone while the other two still run Pallas — see
 ``TrainingPlans.reference_ops``.
 
-Three APIs, smallest to largest scope:
+Two APIs, by scope:
 
   * ``make_training_plans`` + ``conv_with_plans``: plan-once / execute-many —
     build the (fprop, dgrad, wgrad) triple per layer, then every training
@@ -22,9 +22,7 @@ Three APIs, smallest to largest scope:
     ``PlanRegistry.warm`` (or built as mesh-sharded triples via
     ``repro.shard.autodiff`` when ``devices`` are given), so an entire
     training step touches zero schedule resolutions (``repro.train.cnn``
-    builds its step functions on this);
-  * ``mg3m_conv_trainable``: the legacy per-call signature, now a thin shim
-    that fetches plans from the default ``PlanRegistry``.
+    builds its step functions on this).
 """
 from __future__ import annotations
 
@@ -37,7 +35,7 @@ import jax
 from repro.core.mapping import ScheduleChoice
 from repro.core.scene import ConvScene
 from repro.plan.build import ConvOp, ConvPlan, make_plan
-from repro.plan.registry import PlanRegistry, default_registry, get_plan
+from repro.plan.registry import PlanRegistry, default_registry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +81,6 @@ def backward_policy(policy: Union[None, str, ScheduleChoice]) -> str:
 
 def make_training_plans(scene: ConvScene, *,
                         policy: Union[None, str, ScheduleChoice] = "analytic",
-                        use_pallas: bool = True,
                         registry: Optional[PlanRegistry] = None
                         ) -> TrainingPlans:
     """Plan all three directions of one layer, each through the selector.
@@ -93,11 +90,8 @@ def make_training_plans(scene: ConvScene, *,
     propagate to the backward scenes).
     """
     bwd_policy = backward_policy(policy)
-    if registry is not None:
-        build = functools.partial(registry.get_or_build, scene,
-                                  use_pallas=use_pallas)
-    else:
-        build = functools.partial(make_plan, scene, use_pallas=use_pallas)
+    build = functools.partial(
+        registry.get_or_build if registry is not None else make_plan, scene)
     return TrainingPlans(fprop=build(ConvOp.FPROP, policy=policy),
                          dgrad=build(ConvOp.DGRAD, policy=bwd_policy),
                          wgrad=build(ConvOp.WGRAD, policy=bwd_policy))
@@ -192,7 +186,6 @@ class ModelPlans:
 
 def make_model_plans(scenes: Mapping[str, ConvScene], *,
                      policy: Union[None, str, ScheduleChoice] = "analytic",
-                     use_pallas: bool = True,
                      registry: Optional[PlanRegistry] = None,
                      devices: Optional[Sequence] = None,
                      max_shards: Optional[int] = None) -> ModelPlans:
@@ -220,13 +213,10 @@ def make_model_plans(scenes: Mapping[str, ConvScene], *,
     reg = registry if registry is not None else default_registry()
     scene_list = list(scenes.values())
     bwd = backward_policy(policy)
-    reg.warm(scene_list, ops=(ConvOp.FPROP,), policy=policy,
-             use_pallas=use_pallas)
-    reg.warm(scene_list, ops=(ConvOp.DGRAD, ConvOp.WGRAD), policy=bwd,
-             use_pallas=use_pallas)
+    reg.warm(scene_list, ops=(ConvOp.FPROP,), policy=policy)
+    reg.warm(scene_list, ops=(ConvOp.DGRAD, ConvOp.WGRAD), policy=bwd)
     return ModelPlans(layers=tuple(
-        (name, make_training_plans(sc, policy=policy, use_pallas=use_pallas,
-                                   registry=reg))
+        (name, make_training_plans(sc, policy=policy, registry=reg))
         for name, sc in scenes.items()))
 
 
@@ -245,31 +235,3 @@ def apply_conv(inp: jax.Array, flt: jax.Array, plans) -> jax.Array:
         f"apply_conv expects a TrainingPlans or ShardedTrainingPlans, "
         f"got {type(plans).__name__}")
 
-
-# --------------------------------------------------------------------------
-# legacy per-call shims (signatures preserved)
-# --------------------------------------------------------------------------
-def grad_input(d_out: jax.Array, flt: jax.Array, scene: ConvScene, *,
-               use_pallas: bool = True) -> jax.Array:
-    """dL/dIN via the scene's DGRAD plan (Pallas even on strided forwards;
-    see the plan's ``uses_reference``/``notes`` for the rare fallback)."""
-    plan = get_plan(scene, ConvOp.DGRAD, use_pallas=use_pallas)
-    return plan.execute(d_out, flt)
-
-
-def grad_filter(inp: jax.Array, d_out: jax.Array, scene: ConvScene
-                ) -> jax.Array:
-    """dL/dFLT via the scene's WGRAD plan (fp32-accumulated either way)."""
-    return get_plan(scene, ConvOp.WGRAD).execute(inp, d_out)
-
-
-def mg3m_conv_trainable(inp: jax.Array, flt: jax.Array, scene: ConvScene,
-                        schedule: Optional[str] = None) -> jax.Array:
-    """Differentiable MG3MConv — Pallas forward, MG3M-scene backward.
-
-    Legacy signature; plans come from the default ``PlanRegistry``, so
-    repeated calls on the same scene reuse the same frozen plans."""
-    from repro.plan.registry import default_registry
-    plans = make_training_plans(scene, policy=schedule,
-                                registry=default_registry())
-    return conv_with_plans(inp, flt, plans)

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels, their pure-jnp oracles (``ref``) and wrappers (``ops``).
+"""Pallas TPU kernels and their pure-jnp oracles (``ref``).
 
 The kernel mode is derived from the platform here, in one place: the
 kernels compile for the chip when JAX's default backend is a TPU and run

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.scene import round_up
 from repro.kernels import interpret_mode
 
 
@@ -65,3 +66,17 @@ def causal_conv1d(x: jax.Array, w: jax.Array, *, block_l: int,
             dimension_semantics=("parallel", "arbitrary", "parallel")),
         interpret=interpret_mode(),
     )(x, x, w)
+
+
+def causal_conv1d_op(x: jax.Array, w: jax.Array, *, block_l: int = 256,
+                     block_d: int = 256) -> jax.Array:
+    """Depthwise causal conv1d (Mamba2's conv) at any (L, D): pads both to
+    whole blocks, runs ``causal_conv1d`` and slices the padding off."""
+    b, l, d = x.shape
+    bl = min(block_l, l)
+    bd = min(block_d, d)
+    lp, dp = round_up(l, bl), round_up(d, bd)
+    x_a = jnp.pad(x, ((0, 0), (0, lp - l), (0, dp - d)))
+    w_a = jnp.pad(w, ((0, 0), (0, dp - d)))
+    out = causal_conv1d(x_a, w_a, block_l=bl, block_d=bd)
+    return out[:, :l, :d]

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, turned on the same way by every
 entry point (``chip_smoke.py``, ``launch/train_cnn.py``,
-``benchmarks/run.py``, ``scripts/tune.py``) before its first compile.
+``bench/run.py``, ``scripts/tune.py``) before its first compile.
 
 The directory is ``$JAX_COMPILATION_CACHE_DIR`` when that is set, and
 otherwise the fixed ``<checkout>/.cache/jax`` (listed in ``.gitignore``):

@@ -23,11 +23,15 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.core.conv import mg3m_conv_nhwc
 from repro.core.scene import ConvScene
-from repro.models.layers import trunc_normal
+from repro.kernels.ref import conv_ref
 
 Params = Dict[str, jax.Array]
+
+
+def trunc_normal(key, shape, std, dtype):
+    return (jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
+            * std).astype(dtype)
 
 
 def nhwc_to_plan(x: jax.Array) -> jax.Array:
@@ -217,30 +221,36 @@ def small_cnn_plans(p: Params, batch: int, res: int, *,
                             policy=policy, devices=devices)
 
 
-def small_cnn_forward(p: Params, x: jax.Array, *, use_pallas: bool = False,
-                      schedule=None, plans=None) -> jax.Array:
+def small_cnn_forward(p: Params, x: jax.Array, *, schedule=None,
+                      plans=None) -> jax.Array:
     """x: [B, H, W, C] -> logits [B, n_classes].  All convs via MG3MConv.
 
-    use_pallas=True routes through the differentiable plan path
-    (``core/autodiff.apply_conv``) so the whole CNN trains through the
-    Pallas forward; the activation enters plan layout once and stays there
-    across c1 -> c2 -> c3 -> pool -> head (no per-layer transposes).  Pass
-    ``plans`` (from ``small_cnn_plans``) to use pre-built per-layer plans;
-    otherwise they are fetched from the default PlanRegistry on first use.
+    Runs the differentiable plan path (``core/autodiff.apply_conv``), so the
+    whole CNN trains through the Pallas forward; the activation enters plan
+    layout once and stays there across c1 -> c2 -> c3 -> pool -> head (no
+    per-layer transposes).  Pass ``plans`` (from ``small_cnn_plans``) to
+    use pre-built per-layer plans; otherwise they are built here under the
+    ``schedule`` policy.
     """
-    if not use_pallas:
-        z = x
-        for name, stride in _LAYER_STRIDES.items():
-            z = jax.nn.relu(mg3m_conv_nhwc(z, p[name],
-                                           stride=(stride, stride),
-                                           padding=(1, 1), schedule=schedule,
-                                           use_pallas=False))
-        return z.mean(axis=(1, 2)) @ p["head"]
     if plans is None:
         plans = small_cnn_plans(p, x.shape[0], x.shape[1],
                                 dtype=str(x.dtype), policy=schedule)
     return cnn_forward_planned(p, x, plans,
                                graph=chain_graph(tuple(_LAYER_STRIDES)))
+
+
+def small_cnn_reference(p: Params, x: jax.Array) -> jax.Array:
+    """The plain reference of ``small_cnn_forward``: the same three convs,
+    ReLU, mean-pool and head, each conv on ``kernels/ref.conv_ref`` (lax at
+    ``HIGHEST``) in plan layout."""
+    z = nhwc_to_plan(x)
+    for name, stride in _LAYER_STRIDES.items():
+        sc = ConvScene(B=z.shape[3], IC=z.shape[2], OC=p[name].shape[3],
+                       inH=z.shape[0], inW=z.shape[1], fltH=p[name].shape[0],
+                       fltW=p[name].shape[1], padH=1, padW=1, stdH=stride,
+                       stdW=stride, dtype=str(x.dtype))
+        z = jax.nn.relu(conv_ref(z, p[name], sc))
+    return z.mean(axis=(0, 1)).T @ p["head"]
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SSMConfig
-from repro.kernels import ops as kops
+from repro.kernels.causal_conv1d import causal_conv1d_op
 from repro.kernels import ref as kref
 from repro.models.layers import trunc_normal
 
@@ -144,7 +144,7 @@ def mamba2_block(p: Params, x: jax.Array, cfg: SSMConfig, *,
         zxbcdt, [di, 2 * di, 2 * di + 2 * g * s], axis=-1)
     conv_in = jnp.concatenate([xin, bc], -1)
     if use_pallas:
-        conv_out = kops.causal_conv1d_op(conv_in, p["conv_w"])
+        conv_out = causal_conv1d_op(conv_in, p["conv_w"])
     else:
         conv_out = kref.causal_conv1d_ref(conv_in, p["conv_w"])
     conv_out = jax.nn.silu(conv_out.astype(F32)).astype(x.dtype)

@@ -106,7 +106,7 @@ def policy_tag(policy: PolicySpec) -> str:
 
 
 def resolve_policy(scene: ConvScene, policy: PolicySpec) -> ScheduleChoice:
-    """One-time schedule resolution for a plan (and the legacy per-call path).
+    """One-time schedule resolution for a plan.
 
       None / "analytic"   multi-grained selection under the active cost model
                           (calibrated when an artifact exists, else roofline);
@@ -165,7 +165,7 @@ class ExecSpec:
 def derive_exec_spec(scene: ConvScene, choice: ScheduleChoice,
                      out_hw: Optional[Tuple[int, int]] = None) -> ExecSpec:
     """Precompute every padded/aligned dim the kernel dispatch needs —
-    the per-call shape arithmetic of the legacy path, done once.
+    once per plan.
     ``out_hw`` overrides the spatial slice-back extents (the wgrad scene's
     conv output can exceed the true dFLT spatial dims by the forward's
     stride remainder)."""
@@ -402,7 +402,7 @@ def _exec_wgrad(inp, d_out, scene: ConvScene, spec: ExecSpec):
     return wgrad_finish(_conv_body(a, b, scene, spec))
 
 
-# Reference executors (use_pallas=False and the recorded fallbacks).
+# Reference executors (the recorded fallbacks).
 @functools.partial(jax.jit, static_argnames=("scene",))
 def _ref_fprop(inp, flt, scene: ConvScene):
     return ref.conv_ref(inp, flt, scene)
@@ -445,14 +445,13 @@ class ConvPlan:
 
     All selection and shape work happened in ``make_plan``; ``execute`` is a
     pure dispatch into a jitted kernel call.  ``uses_reference`` + ``notes``
-    surface when the plan bypasses Pallas (strided-backward fallbacks,
-    ``use_pallas=False``) — metadata, not buried comments.
+    surface when the plan bypasses Pallas (the recorded dgrad/wgrad
+    fallbacks) — metadata, not buried comments.
     """
 
     scene: ConvScene                    # the *forward* scene the plan serves
     op: ConvOp
     policy: str                         # canonical tag (see ``policy_tag``)
-    use_pallas: bool
     uses_reference: bool
     notes: Tuple[str, ...] = ()
     exec_scene: Optional[ConvScene] = None   # scene actually dispatched
@@ -521,8 +520,7 @@ class ConvPlan:
 
 
 def make_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
-              policy: PolicySpec = "analytic",
-              use_pallas: bool = True) -> ConvPlan:
+              policy: PolicySpec = "analytic") -> ConvPlan:
     """Build a frozen ``ConvPlan``: resolve the schedule once, derive the
     backward scene (DGRAD/WGRAD), precompute every padded/aligned shape.
 
@@ -546,19 +544,16 @@ def make_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
     with default_tracer().span("repro.plan.make_plan") as sp:
         if sp:
             sp.set(op=op.value, policy=tag, scene=scene.describe())
-        return _make_plan_inner(scene, op, policy, tag, use_pallas)
+        return _make_plan_inner(scene, op, policy, tag)
 
 
 def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
-                     tag: str, use_pallas: bool) -> ConvPlan:
+                     tag: str) -> ConvPlan:
     t_build = time.perf_counter()
     notes = []
-    uses_reference = not use_pallas
-    if not use_pallas:
-        notes.append(f"{op.value}: use_pallas=False; jnp reference")
-
     out_hw = None
     spec = None
+    why = None                            # the recorded reference blocker
     choice = _batch_lanes_route(scene, op, policy, tag)
     # FPROP and the WBL route execute over the forward scene itself
     exec_scene: Optional[ConvScene] = (
@@ -567,29 +562,19 @@ def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
         why = _dgrad_blocker(scene)
         if why is None:
             exec_scene = grad_input_scene(scene)
-        elif use_pallas:
-            if tag.startswith("forced:"):
-                raise ValueError(
-                    f"dgrad of {scene.describe()} requires a reference "
-                    f"fallback ({why}); the forced policy {tag!r} cannot "
-                    f"be honored for this op")
-            uses_reference = True
-            notes.append(f"dgrad: {why}; exact jnp adjoint instead of Pallas")
     elif op is ConvOp.WGRAD and choice is None:
         why = _wgrad_blocker(scene)
         if why is None:
             exec_scene = grad_filter_scene(scene)
             out_hw = (scene.fltH, scene.fltW)   # trim stride-remainder rows
-        elif use_pallas:
-            if tag.startswith("forced:"):
-                raise ValueError(
-                    f"wgrad of {scene.describe()} requires a reference "
-                    f"fallback ({why}); the forced policy {tag!r} cannot "
-                    f"be honored for this op")
-            uses_reference = True
-            notes.append(f"wgrad: {why}; exact jnp adjoint instead of Pallas")
-
+    uses_reference = why is not None
     if uses_reference:
+        if tag.startswith("forced:"):
+            raise ValueError(
+                f"{op.value} of {scene.describe()} requires a reference "
+                f"fallback ({why}); the forced policy {tag!r} cannot "
+                f"be honored for this op")
+        notes.append(f"{op.value}: {why}; exact jnp adjoint instead of Pallas")
         choice = None
     else:
         choice = choice or resolve_policy(exec_scene, policy)
@@ -601,7 +586,7 @@ def _make_plan_inner(scene: ConvScene, op: ConvOp, policy: PolicySpec,
     if uses_reference:
         m.counter("repro.plan.reference_fallbacks").inc()
     m.histogram("repro.plan.build_s").observe(time.perf_counter() - t_build)
-    return ConvPlan(scene=scene, op=op, policy=tag, use_pallas=use_pallas,
+    return ConvPlan(scene=scene, op=op, policy=tag,
                     uses_reference=uses_reference, notes=tuple(notes),
                     exec_scene=None if uses_reference else exec_scene,
                     choice=choice, spec=spec)
@@ -630,8 +615,7 @@ def _batch_lanes_route(scene: ConvScene, op: ConvOp, policy: PolicySpec,
 
 
 def assemble_plan(scene: ConvScene, op: Union[ConvOp, str], policy: str,
-                  choice: Optional[ScheduleChoice], *,
-                  use_pallas: bool = True) -> ConvPlan:
+                  choice: Optional[ScheduleChoice]) -> ConvPlan:
     """Rebuild a plan from a stored (scene, op, policy-tag, choice) without
     re-running resolution — the registry's deserialization path.  A stored
     choice is pinned exactly; a stored reference plan stays a reference
@@ -639,14 +623,14 @@ def assemble_plan(scene: ConvScene, op: Union[ConvOp, str], policy: str,
     what the op can execute (e.g. a Pallas choice for a strided dgrad)."""
     op = ConvOp(op)
     if choice is None:
-        plan = make_plan(scene, op, policy="analytic", use_pallas=use_pallas)
+        plan = make_plan(scene, op, policy="analytic")
         if not plan.uses_reference:
             raise ValueError(
                 f"stored {op.value} plan for {scene.describe()} has no "
                 f"schedule choice but the op does not require a reference "
                 f"fallback")
         return dataclasses.replace(plan, policy=policy)
-    plan = make_plan(scene, op, policy=choice, use_pallas=use_pallas)
+    plan = make_plan(scene, op, policy=choice)
     if plan.uses_reference:
         raise ValueError(
             f"stored {op.value} plan for {scene.describe()} pins "

@@ -1,7 +1,7 @@
 """Process-level plan repository — plan-once, execute-many, serve-forever.
 
 ``PlanRegistry`` memoizes frozen ``ConvPlan``s under a canonical signature
-(scene dims + dtype + op + policy + use_pallas), with the same
+(scene dims + dtype + op + policy), with the same
 conventions as the tune subsystem's schedule cache: hit/miss counters,
 bounded LRU eviction, and a versioned JSON artifact (atomic tmp+rename
 merge-on-``save`` so concurrent writers union rather than clobber,
@@ -40,7 +40,7 @@ _SCENE_FIELDS = ("B", "IC", "OC", "inH", "inW", "fltH", "fltW",
 
 
 def plan_signature(scene: ConvScene, op: Union[ConvOp, str],
-                   policy: PolicySpec, use_pallas: bool,
+                   policy: PolicySpec,
                    shard: Optional[str] = None) -> str:
     """Canonical registry key.  Dtype-alias-stable (via numpy dtype names)
     and explicit about everything that changes the executable.  Dilation
@@ -53,7 +53,7 @@ def plan_signature(scene: ConvScene, op: Union[ConvOp, str],
     dt = jnp.dtype(scene.dtype).name
     frag = f"|shard={shard}" if shard else ""
     return (f"v={PLAN_VERSION}|op={ConvOp(op).value}|pol={policy_tag(policy)}"
-            f"|pl={int(use_pallas)}|dt={dt}"
+            f"|dt={dt}"
             f"|B={scene.B}|IC={scene.IC}|OC={scene.OC}"
             f"|in={scene.inH}x{scene.inW}|flt={scene.fltH}x{scene.fltW}"
             f"|pad={scene.padH},{scene.padW}|std={scene.stdH},{scene.stdW}"
@@ -65,7 +65,6 @@ def plan_to_dict(plan) -> Dict:
         "scene": {f: getattr(plan.scene, f) for f in _SCENE_FIELDS},
         "op": plan.op.value,
         "policy": plan.policy,
-        "use_pallas": plan.use_pallas,
         "uses_reference": plan.uses_reference,
         "notes": list(plan.notes),
         "choice": choice_to_dict(plan.choice) if plan.choice else None,
@@ -84,7 +83,13 @@ def plan_from_dict(d: Dict):
     ``ValueError`` when this process has fewer devices than the stored
     ring (``load`` skips them, ``save`` keeps them — see
     ``valid_plan_dict``).  The ``interpret`` field of artifacts written
-    before the kernel mode was derived from the platform is ignored."""
+    before the kernel mode was derived from the platform is ignored, and
+    so is ``use_pallas: true`` from before every plan ran the kernels; an
+    entry stored with ``use_pallas: false`` describes a plan no longer
+    built and raises ``ValueError``."""
+    if not d.get("use_pallas", True):
+        raise ValueError("stored plan has use_pallas=false: the reference "
+                         "is no longer a plan mode")
     scene = ConvScene(**d["scene"])
     sh = d.get("shard")
     if sh:
@@ -93,17 +98,16 @@ def plan_from_dict(d: Dict):
         return assemble_sharded_plan(scene, d["op"], d["policy"],
                                      sh["axis"], int(sh["n"]), choice)
     choice = choice_from_dict(d["choice"]) if d.get("choice") else None
-    return assemble_plan(scene, d["op"], d["policy"], choice,
-                         use_pallas=bool(d.get("use_pallas", True)))
+    return assemble_plan(scene, d["op"], d["policy"], choice)
 
 
 def entry_key(d: Dict) -> str:
     """Registry key of one stored entry, recomputed from its fields: an
     artifact whose keys carry a fragment the signature no longer has (the
-    ``|int=`` kernel mode) still serves its plans under today's keys."""
+    ``|int=`` kernel mode, the ``|pl=`` knob) still serves its plans under
+    today's keys."""
     sh = d.get("shard")
     return plan_signature(ConvScene(**d["scene"]), d["op"], d["policy"],
-                          bool(d.get("use_pallas", True)),
                           shard=f"{sh['axis']}:{int(sh['n'])}" if sh else None)
 
 
@@ -193,18 +197,18 @@ class PlanRegistry:
             return key in self._mem
 
     def key(self, scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP,
-            policy: PolicySpec = "analytic", use_pallas: bool = True,
+            policy: PolicySpec = "analytic",
             shard: Optional[str] = None) -> str:
-        return plan_signature(scene, op, policy, use_pallas, shard)
+        return plan_signature(scene, op, policy, shard)
 
     # -- lookup ------------------------------------------------------------
     def get(self, scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
-            policy: PolicySpec = "analytic", use_pallas: bool = True,
+            policy: PolicySpec = "analytic",
             shard: Optional[str] = None):
         """Registered plan, or None on miss (LRU-touching).  ``shard`` is a
         ``ShardSpec.tag`` and selects the mesh-sharded entry population
         (``ShardedConvPlan``); ``None`` addresses unsharded plans only."""
-        k = self.key(scene, op, policy, use_pallas, shard)
+        k = self.key(scene, op, policy, shard)
         with self._lock:
             plan = self._mem.get(k)
             if plan is None:
@@ -215,7 +219,7 @@ class PlanRegistry:
             return plan
 
     def put(self, plan) -> str:
-        k = plan_signature(plan.scene, plan.op, plan.policy, plan.use_pallas,
+        k = plan_signature(plan.scene, plan.op, plan.policy,
                            shard=getattr(plan, "shard_tag", None))
         with self._lock:
             self._mem[k] = plan
@@ -225,8 +229,7 @@ class PlanRegistry:
 
     def get_or_build(self, scene: ConvScene,
                      op: Union[ConvOp, str] = ConvOp.FPROP, *,
-                     policy: PolicySpec = "analytic",
-                     use_pallas: bool = True) -> ConvPlan:
+                     policy: PolicySpec = "analytic") -> ConvPlan:
         """The plan-once entry: registry hit, or ``make_plan`` + register.
         Atomic under the registry lock: concurrent callers racing the same
         miss serialize through one build and all receive the same plan.
@@ -235,10 +238,9 @@ class PlanRegistry:
         analytic fallback), so the critical section is bounded by selector
         math — cheap enough that same-key dedup beats per-key locking."""
         with self._lock:
-            plan = self.get(scene, op, policy=policy, use_pallas=use_pallas)
+            plan = self.get(scene, op, policy=policy)
             if plan is None:
-                plan = make_plan(scene, op, policy=policy,
-                                 use_pallas=use_pallas)
+                plan = make_plan(scene, op, policy=policy)
                 self._c_builds.inc()
                 self.put(plan)
             return plan
@@ -246,8 +248,7 @@ class PlanRegistry:
     def warm(self, scenes: Iterable[ConvScene],
              ops: Sequence[Union[ConvOp, str]] = (ConvOp.FPROP,),
              buckets: Optional[Sequence[int]] = None, *,
-             policy: PolicySpec = "analytic",
-             use_pallas: bool = True) -> int:
+             policy: PolicySpec = "analytic") -> int:
         """Pre-build every (scene x op x bucket) plan not already registered;
         returns how many were built.  ``buckets`` rebatches each scene to
         every given batch size (``ConvScene.with_batch``) — the serving
@@ -271,8 +272,7 @@ class PlanRegistry:
                     rebatched = scene.with_batch(b)
                     for op in ops:
                         work.append((rebatched, op,
-                                     self.key(rebatched, op, policy,
-                                              use_pallas)))
+                                     self.key(rebatched, op, policy)))
             if len({k for _, _, k in work}) > self.max_plans:
                 raise ValueError(
                     f"cannot warm {len({k for _, _, k in work})} plans into "
@@ -282,8 +282,7 @@ class PlanRegistry:
                     f"(scenes x ops x buckets) ladder")
             for rebatched, op, k in work:
                 if k not in self._mem:
-                    self._mem[k] = make_plan(
-                        rebatched, op, policy=policy, use_pallas=use_pallas)
+                    self._mem[k] = make_plan(rebatched, op, policy=policy)
                     self._c_builds.inc()
                     built += 1
                 self._mem.move_to_end(k)
@@ -331,10 +330,9 @@ class PlanRegistry:
 
     def warmed_buckets(self, scene: ConvScene,
                        op: Union[ConvOp, str] = ConvOp.FPROP, *,
-                       policy: PolicySpec = "analytic",
-                       use_pallas: bool = True) -> tuple:
+                       policy: PolicySpec = "analytic") -> tuple:
         """Every batch size of ``scene``'s family resident for ``op`` under
-        the given build options, ascending.  This is the sub-rung execution
+        ``policy``, ascending.  This is the sub-rung execution
         probe for the scheduling layer: a deadline flush may execute any
         warmed bucket without a steady-state resolution, so "which buckets
         are free to dispatch at" is a registry question, not a ladder one.
@@ -347,7 +345,6 @@ class PlanRegistry:
         with self._lock:
             for plan in self._mem.values():
                 if (plan.op is op and plan.policy == pol
-                        and plan.use_pallas == use_pallas
                         and getattr(plan, "shard_tag", None) is None
                         and plan.scene.with_batch(1) == base):
                     out.append(plan.scene.B)
@@ -454,8 +451,8 @@ def set_default_registry(registry: Optional[PlanRegistry]) -> None:
 
 
 def get_plan(scene: ConvScene, op: Union[ConvOp, str] = ConvOp.FPROP, *,
-             policy: PolicySpec = "analytic", use_pallas: bool = True,
+             policy: PolicySpec = "analytic",
              registry: Optional[PlanRegistry] = None) -> ConvPlan:
     """Plan-once convenience on the default (or given) registry."""
     reg = registry if registry is not None else default_registry()
-    return reg.get_or_build(scene, op, policy=policy, use_pallas=use_pallas)
+    return reg.get_or_build(scene, op, policy=policy)

@@ -224,17 +224,12 @@ class ConvServer:
     """
 
     def __init__(self, *, registry: Optional[PlanRegistry] = None,
-                 policy: PolicySpec = "analytic",
-                 use_pallas: bool = True, max_batch: int = 32,
+                 policy: PolicySpec = "analytic", max_batch: int = 32,
                  min_bucket: int = 1, ladder_slack: float = 1.15,
                  cost_model: Optional[CostModel] = None, strict: bool = False,
                  on_dispatch: Optional[Callable[[DispatchRecord], None]]
                  = None, metrics: Optional[MetricRegistry] = None,
                  tracer: Optional[Tracer] = None, mesh=None):
-        if mesh is not None and not use_pallas:
-            raise ValueError(
-                "mesh serving requires use_pallas=True: sharded plans "
-                "always dispatch Pallas per shard")
         self.registry = registry if registry is not None else PlanRegistry()
         self.mesh = mesh
         if mesh is not None:
@@ -246,7 +241,6 @@ class ConvServer:
         # at prewarm so steady state never re-runs the joint selector
         self._shard_tags: Dict[Tuple[str, ConvOp, int], str] = {}
         self.policy = policy
-        self.use_pallas = use_pallas
         self.max_batch = max_batch
         self.min_bucket = min_bucket
         self.ladder_slack = ladder_slack
@@ -329,7 +323,7 @@ class ConvServer:
                 else:
                     built += self.registry.warm(
                         [fam.base], ops=fam.ops, buckets=fam.ladder,
-                        policy=self.policy, use_pallas=self.use_pallas)
+                        policy=self.policy)
         if compile:
             for fam in families:
                 with self._prewarm_span(fam, "compile"):
@@ -456,7 +450,7 @@ class ConvServer:
             for bucket in fam.ladder:
                 plan = self._build_sharded(fam.base.with_batch(bucket), op)
                 k = self.registry.key(plan.scene, op, self.policy,
-                                      self.use_pallas, shard=plan.shard_tag)
+                                      shard=plan.shard_tag)
                 if k not in self.registry:
                     built += 1
                 self.registry.put(plan)
@@ -476,11 +470,10 @@ class ConvServer:
             with self._lock:
                 tag = self._shard_tags.get((fam.layer, op, bucket))
             plan = (self.registry.get(scene, op, policy=self.policy,
-                                      use_pallas=self.use_pallas, shard=tag)
+                                      shard=tag)
                     if tag else None)
         else:
-            plan = self.registry.get(scene, op, policy=self.policy,
-                                     use_pallas=self.use_pallas)
+            plan = self.registry.get(scene, op, policy=self.policy)
         if plan is None:
             self._c_plan_misses.inc()
             if self.strict:
@@ -495,8 +488,7 @@ class ConvServer:
                 with self._lock:
                     self._shard_tags[(fam.layer, op, bucket)] = plan.shard_tag
             else:
-                plan = make_plan(scene, op, policy=self.policy,
-                                 use_pallas=self.use_pallas)
+                plan = make_plan(scene, op, policy=self.policy)
             self.registry.put(plan)
             self._c_plan_builds.inc()
         return plan

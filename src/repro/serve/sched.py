@@ -288,12 +288,11 @@ class ConvScheduler(ConvServer):
                                       min_bucket=self.min_bucket, slack=0.0)
                 built += self.registry.warm(
                     [fam.base], ops=fam.ops, buckets=rungs,
-                    policy=self.policy, use_pallas=self.use_pallas)
+                    policy=self.policy)
                 for op in fam.ops:
                     for b in rungs:
                         plan = self.registry.get(
-                            fam.base.with_batch(b), op, policy=self.policy,
-                            use_pallas=self.use_pallas)
+                            fam.base.with_batch(b), op, policy=self.policy)
                         self._pred_s[(fam.layer, op, b)] = (
                             plan.predicted_s or 0.0)
                 with self._lock:

@@ -238,10 +238,6 @@ class ShardedConvPlan:
         return self.spec.tag
 
     @property
-    def use_pallas(self) -> bool:
-        return self.inner.use_pallas
-
-    @property
     def uses_reference(self) -> bool:
         return self.inner.uses_reference
 

@@ -46,7 +46,7 @@ from repro.models.cnn import cnn_forward_planned
 from repro.obs.metrics import MetricRegistry, default_metrics
 from repro.obs.trace import default_tracer
 from repro.train import optimizer as opt
-from repro.train.step import TrainState
+from repro.train.optimizer import TrainState
 
 F32 = jnp.float32
 

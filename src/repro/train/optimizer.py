@@ -38,6 +38,11 @@ class OptState(NamedTuple):
     step: jax.Array
 
 
+class TrainState(NamedTuple):
+    params: Any
+    opt: OptState
+
+
 def init_opt_state(params: Any, moments_dtype: str = "float32") -> OptState:
     dt = jnp.dtype(moments_dtype)
     zeros = lambda p: jnp.zeros(p.shape, dt)

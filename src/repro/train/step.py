@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,13 +27,9 @@ from repro.configs.base import ArchConfig, SHAPES
 from repro.models import transformer as T
 from repro.parallel import ctx, sharding
 from repro.train import optimizer as opt
+from repro.train.optimizer import TrainState
 
 F32 = jnp.float32
-
-
-class TrainState(NamedTuple):
-    params: Any
-    opt: opt.OptState
 
 
 @dataclasses.dataclass(frozen=True)

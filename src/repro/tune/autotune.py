@@ -101,7 +101,7 @@ def autotune_scene(scene: ConvScene, *,
     """Tune one scene; consults/updates ``cache`` (default process cache).
 
     ``measure_fn`` overrides the wall-clock harness (tests inject synthetic
-    timings); the default measures through ``ops.mg3m_conv_op``.
+    timings); the default times the scene's fprop ``ConvPlan.execute``.
     """
     cache = cache if cache is not None else cache_mod.default_cache()
     backend = cache_mod.default_backend()

@@ -21,7 +21,7 @@ The fit persists as a versioned JSON artifact (same conventions as
 ``tune/cache.py``: schema + version fields, atomic tmp+rename write, env-var
 path override).  ``active_cost_model()`` is the hot-path hook: it returns the
 explicitly-installed model, else auto-loads the artifact (mtime-cached), else
-the uncalibrated default — ``kernels/ops.resolve_choice`` and
+the uncalibrated default — ``plan/build.resolve_policy`` and
 ``autotune.resolve_schedule`` route ``schedule=None`` / ``schedule="auto"``
 cache misses through it.
 

@@ -1,8 +1,7 @@
 """Measurement harness: wall-clock one candidate schedule through the real
-``ops.mg3m_conv_op`` dispatch.
+``ConvPlan.execute`` dispatch.
 
-Honesty conventions follow ``benchmarks/common.py``: off a TPU the kernels
-run in the Pallas interpreter, so absolute µs validate *relative*
+Off a TPU the kernels run in the Pallas interpreter, so absolute µs validate *relative*
 candidate ordering, not TPU truth; on a TPU the same harness times
 compiled kernels (the kernel mode follows the platform).  Proxy mode
 (channel/batch/spatial caps) measures a shrunken stand-in of the scene —
@@ -70,7 +69,9 @@ def make_operands(scene: ConvScene, seed: int = 0):
 def measure_choice(scene: ConvScene, choice: ScheduleChoice, *,
                    iters: int = 3, warmup: int = 1,
                    timeout_s: float = DEFAULT_TIMEOUT_S) -> float:
-    """Median wall-time (µs) of ``mg3m_conv_op`` pinned to ``choice``.
+    """Median wall-time (µs) of the scene's fprop plan pinned to ``choice``
+    (``make_plan(scene, policy=choice).execute``), plan build included in
+    every call as the tuner has always timed it.
 
     Warmup triggers compilation; the remaining budget bounds how many timed
     iterations actually run.  The budget applies to warmup too: a candidate
@@ -80,7 +81,7 @@ def measure_choice(scene: ConvScene, choice: ScheduleChoice, *,
     failure) likewise scores ``inf`` so the picker skips it instead of
     aborting the tune.
     """
-    from repro.kernels import ops  # local: keeps tune importable sans kernels
+    from repro.plan.build import make_plan  # local: avoids an import cycle
 
     m = default_metrics()
     m.counter("repro.tune.measurements").inc()
@@ -91,7 +92,7 @@ def measure_choice(scene: ConvScene, choice: ScheduleChoice, *,
                                scene=scene.describe()) as sp:
         t0 = time.perf_counter()
         try:
-            fn = lambda: ops.mg3m_conv_op(inp, flt, scene, schedule=choice)
+            fn = lambda: make_plan(scene, policy=choice).execute(inp, flt)
             for _ in range(max(warmup, 1)):
                 jax.block_until_ready(fn())
                 if time.perf_counter() - t0 > timeout_s:

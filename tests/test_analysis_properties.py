@@ -10,10 +10,10 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st   # noqa: E402
 
 from repro.analysis.verify import verify_point             # noqa: E402
-from repro.core.conv import mg3m_conv                      # noqa: E402
 from repro.core.mapping import ScheduleChoice              # noqa: E402
 from repro.core.scene import ConvScene                     # noqa: E402
 from repro.kernels import ref                              # noqa: E402
+from repro.plan import make_plan                           # noqa: E402
 from repro.tune.space import enumerate_space               # noqa: E402
 
 
@@ -42,7 +42,7 @@ def test_verified_point_matches_reference(sc, data):
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
     choice = ScheduleChoice(pt.schedule, pt.bm, pt.bn, pt.bk,
                             0.0, 0.0, 0.0, 0)
-    got = mg3m_conv(inp, flt, sc, schedule=choice)
+    got = make_plan(sc, policy=choice).execute(inp, flt)
     want = ref.conv_ref(inp, flt, sc)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -53,7 +53,6 @@ def test_verified_point_matches_reference(sc, data):
 def test_plan_for_scene_verifies_and_matches_reference(sc):
     # the production path end to end: whatever geometry make_plan settles
     # on is statically clean and numerically right
-    from repro.plan import make_plan
     plan = make_plan(sc)
     from repro.analysis.verify import verify_plan
     assert verify_plan(plan) == []

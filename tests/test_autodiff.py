@@ -4,9 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.autodiff import grad_filter, grad_input, mg3m_conv_trainable
+from repro.core.autodiff import conv_with_plans, make_training_plans
 from repro.core.scene import ConvScene
 from repro.kernels import ref
+from repro.plan import ConvOp, make_plan
 
 
 def _setup(b, ic, oc, hw, f, pad, std, seed=0):
@@ -32,9 +33,10 @@ def test_vjp_matches_oracle_grads(spec):
         return jnp.sum(ref.conv_ref(i, f, sc) * cot)
 
     want_din, want_dflt = jax.grad(loss_ref, argnums=(0, 1))(inp, flt)
+    plans = make_training_plans(sc)
 
     def loss_kernel(i, f):
-        return jnp.sum(mg3m_conv_trainable(i, f, sc) * cot)
+        return jnp.sum(conv_with_plans(i, f, plans) * cot)
 
     got_din, got_dflt = jax.grad(loss_kernel, argnums=(0, 1))(inp, flt)
     np.testing.assert_allclose(got_din, want_din, rtol=2e-4, atol=2e-4)
@@ -44,13 +46,13 @@ def test_vjp_matches_oracle_grads(spec):
 def test_grad_input_is_itself_an_mg3m_scene():
     """The dIN computation routes through the selector like any scene."""
     sc, inp, flt, cot = _setup(4, 8, 16, 9, 3, 1, 1)
-    din = grad_input(cot, flt, sc)
+    din = make_plan(sc, ConvOp.DGRAD).execute(cot, flt)
     assert din.shape == sc.in_shape()
 
 
 def test_grad_filter_shapes_and_values():
     sc, inp, flt, cot = _setup(2, 4, 5, 6, 3, 1, 1, seed=3)
-    dflt = grad_filter(inp, cot, sc)
+    dflt = make_plan(sc, ConvOp.WGRAD).execute(inp, cot)
     assert dflt.shape == sc.flt_shape()
 
     def loss_ref(f):
@@ -64,9 +66,10 @@ def test_training_through_the_kernel_decreases_loss():
     """End-to-end: gradient descent through the Pallas forward kernel."""
     sc, inp, flt, _ = _setup(4, 3, 4, 8, 3, 1, 1, seed=5)
     target = ref.conv_ref(inp, jnp.ones_like(flt) * 0.1, sc)
+    plans = make_training_plans(sc)
 
     def loss(f):
-        return jnp.mean((mg3m_conv_trainable(inp, f, sc) - target) ** 2)
+        return jnp.mean((conv_with_plans(inp, f, plans) - target) ** 2)
 
     f = flt
     l0 = float(loss(f))

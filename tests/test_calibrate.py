@@ -11,7 +11,7 @@ from repro.core import mapping
 from repro.core.mapping import (CostModel, ClassCorrection, ai_band,
                                 class_key, grid_steps, select_schedule)
 from repro.core.scene import ConvScene
-from repro.kernels.ops import resolve_choice
+from repro.plan.build import resolve_policy
 from repro import tune
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -236,13 +236,13 @@ def test_active_model_used_on_selection(tuned_cache, no_active_model,
                        str(tmp_path / "nonexistent.json"))
     sc = scene_grid()[0]
     assert tune.active_cost_model() is mapping.DEFAULT_COST_MODEL
-    assert resolve_choice(sc, None) == select_schedule(sc)
+    assert resolve_policy(sc, None) == select_schedule(sc)
 
     report = tune.fit_calibration(tuned_cache)
     model = report.cost_model()
     tune.set_active_cost_model(model)
     assert tune.active_cost_model() is model
-    got = resolve_choice(sc, None)
+    got = resolve_policy(sc, None)
     assert got == select_schedule(sc, model=model)
 
 
@@ -279,8 +279,8 @@ def test_malformed_artifact_never_crashes_auto_path(no_active_model,
     try:
         assert tune.active_cost_model() is mapping.DEFAULT_COST_MODEL
         sc = scene_grid()[0]
-        assert resolve_choice(sc, "auto") == select_schedule(sc)
-        assert resolve_choice(sc, None) == select_schedule(sc)
+        assert resolve_policy(sc, "auto") == select_schedule(sc)
+        assert resolve_policy(sc, None) == select_schedule(sc)
     finally:
         tune.set_default_cache(None)
 
@@ -310,8 +310,8 @@ def test_auto_cache_miss_uses_calibrated_model(no_active_model, tmp_path):
         flipped = select_schedule(sc, model=model)
         assert flipped.schedule != base.schedule     # premise of the test
         tune.set_active_cost_model(model)
-        assert resolve_choice(sc, "auto").schedule == flipped.schedule
-        assert resolve_choice(sc, None).schedule == flipped.schedule
+        assert resolve_policy(sc, "auto").schedule == flipped.schedule
+        assert resolve_policy(sc, None).schedule == flipped.schedule
     finally:
         tune.set_default_cache(None)
 

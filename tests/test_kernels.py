@@ -10,12 +10,12 @@ import numpy as np
 import pytest
 
 from repro.analysis.footprint import strip_width
-from repro.core.conv import mg3m_conv, mg3m_conv_nhwc
+from repro.core.conv import mg3m_conv_nhwc
 from repro.core.mapping import ScheduleChoice
 from repro.core.scene import ConvScene
 from repro.kernels import mg3m_conv as K
 from repro.kernels import ref
-from repro.kernels.ops import causal_conv1d_op
+from repro.kernels.causal_conv1d import causal_conv1d_op
 from repro.plan import ConvOp, build, make_plan
 
 SCENES = [
@@ -43,7 +43,7 @@ def test_mg3m_conv_schedules_match_oracle(spec, schedule):
     inp = jax.random.normal(k1, sc.in_shape(), jnp.float32)
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
     want = ref.conv_ref(inp, flt, sc)
-    got = mg3m_conv(inp, flt, sc, schedule=schedule)
+    got = make_plan(sc, policy=schedule).execute(inp, flt)
     # fp32 accumulation order differs between the Pallas grid walk and the
     # lax oracle; spec2 (K=32*25 taps) lands ~9e-5 relative on one element.
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
@@ -55,7 +55,7 @@ def test_mg3m_conv_autoselect(spec):
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     inp = jax.random.normal(k1, sc.in_shape(), jnp.float32)
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.float32)
-    got = mg3m_conv(inp, flt, sc)
+    got = make_plan(sc).execute(inp, flt)
     np.testing.assert_allclose(got, ref.conv_ref(inp, flt, sc),
                                rtol=3e-5, atol=3e-5)
 
@@ -65,7 +65,7 @@ def test_mg3m_conv_bf16():
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
     inp = jax.random.normal(k1, sc.in_shape(), jnp.bfloat16)
     flt = jax.random.normal(k2, sc.flt_shape(), jnp.bfloat16)
-    got = mg3m_conv(inp, flt, sc, schedule="TB88")
+    got = make_plan(sc, policy="TB88").execute(inp, flt)
     want = ref.conv_ref(inp, flt, sc)
     np.testing.assert_allclose(got.astype(np.float32),
                                want.astype(np.float32), rtol=2e-2, atol=2e-2)
@@ -143,7 +143,7 @@ def test_kernel_dot_precision_follows_dtype(schedule, dtype):
     w = jnp.zeros(sc.flt_shape(), dtype)
     with jax.default_matmul_precision("bfloat16"):
         jaxpr = jax.make_jaxpr(
-            lambda a, b: mg3m_conv(a, b, sc, schedule=schedule))(x, w)
+            make_plan(sc, policy=schedule).execute)(x, w)
     precs = _dot_precisions(jaxpr.jaxpr)
     assert precs
     highest = (jax.lax.Precision.HIGHEST,) * 2

@@ -15,7 +15,7 @@ from repro.analysis.verify import verify_plan
 from repro.core.conv import mg3m_conv_nhwc
 from repro.core.mapping import ScheduleChoice
 from repro.core.scene import ConvScene
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.models.cnn import resnet_scenes
 from repro.obs.metrics import default_metrics
 from repro.plan import (ConvOp, PlanRegistry, default_registry, get_plan,
@@ -222,23 +222,6 @@ def test_execute_performs_zero_resolutions_and_cache_io(monkeypatch):
         f"{after_build} -> {calls}")
 
 
-def test_legacy_per_call_path_still_resolves_per_call(monkeypatch):
-    """The shim keeps the legacy contract: resolution on every call."""
-    sc = _scene(*SCENES["plain"])
-    inp, flt, _ = _operands(sc)
-    calls = {"n": 0}
-    orig = build_mod.resolve_policy
-
-    def counting(*a, **kw):
-        calls["n"] += 1
-        return orig(*a, **kw)
-
-    monkeypatch.setattr(build_mod, "resolve_policy", counting)
-    ops.mg3m_conv_op(inp, flt, sc)
-    ops.mg3m_conv_op(inp, flt, sc)
-    assert calls["n"] == 2
-
-
 # -- registry ----------------------------------------------------------------
 def test_registry_hit_miss_and_identity():
     reg = PlanRegistry()
@@ -410,10 +393,11 @@ def test_nhwc_channel_mismatch_raises_value_error():
 def test_conv_op_shape_mismatch_raises_value_error():
     sc = _scene(*SCENES["plain"])
     inp, flt, _ = _operands(sc)
-    with pytest.raises(ValueError, match="IN layout"):
-        ops.mg3m_conv_op(inp[:-1], flt, sc)
-    with pytest.raises(ValueError, match="FLT layout"):
-        ops.mg3m_conv_op(inp, flt[..., :-1], sc)
+    plan = make_plan(sc)
+    with pytest.raises(ValueError, match="expects operands"):
+        plan.execute(inp[:-1], flt)
+    with pytest.raises(ValueError, match="expects operands"):
+        plan.execute(inp, flt[..., :-1])
 
 
 def test_scene_rejects_unparseable_dtype():
@@ -427,7 +411,7 @@ def test_plans_are_frozen_and_hashable():
     plan = make_plan(sc)
     hash(plan)   # jit-stability requires hashable static plans
     with pytest.raises(dataclasses.FrozenInstanceError):
-        plan.use_pallas = False
+        plan.policy = "forced:TB88"
 
 
 # -- kernel mode derived from the platform -----------------------------------
@@ -442,7 +426,10 @@ def test_kernel_mode_follows_platform(monkeypatch):
 
 def _entry_points():
     from repro.core import autodiff, conv
+    from repro.kernels.causal_conv1d import causal_conv1d_op
+    from repro.models.cnn import small_cnn_forward
     from repro.serve.conv import ConvServer
+    from repro.serve.sched import ConvScheduler
     from repro.shard import autodiff as shard_autodiff
     from repro.shard import plan as shard_plan
     from repro.tune import autotune, measure
@@ -452,10 +439,12 @@ def _entry_points():
         "registry.get": PlanRegistry.get,
         "registry.get_or_build": PlanRegistry.get_or_build,
         "registry.warm": PlanRegistry.warm, "get_plan": get_plan,
-        "ConvServer": ConvServer, "mg3m_conv": conv.mg3m_conv,
-        "mg3m_conv_op": ops.mg3m_conv_op,
-        "causal_conv1d_op": ops.causal_conv1d_op,
+        "ConvServer": ConvServer, "ConvScheduler": ConvScheduler,
+        "mg3m_conv_nhwc": conv.mg3m_conv_nhwc,
+        "causal_conv1d_op": causal_conv1d_op,
+        "make_training_plans": autodiff.make_training_plans,
         "make_model_plans": autodiff.make_model_plans,
+        "small_cnn_forward": small_cnn_forward,
         "make_sharded_plan": shard_plan.make_sharded_plan,
         "make_sharded_training_plans":
             shard_autodiff.make_sharded_training_plans,
@@ -466,16 +455,32 @@ def _entry_points():
 
 @pytest.mark.parametrize("name", sorted(_entry_points()))
 def test_no_interpret_option_above_the_kernels(name):
+    """Neither the kernel mode nor a Pallas-or-reference switch is an
+    option of any entry point: the platform decides the one, the plan the
+    other."""
     import inspect
-    assert "interpret" not in inspect.signature(
-        _entry_points()[name]).parameters
+    params = inspect.signature(_entry_points()[name]).parameters
+    assert "interpret" not in params and "use_pallas" not in params
 
 
-def test_registry_loads_artifact_with_interpret_field(tmp_path):
+# An old artifact's (key fragment, entry fields) and whether it still loads
+_OLD_ARTIFACTS = {
+    "interpret": ("|int=1", {"interpret": True}, True),
+    "pallas_on": ("|pl=1", {"use_pallas": True}, True),
+    "pallas_off": ("|pl=0", {"use_pallas": False}, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OLD_ARTIFACTS))
+def test_registry_loads_artifact_with_interpret_field(tmp_path, case):
     """An artifact written when plans carried the kernel mode (``|int=``
-    in the key, ``interpret`` in the entry) still loads, serves its plans
-    under today's keys, and is rewritten without the field."""
+    in the key, ``interpret`` in the entry) or the Pallas switch (``|pl=1``,
+    ``use_pallas: true``) still loads, serves its plans under today's keys,
+    and is rewritten without the field.  A ``use_pallas: false`` entry
+    describes a plan no longer built: ``load`` skips it, ``save`` drops
+    it."""
     import json
+    frag, fields, loads = _OLD_ARTIFACTS[case]
     sc = _scene(*SCENES["plain"])
     reg = PlanRegistry()
     plan = reg.get_or_build(sc)
@@ -484,16 +489,50 @@ def test_registry_loads_artifact_with_interpret_field(tmp_path):
     with open(path) as f:
         doc = json.load(f)
     (key, entry), = doc["plans"].items()
-    old_key = key.replace("|pl=", "|int=1|pl=")
-    doc["plans"] = {old_key: dict(entry, interpret=True)}
+    old_key = key.replace("|dt=", frag + "|dt=")
+    doc["plans"] = {old_key: dict(entry, **fields)}
     with open(path, "w") as f:
         json.dump(doc, f)
     fresh = PlanRegistry()
-    assert fresh.load(path) == 1
+    assert fresh.load(path) == int(loads)
     got = fresh.get(sc)
-    assert got is not None and got.choice == plan.choice
+    if loads:
+        assert got is not None and got.choice == plan.choice
+    else:
+        assert got is None
     fresh.save(path)
     with open(path) as f:
         saved = json.load(f)["plans"]
-    assert list(saved) == [key]
-    assert "interpret" not in saved[key]
+    assert list(saved) == ([key] if loads else [])
+    if loads:
+        assert not set(fields) & set(saved[key])
+
+
+# -- the conv system stands apart from the LM substrate ----------------------
+_LM_SUBSTRATE = ("repro.models.transformer", "repro.models.mamba2",
+                 "repro.models.moe", "repro.models.rwkv6",
+                 "repro.models.layers", "repro.train.step", "repro.parallel",
+                 "repro.configs")
+
+
+def test_conv_system_imports_no_lm_substrate():
+    """The modules the cells enter through (training, the CNN models,
+    serving, plans) load no module of the LM substrate."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys\n"
+            "import repro.train.cnn, repro.models.cnn, repro.serve.sched\n"
+            "import repro.plan\n"
+            f"lm = {_LM_SUBSTRATE!r}\n"
+            "print(sorted(m for m in sys.modules\n"
+            "             if any(m == p or m.startswith(p + '.') "
+            "for p in lm)))\n")
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(repo, "src")
+               + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.strip() == "[]", proc.stdout

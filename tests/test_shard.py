@@ -171,8 +171,8 @@ def test_selector_respects_axis_restriction():
 
 
 def test_plan_signature_shard_fragment():
-    base = plan_signature(SC, ConvOp.FPROP, "analytic", True)
-    tagged = plan_signature(SC, ConvOp.FPROP, "analytic", True, shard="h:8")
+    base = plan_signature(SC, ConvOp.FPROP, "analytic")
+    tagged = plan_signature(SC, ConvOp.FPROP, "analytic", shard="h:8")
     assert tagged == base + "|shard=h:8"
 
 

@@ -11,8 +11,8 @@ from repro.core.scene import ConvScene
 from repro.data.pipeline import SyntheticImages
 from repro.models.cnn import (cnn_forward_planned, init_cnn_from_scenes,
                               init_small_cnn, small_cnn_forward,
-                              small_cnn_plans, validate_scene_chain,
-                              vgg_style_scenes)
+                              small_cnn_plans, small_cnn_reference,
+                              validate_scene_chain, vgg_style_scenes)
 from repro.obs.metrics import default_metrics
 from repro.train import checkpoint as ckpt
 from repro.train import cnn as tc
@@ -107,8 +107,8 @@ def test_validate_scene_chain_raises_on_break():
 def test_small_cnn_forward_plan_path_matches_reference():
     params, plans = _model()
     x = jax.random.normal(jax.random.PRNGKey(3), (B, RES, RES, 3))
-    ref = small_cnn_forward(params, x, use_pallas=False)
-    got = small_cnn_forward(params, x, use_pallas=True, plans=plans)
+    ref = small_cnn_reference(params, x)
+    got = small_cnn_forward(params, x, plans=plans)
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -116,8 +116,8 @@ def test_small_cnn_forward_plan_path_matches_reference():
 # the fused train step
 # ---------------------------------------------------------------------------
 def test_multi_step_loss_descent_parity_vs_reference():
-    """Same seed, same data: the plan-driven step and a use_pallas=False
-    reference step produce allclose losses at every step, and both
+    """Same seed, same data: the plan-driven step and a
+    ``small_cnn_reference`` step produce allclose losses at every step, and both
     descend."""
     params, plans = _model()
     cfg = AdamWConfig(lr=1e-2, warmup_steps=1, total_steps=20)
@@ -132,7 +132,7 @@ def test_multi_step_loss_descent_parity_vs_reference():
         plan_losses.append(float(ms["loss"]))
 
     def ref_loss(p, b):
-        logits = small_cnn_forward(p, b["images"], use_pallas=False)
+        logits = small_cnn_reference(p, b["images"])
         return tc.softmax_cross_entropy(logits, b["labels"]), {
             "accuracy": (logits.argmax(-1) == b["labels"]).mean()}
 

@@ -13,7 +13,7 @@ from repro.core.mapping import SCHEDULES, VMEM_BUDGET, _vmem_bytes, \
     select_schedule
 from repro.core.scene import ConvScene
 from repro.kernels import ref
-from repro.kernels.ops import resolve_choice
+from repro.plan.build import make_plan, resolve_policy
 from repro import tune
 
 SC = ConvScene(B=8, IC=16, OC=24, inH=10, inW=10, fltH=3, fltW=3,
@@ -323,18 +323,23 @@ def test_measure_timeout_enforced_during_warmup(monkeypatch):
     past timeout_s because the warmup loop never checked the budget."""
     from repro.tune import measure as measure_mod
 
+    import repro.plan.build as build_mod
     calls = []
 
-    def slow_op(inp, flt, scene, schedule=None):
-        calls.append(1)
-        time.sleep(0.05)
-        import jax.numpy as jnp
-        return jnp.zeros(scene.out_shape(), jnp.float32)
+    class SlowPlan:
+        def __init__(self, scene):
+            self.scene = scene
+
+        def execute(self, inp, flt):
+            calls.append(1)
+            time.sleep(0.05)
+            import jax.numpy as jnp
+            return jnp.zeros(self.scene.out_shape(), jnp.float32)
 
     monkeypatch.setattr(measure_mod, "make_operands",
                         lambda scene, seed=0: (None, None))
-    import repro.kernels.ops as ops_mod
-    monkeypatch.setattr(ops_mod, "mg3m_conv_op", slow_op)
+    monkeypatch.setattr(build_mod, "make_plan",
+                        lambda scene, *a, **kw: SlowPlan(scene))
     choice = tune.ranked_space(SC, top_k=1)[0]
     t0 = time.perf_counter()
     us = tune.measure_choice(SC, choice, warmup=100, iters=3,
@@ -391,21 +396,19 @@ def test_forced_infeasible_schedule_raises():
     with pytest.raises(ValueError, match="TB11"):
         select_schedule(BIG_TB11_INFEASIBLE, allowed=("TB11",))
     with pytest.raises(ValueError, match="TB11"):
-        resolve_choice(BIG_TB11_INFEASIBLE, "TB11")
+        resolve_policy(BIG_TB11_INFEASIBLE, "TB11")
     # the unforced selector still works (TB88 escape hatch stays available)
     assert select_schedule(BIG_TB11_INFEASIBLE).schedule in SCHEDULES
 
 
 def test_mg3m_conv_never_silently_substitutes_forced_schedule():
-    from repro.core.conv import mg3m_conv
-    inp, flt = tune.make_operands(BIG_TB11_INFEASIBLE)
     with pytest.raises(ValueError, match="TB11"):
-        mg3m_conv(inp, flt, BIG_TB11_INFEASIBLE, schedule="TB11")
+        make_plan(BIG_TB11_INFEASIBLE, policy="TB11")
 
 
 def test_forced_feasible_schedule_still_honored():
     for sched in SCHEDULES:
-        choice = resolve_choice(SC, sched)
+        choice = resolve_policy(SC, sched)
         assert choice.schedule == sched
 
 
@@ -418,7 +421,7 @@ def test_ranked_space_restricted_schedules_never_substitute():
 def test_auto_dispatch_cache_hit_and_miss(fresh_default_cache):
     cache = fresh_default_cache
     # miss: falls back to the analytic model
-    assert resolve_choice(SC, "auto") == select_schedule(SC)
+    assert resolve_policy(SC, "auto") == select_schedule(SC)
     assert cache.misses == 1 and cache.hits == 0
     # hit: returns the cached (deliberately non-analytic) choice exactly
     ranked = tune.ranked_space(SC)
@@ -427,7 +430,7 @@ def test_auto_dispatch_cache_hit_and_miss(fresh_default_cache):
     from repro.tune.cache import choice_to_dict
     cache.put(SC, {"choice": choice_to_dict(cached_choice),
                    "measured_us": 1.0})
-    assert resolve_choice(SC, "auto") == cached_choice
+    assert resolve_policy(SC, "auto") == cached_choice
     assert cache.hits == 1
 
 
@@ -439,9 +442,8 @@ def test_mg3m_conv_auto_matches_oracle(fresh_default_cache):
     tune.autotune_scene(SC, cache=cache, top_k=2, iters=1,
                         measure_max_hw=6)
     hits0 = cache.hits
-    from repro.core.conv import mg3m_conv
     inp, flt = tune.make_operands(SC)
-    got = mg3m_conv(inp, flt, SC, schedule="auto")
+    got = make_plan(SC, policy="auto").execute(inp, flt)
     np.testing.assert_allclose(got, ref.conv_ref(inp, flt, SC),
                                rtol=3e-5, atol=3e-5)
     assert cache.hits == hits0 + 1
@@ -449,9 +451,8 @@ def test_mg3m_conv_auto_matches_oracle(fresh_default_cache):
 
 def test_mg3m_conv_accepts_explicit_choice():
     choice = tune.ranked_space(SC)[-1]   # worst-predicted, still feasible
-    from repro.core.conv import mg3m_conv
     inp, flt = tune.make_operands(SC)
-    got = mg3m_conv(inp, flt, SC, schedule=choice)
+    got = make_plan(SC, policy=choice).execute(inp, flt)
     np.testing.assert_allclose(got, ref.conv_ref(inp, flt, SC),
                                rtol=3e-5, atol=3e-5)
 
@@ -481,7 +482,7 @@ def test_tune_cli_writes_resolvable_artifact(tmp_path):
     cache = tune.ScheduleCache(path)
     tune.set_default_cache(cache)
     try:
-        choice = resolve_choice(scene, "auto")
+        choice = resolve_policy(scene, "auto")
         assert cache.hits == 1, "auto path must resolve from the artifact"
         assert choice.schedule in SCHEDULES
     finally:
