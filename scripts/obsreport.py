@@ -214,23 +214,27 @@ def trace_report(doc: Dict) -> Dict:
                   "dropped_events", 0),
               "wall_s": (span[1] - span[0]) * 1e-6 if events else 0.0,
               "spans": spans}
-    # per-layer breakdown of whole-model pipeline dispatches: the
-    # scheduler's metrics histograms aggregate across layers, so the
-    # per-layer quantiles live here, keyed off the layer span args
-    layers: Dict[str, List[float]] = {}
+    # whole-model pipeline dispatches, per net: each group is one compiled
+    # program over all its layers (the per-layer split of device time is
+    # in the program's ``repro.serve.<layer>`` op names), so the host side
+    # reads the model_dispatch span's ``layers`` and ``programs`` args
+    nets: Dict[str, List[Dict]] = {}
     for e in events:
-        if (e["name"] == "repro.serve.layer_dispatch"
-                and e.get("args", {}).get("layer")):
-            layers.setdefault(e["args"]["layer"], []).append(e["dur"] * 1e-6)
-    if layers:
-        per_layer = {}
-        for lname, durs in sorted(layers.items()):
-            durs.sort()
-            per_layer[lname] = {
-                "count": len(durs), "mean_s": sum(durs) / len(durs),
+        args = e.get("args", {})
+        if e["name"] == "repro.serve.model_dispatch" and "layers" in args:
+            nets.setdefault(args.get("net", "?"), []).append(
+                dict(args, dur=e["dur"] * 1e-6))
+    if nets:
+        per_net = {}
+        for net, ds in sorted(nets.items()):
+            durs = sorted(d["dur"] for d in ds)
+            per_net[net] = {
+                "count": len(ds), "layers": max(d["layers"] for d in ds),
+                "programs_max": max(d["programs"] for d in ds),
+                "mean_s": sum(durs) / len(durs),
                 "p50_s": _percentile(durs, 0.5),
                 "p99_s": _percentile(durs, 0.99), "max_s": durs[-1]}
-        report["layers"] = per_layer
+        report["layers"] = per_net
     return report
 
 
@@ -244,9 +248,10 @@ def print_trace_report(report: Dict) -> None:
               f"p90={_fmt_s(s['p90_s'])} p99={_fmt_s(s['p99_s'])} "
               f"max={_fmt_s(s['max_s'])}")
     if "layers" in report:
-        print("== per-layer dispatch (model sessions) ==")
-        for lname, s in report["layers"].items():
-            print(f"  {lname:<34} n={s['count']:<6} "
+        print("== model dispatch (one chain program per group) ==")
+        for net, s in report["layers"].items():
+            print(f"  {net:<34} n={s['count']:<6} layers={s['layers']} "
+                  f"programs<={s['programs_max']} "
                   f"mean={_fmt_s(s['mean_s'])} p50={_fmt_s(s['p50_s'])} "
                   f"p99={_fmt_s(s['p99_s'])} max={_fmt_s(s['max_s'])}")
 

@@ -44,7 +44,8 @@ Observability: every server owns a ``MetricRegistry`` (``repro.serve.*``
 counters + queue-wait/enqueue histograms; ``stats(since=snapshot())``
 windows them).  Each stage a request passes through runs under a span —
 ``repro.serve.submit``, ``repro.serve.step``, ``repro.serve.dispatch``,
-``repro.serve.glue`` (concat/pad before the plan, lane slices after),
+``repro.serve.glue`` (concat/pad before the plan, lane slices after; one
+compiled call each, whatever the group size),
 ``repro.plan.execute`` and, at start-up, ``repro.serve.prewarm`` — which
 becomes a host event of a recording ``jax.profiler`` session and, with the
 tracer enabled, a buffered Chrome-trace event (``repro.obs.trace``).
@@ -59,6 +60,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import os
 import threading
@@ -128,6 +130,38 @@ def bucket_ladder(scene: ConvScene, max_batch: int, *, min_bucket: int = 1,
         if times[kept[0]] > slack * times[b]:
             kept.insert(0, b)
     return tuple(kept)
+
+
+# --------------------------------------------------------------------------
+# lane glue — a group's gather before the plans and split after, each one
+# compiled call whatever the group size
+# --------------------------------------------------------------------------
+def _needs_gather(group: Sequence["ConvRequest"], bucket: int) -> bool:
+    return len(group) > 1 or group[0]._b < bucket
+
+
+def _needs_split(group: Sequence["ConvRequest"], bucket: int) -> bool:
+    return len(group) > 1 or group[0]._squeeze or group[0]._b < bucket
+
+
+@functools.partial(jax.jit, static_argnames=("bucket",))
+def _gather_lanes(xs: Tuple[jax.Array, ...], bucket: int) -> jax.Array:
+    """``xs`` concatenated along B and zero-padded to ``bucket`` lanes."""
+    x = jnp.concatenate(xs, axis=3)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, bucket - x.shape[3])))
+
+
+@functools.partial(jax.jit, static_argnames=("widths", "squeeze"))
+def _split_lanes(out: jax.Array, widths: Tuple[int, ...],
+                 squeeze: Tuple[bool, ...]) -> Tuple[jax.Array, ...]:
+    """Consecutive lane slices of ``out``, ``widths[i]`` lanes each, the
+    batch axis dropped where ``squeeze[i]``."""
+    outs, off = [], 0
+    for b, sq in zip(widths, squeeze):
+        sl = out[..., off:off + b]
+        outs.append(sl[..., 0] if sq else sl)
+        off += b
+    return tuple(outs)
 
 
 # --------------------------------------------------------------------------
@@ -519,16 +553,25 @@ class ConvServer:
                 self._h_wait.observe(t_start - r._t_submit)
         return t_start
 
-    def _gather(self, group: List[ConvRequest], bucket: int,
-                total: int) -> jax.Array:
+    def _gather(self, group: List[ConvRequest], bucket: int) -> jax.Array:
         """The group's tensors concatenated along B and zero-padded to
-        ``bucket`` lanes, under a ``repro.serve.glue`` span."""
+        ``bucket`` lanes (one compiled call, none for a lone full-bucket
+        request), under a ``repro.serve.glue`` span."""
         with self.tracer.span("repro.serve.glue"):
-            x = (group[0].x if len(group) == 1
-                 else jnp.concatenate([r.x for r in group], axis=3))
-            if bucket > total:
-                x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, bucket - total)))
-            return x
+            if not _needs_gather(group, bucket):
+                return group[0].x
+            return _gather_lanes(tuple(r.x for r in group), bucket)
+
+    def _split(self, group: List[ConvRequest], out: jax.Array,
+               bucket: int) -> Sequence[jax.Array]:
+        """Each request's lanes of ``out``, squeezed where it was submitted
+        3-D (one compiled call, none for a lone full-bucket 4-D request),
+        under a ``repro.serve.glue`` span."""
+        with self.tracer.span("repro.serve.glue"):
+            if not _needs_split(group, bucket):
+                return (out,)
+            return _split_lanes(out, tuple(r._b for r in group),
+                                tuple(r._squeeze for r in group))
 
     @staticmethod
     def _fail(group: List[ConvRequest], e: BaseException) -> None:
@@ -539,20 +582,15 @@ class ConvServer:
             if r._event is not None:
                 r._event.set()
 
-    def _complete(self, group: List[ConvRequest], out: jax.Array,
+    def _complete(self, group: List[ConvRequest], outs: Sequence[jax.Array],
                   bucket: int, total: int, t_start: float) -> None:
-        """Slice each request's lanes out of ``out`` (a
-        ``repro.serve.glue`` span), signal its waiters and record the
-        dispatch's counters; ``enqueue_s`` ends here, outputs in flight."""
-        with self.tracer.span("repro.serve.glue"):
-            off = 0
-            for r in group:
-                sl = out[..., off:off + r._b]
-                off += r._b
-                r.out = sl[..., 0] if r._squeeze else sl
-                r.done = True
-                if r._event is not None:
-                    r._event.set()
+        """Hand each request its output (``outs``, in group order, from
+        ``_split``), signal its waiters and record the dispatch's counters;
+        ``enqueue_s`` ends here, outputs in flight."""
+        for r, o in zip(group, outs):
+            r.out, r.done = o, True
+            if r._event is not None:
+                r._event.set()
         self._h_enqueue.observe(time.perf_counter() - t_start)
         self._c_requests.inc(len(group))
         self._c_dispatches.inc()
@@ -572,14 +610,14 @@ class ConvServer:
                 op = group[0].op
                 total = sum(r._b for r in group)
                 bucket = self._bucket_for(fam, op, total)
-                x = self._gather(group, bucket, total)
+                x = self._gather(group, bucket)
                 plan = self._plan(fam, op, bucket)
-                out = plan.execute(x, fam.flt)
+                outs = self._split(group, plan.execute(x, fam.flt), bucket)
             except BaseException as e:  # noqa: BLE001 — propagated to every
                 # waiter in the group (r.error), then re-raised
                 self._fail(group, e)
                 raise
-            self._complete(group, out, bucket, total, t_start)
+            self._complete(group, outs, bucket, total, t_start)
             if sp:
                 sp.set(layer=fam.layer, op=op.value, bucket=bucket,
                        occupied=total, requests=len(group))

@@ -48,16 +48,25 @@ deadlines blocks on its result before ``repro.serve.deadline_misses`` /
 ready, not merely enqueued.  Nothing else in the loop waits for the device,
 traced or not.
 
+A whole-model group is at most three host enqueues, whatever the layer
+count or group size: the gather (concat and pad), one compiled program per
+(net, bucket) that runs every layer's plan and the activation — weights
+are its arguments, and ``prewarm(compile=True)`` compiles it for every
+flush rung (``repro.serve.chain_builds`` counts those compiles) — and the
+split into each request's output.  Each layer traces under
+``jax.named_scope("repro.serve.<layer>")``, so the device trace still
+splits the program's time by layer through its ops' ``op_name``.
+
 Tracing follows ``ConvServer`` (``repro.serve.conv``): ``repro.serve.step``
 spans each scheduling decision and its dispatch (args: the flush reason),
 ``repro.serve.poll`` each idle sleep of ``drain`` and the background loop
 (args: the queue depth; ``repro.serve.poll_s`` histograms its seconds), and
-a whole-model group runs under ``repro.serve.model_dispatch`` with a
-``repro.serve.glue`` span on each side of one ``repro.serve.layer_dispatch``
-span per layer (its ``plan.execute`` and activation).  So the scheduler's
-thread is under a ``repro.serve.*`` span at every moment but a few µs of
-loop overhead, and a profiler trace names what the host did while the
-device idled.
+a whole-model group runs under ``repro.serve.model_dispatch`` (args: the
+net, bucket, lanes occupied, requests, ``layers`` and ``programs``, the
+host enqueues it made) with a ``repro.serve.glue`` span on each side of
+the chain program's call.  So the scheduler's thread is under a
+``repro.serve.*`` span at every moment but a few µs of loop overhead, and
+a profiler trace names what the host did while the device idled.
 """
 from __future__ import annotations
 
@@ -77,7 +86,8 @@ from repro.models.cnn import validate_scene_chain
 from repro.obs.metrics import snapshot_delta, snapshot_value
 from repro.plan import ConvOp
 from repro.serve.conv import (ConvRequest, ConvServer, DispatchRecord,
-                              _Family, bucket_ladder, seeded_weights)
+                              _Family, _needs_gather, _needs_split,
+                              bucket_ladder, seeded_weights)
 
 __all__ = ["Overloaded", "SchedConfig", "ModelRequest", "ModelSession",
            "ConvScheduler", "scheduler_from_scenes"]
@@ -151,6 +161,33 @@ class _NetChain:
     name: str
     layers: Tuple[str, ...]
     activation: Optional[Callable[[jax.Array], jax.Array]]
+
+
+@dataclasses.dataclass(frozen=True)
+class _ChainProgram:
+    """One net's forward at one bucket, compiled: the plans it was traced
+    from (a registry that hands out another plan forces a rebuild), the
+    weights it is called with, and the executable."""
+
+    plans: Tuple
+    flts: Tuple[jax.Array, ...]
+    run: Callable[[jax.Array, Tuple[jax.Array, ...]], jax.Array]
+
+
+def _chain_forward(chain: _NetChain, plans: Sequence
+                   ) -> Callable[[jax.Array, Tuple[jax.Array, ...]], jax.Array]:
+    """The whole forward of ``chain`` as one traceable function of the
+    input and the weights: every layer's ``plan.execute`` and the
+    activation, each layer under ``jax.named_scope("repro.serve.<layer>")``
+    so its ops keep the layer's name in the compiled program."""
+    def serve_chain(x, ws):
+        for lname, plan, w in zip(chain.layers, plans, ws):
+            with jax.named_scope(f"repro.serve.{lname}"):
+                x = plan.execute(x, w)
+                if chain.activation is not None:
+                    x = chain.activation(x)
+        return x
+    return serve_chain
 
 
 def _urgency(r: ConvRequest) -> Tuple[float, float]:
@@ -227,6 +264,9 @@ class ConvScheduler(ConvServer):
         # seconds of each idle sleep of the loop: the sum is the host's
         # waiting time, the mean against poll_s its oversleep
         self._h_poll = self.metrics.histogram("repro.serve.poll_s")
+        self._c_chain_builds = self.metrics.counter(
+            "repro.serve.chain_builds")
+        self._chains: Dict[Tuple[str, int], _ChainProgram] = {}
         self._stop_evt = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -277,8 +317,10 @@ class ConvScheduler(ConvServer):
         power-of-two *flush ladder* of every family and record the cost
         model's per-bucket prediction — a deadline flush at any sub-rung
         bucket is then a pure registry hit, and partial-bucket choice is a
-        dict lookup.  ``compile=True`` JIT-warms the flush rungs too, so a
-        deadline flush never pays kernel JIT inside a latency budget."""
+        dict lookup.  ``compile=True`` JIT-warms the flush rungs too, and
+        compiles every registered net's chain program at every flush rung,
+        so neither a deadline flush nor a model group pays a compile inside
+        a latency budget."""
         built = super().prewarm(artifact, compile=compile)
         with self._lock:
             families = list(self._layers.values())
@@ -302,6 +344,14 @@ class ConvScheduler(ConvServer):
                 with self._prewarm_span(fam, "flush_compile"):
                     self._compile(fam, [b for b in self._flush_rungs[fam.layer]
                                         if b not in fam.ladder])
+            with self._lock:
+                chains = list(self._nets.values())
+            for chain in chains:
+                net = "@" + chain.name
+                with self.tracer.span("repro.serve.prewarm", layer=net,
+                                      stage="chain_compile"):
+                    for b in self._flush_rungs[chain.layers[0]]:
+                        self._chain_program(chain, b)
         with self._lock:
             self._warmed = True
         return built
@@ -525,35 +575,53 @@ class ConvScheduler(ConvServer):
             for l in chain.layers)
         return min(rungs, key=lambda b: (cost(b), b))
 
+    def _chain_program(self, chain: _NetChain, bucket: int) -> _ChainProgram:
+        """The compiled forward of ``chain`` at ``bucket`` lanes.  The plans
+        are looked up on every call, so a strict-mode miss still raises here
+        and the registry's counters see each dispatch; the program is
+        compiled once per (net, bucket) and again only when the registry
+        hands out another plan (``repro.serve.chain_builds``)."""
+        fams = [self._layers[l] for l in chain.layers]
+        plans = tuple(self._plan(f, ConvOp.FPROP, bucket) for f in fams)
+        key = (chain.name, bucket)
+        with self._lock:
+            prog = self._chains.get(key)
+        if prog is not None and prog.plans == plans:
+            return prog
+        flts = tuple(f.flt for f in fams)
+        x = jax.ShapeDtypeStruct(fams[0].a_spatial(ConvOp.FPROP) + (bucket,),
+                                 jnp.dtype(fams[0].base.dtype))
+        run = jax.jit(_chain_forward(chain, plans)).lower(x, flts).compile()
+        prog = _ChainProgram(plans=plans, flts=flts, run=run)
+        with self._lock:
+            self._chains[key] = prog
+        self._c_chain_builds.inc()
+        return prog
+
     def _dispatch_model(self, group: List[ConvRequest]) -> int:
-        """Execute one coalesced whole-model group: concat + pad once,
-        carry the activation through every layer in plan layout, slice
-        lanes back at the end.  Mirrors ``ConvServer._dispatch``'s tracing
-        and completion contract."""
+        """Execute one coalesced whole-model group in at most three host
+        enqueues: the gather, the (net, bucket) chain program, the split.
+        Mirrors ``ConvServer._dispatch``'s tracing and completion
+        contract."""
         t_start = self._observe_wait(group)
         chain = self._nets[group[0].net]
         with self.tracer.span("repro.serve.model_dispatch") as sp:
             try:
                 total = sum(r._b for r in group)
                 bucket = self._model_bucket(chain, total)
-                z = self._gather(group, bucket, total)
-                for lname in chain.layers:
-                    fam = self._layers[lname]
-                    plan = self._plan(fam, ConvOp.FPROP, bucket)
-                    with self.tracer.span("repro.serve.layer_dispatch") as lsp:
-                        if lsp:
-                            lsp.set(layer=lname, bucket=bucket)
-                        z = plan.execute(z, fam.flt)
-                        if chain.activation is not None:
-                            z = chain.activation(z)
+                prog = self._chain_program(chain, bucket)
+                x = self._gather(group, bucket)
+                outs = self._split(group, prog.run(x, prog.flts), bucket)
             except BaseException as e:  # noqa: BLE001 — propagated to every
                 # waiter in the group (r.error), then re-raised
                 self._fail(group, e)
                 raise
-            self._complete(group, z, bucket, total, t_start)
+            self._complete(group, outs, bucket, total, t_start)
             if sp:
                 sp.set(net=chain.name, bucket=bucket, occupied=total,
-                       requests=len(group))
+                       requests=len(group), layers=len(chain.layers),
+                       programs=1 + _needs_gather(group, bucket)
+                       + _needs_split(group, bucket))
         self._publish(DispatchRecord(
             layer=group[0].layer, op=ConvOp.FPROP, bucket=bucket,
             occupied=total, requests=len(group), schedule=None))

@@ -451,16 +451,24 @@ def test_tracing_never_blocks_dispatch(tmp_path, monkeypatch, mode):
     assert calls == [], "a traced dispatch waited on the device"
     assert traced == untraced
     assert {r.layer for r in traced} == {"l0", "@net"}
+    # a model group is one model_dispatch span over both layers, with at
+    # most three host enqueues; no per-layer host span is left
+    groups = sum(r.layer == "@net" for r in traced)
     if mode == "enabled":
         names = {e["name"] for e in tr.events()}
-        assert {"repro.serve.model_dispatch", "repro.serve.layer_dispatch",
-                "repro.serve.dispatch", "repro.serve.glue",
-                "repro.plan.execute"} <= names
+        assert {"repro.serve.model_dispatch", "repro.serve.dispatch",
+                "repro.serve.glue", "repro.plan.execute"} <= names
+        assert "repro.serve.layer_dispatch" not in names
+        model = [e["args"] for e in tr.events()
+                 if e["name"] == "repro.serve.model_dispatch"]
     else:
         _, host = _xplane(tmp_path)
-        layers = [e for _, e in host
-                  if e.name == "repro.serve.layer_dispatch"]
-        assert {dict(e.stats)["layer"] for e in layers} == {"a", "b"}
+        assert not any(e.name == "repro.serve.layer_dispatch"
+                       for _, e in host)
+        model = [dict(e.stats) for _, e in host
+                 if e.name == "repro.serve.model_dispatch"]
+    assert len(model) == groups >= 1
+    assert all(a["layers"] == 2 and 1 <= a["programs"] <= 3 for a in model)
 
 
 @pytest.mark.parametrize("traced", [False, True])

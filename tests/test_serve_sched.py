@@ -199,6 +199,10 @@ def test_strict_zero_resolution_steady_state(monkeypatch):
                                               max_gather_s=0.01))
     sched.register_net("resnet", chain, seed=3)
     sched.prewarm(compile=True)
+    # one compiled chain per flush rung, all of them built by the prewarm
+    rungs = sched.flush_ladders()[next(iter(chain))]
+    builds = sched.snapshot()["repro.serve.chain_builds"]["value"]
+    assert builds == len(rungs)
 
     def forbidden(*a, **kw):
         raise AssertionError("post-warm schedule resolution")
@@ -219,6 +223,11 @@ def test_strict_zero_resolution_steady_state(monkeypatch):
     assert s["plan_misses"] == 0 and s["plan_builds"] == 0
     assert s["registry"]["misses"] == 0
     assert s["deadline_flushes"] >= 1
+    # a second prewarm and a burst at every group size compile no chain
+    sched.prewarm(compile=True)
+    sess.serve([_x(sc0, 1, 200 + i) for i in range(8)])
+    sess.serve([_x(sc0, 1, 300 + i)[..., 0] for i in range(5)])
+    assert sched.snapshot()["repro.serve.chain_builds"]["value"] == builds
 
 
 def test_warmed_buckets_probe():
@@ -239,12 +248,31 @@ def test_warmed_buckets_probe():
 
 
 # -- whole-model sessions ----------------------------------------------------
+def _layer_by_layer(chain, flts, xs, activation=None):
+    """Each input served alone through a plain ``ConvServer``, one layer
+    per request, the activation applied between layers."""
+    server = server_from_scenes(chain, flts, max_batch=8, ladder_slack=0.0,
+                                strict=True)
+    server.prewarm()
+    outs = []
+    for x in xs:
+        cur = x
+        for i, lname in enumerate(chain):
+            r = ConvRequest(rid=i, layer=lname, x=cur)
+            server.serve([r])
+            cur = r.out if activation is None else activation(r.out)
+        outs.append(cur)
+    return outs
+
+
 @pytest.mark.parametrize("net", ["alexnet", "resnet"])
 def test_model_session_parity_vs_layer_by_layer(net):
     """A ``ModelSession`` burst through a registered chain is bitwise (f32)
     what a plain ``ConvServer`` produces serving the same images layer by
-    layer — pipelining the coalesced activation is a layout move, never a
-    numeric one."""
+    layer — pipelining the coalesced activation in one compiled program is
+    a layout move, never a numeric one — at every group size 1-8, for 3-D
+    (squeezed) and 4-D requests, and for a b > 1 request among single
+    images."""
     chain = cnn_chain_scenes(net, **CAPS)
     sched = ConvScheduler(max_batch=8, ladder_slack=0.0, strict=True,
                           config=SchedConfig(occupancy_target=8,
@@ -254,22 +282,108 @@ def test_model_session_parity_vs_layer_by_layer(net):
     flts = {ln: sched._layers[ln].flt for ln in chain}
 
     sc0 = chain[next(iter(chain))]
-    xs = [_x(sc0, 1, 40 + i) for i in range(5)]
     sess = sched.session(net)
-    outs = sess.serve(xs)
+    records = []
+    sched.on_dispatch = records.append
+    groups = [[_x(sc0, 1, 40 + 10 * g + i) for i in range(g)]
+              for g in range(1, 9)]
+    # odd positions go in 3-D and must come back 3-D
+    groups = [[x[..., 0] if i % 2 else x for i, x in enumerate(xs)]
+              for xs in groups]
+    groups.append([_x(sc0, 3, 7)] + [_x(sc0, 1, 8 + i)[..., 0]
+                                     for i in range(5)])
+    for xs in groups:
+        outs = sess.serve(xs)
+        for x, out, want in zip(xs, outs, _layer_by_layer(chain, flts, xs)):
+            assert out.ndim == x.ndim
+            assert np.array_equal(np.asarray(out), np.asarray(want))
+    assert [r.occupied for r in records] == [1, 2, 3, 4, 5, 6, 7, 8, 8]
     s = sched.stats()
-    assert s["dispatches"] >= 1 and s["plan_misses"] == 0
+    assert s["dispatches"] == len(groups) and s["plan_misses"] == 0
 
-    server = server_from_scenes(chain, flts, max_batch=8, ladder_slack=0.0,
-                                strict=True)
-    server.prewarm()
-    for x, out in zip(xs, outs):
-        cur = x
-        for i, lname in enumerate(chain):
-            r = ConvRequest(rid=i, layer=lname, x=cur)
-            server.serve([r])
-            cur = r.out
-        assert np.array_equal(np.asarray(out), np.asarray(cur))
+
+def test_model_chain_relu_parity_vs_layer_by_layer():
+    """With ReLU between layers (the activation the All-CNN-C cell
+    serves), the compiled chain is still bitwise layer-by-layer serving
+    with an eager ReLU after every layer: ReLU is exact."""
+    chain = cnn_chain_scenes("vgg", **CAPS)
+    sched = ConvScheduler(max_batch=8, ladder_slack=0.0, strict=True,
+                          config=SchedConfig(occupancy_target=8,
+                                             max_gather_s=0.02))
+    sched.register_net("vgg", chain, seed=5, activation=jax.nn.relu)
+    sched.prewarm(compile=True)
+    flts = {ln: sched._layers[ln].flt for ln in chain}
+    sc0 = chain[next(iter(chain))]
+    xs = [_x(sc0, 1, 60 + i)[..., 0] for i in range(5)]
+    outs = sched.session("vgg").serve(xs)
+    wants = _layer_by_layer(chain, flts, xs, activation=jax.nn.relu)
+    for out, want in zip(outs, wants):
+        assert np.array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_model_dispatch_enqueues_at_most_three_programs(depth):
+    """Whatever the layer count or group size, a whole-model group is at
+    most three host enqueues — gather, chain program, split — read from
+    the ``programs`` arg of its ``repro.serve.model_dispatch`` span; a lone
+    full-bucket 4-D request needs neither glue call."""
+    chain = cnn_chain_scenes("alexnet", max_hw=8, max_ch=8,
+                             layers_per_net=depth)
+    tracer = Tracer(enabled=True)
+    sched = ConvScheduler(max_batch=8, ladder_slack=0.0, strict=True,
+                          tracer=tracer,
+                          config=SchedConfig(occupancy_target=8,
+                                             max_gather_s=0.01))
+    sched.register_net("alexnet", chain)
+    sched.prewarm()
+    sess = sched.session("alexnet")
+    sc0 = chain[next(iter(chain))]
+    sess.serve([_x(sc0, 1, i)[..., 0] for i in range(7)])
+    sess.serve([_x(sc0, 1, 20)])
+    spans = [e["args"] for e in tracer.events()
+             if e["name"] == "repro.serve.model_dispatch"]
+    assert [(a["requests"], a["layers"], a["programs"]) for a in spans] == [
+        (7, len(chain), 3), (1, len(chain), 1)]
+    assert not any(e["name"] == "repro.serve.layer_dispatch"
+                   for e in tracer.events())
+
+
+def test_chain_activation_error_fails_group_loop_serves_next():
+    """An activation that raises inside the chain program fails every
+    waiter of its group with that error, and the background loop goes on
+    to serve the next group."""
+    chain = cnn_chain_scenes("alexnet", **CAPS)
+    calls = []
+
+    def flaky_relu(x):
+        calls.append(1)
+        if len(calls) == 1:
+            raise FloatingPointError("activation blew up")
+        return jax.nn.relu(x)
+
+    sched = ConvScheduler(max_batch=8, ladder_slack=0.0, strict=True,
+                          config=SchedConfig(occupancy_target=8,
+                                             max_gather_s=0.01))
+    sched.register_net("alexnet", chain, activation=flaky_relu)
+    sched.prewarm()
+    sess = sched.session("alexnet")
+    sc0 = chain[next(iter(chain))]
+    # queued before the loop starts, so the three form one group
+    bad = [sess.submit(_x(sc0, 1, i)) for i in range(3)]
+    sched.start()
+    try:
+        sched.wait(bad, raise_on_error=False)
+        good = [sess.submit(_x(sc0, 1, 10 + i)) for i in range(3)]
+        outs = sched.wait(good)
+    finally:
+        sched.stop()
+    assert all(isinstance(r.error, FloatingPointError) and r.out is None
+               for r in bad)
+    with pytest.raises(RuntimeError, match="failed in a coalesced"):
+        sched.wait(bad[:1])
+    assert all(r.error is None for r in good)
+    assert all(o is not None for o in outs)
+    assert sched.stats()["requests"] == 3
 
 
 def test_model_session_validation_and_registration():
@@ -346,9 +460,10 @@ def test_cnn_chain_scenes_chain_and_caps():
 
 # -- observability -----------------------------------------------------------
 def test_slo_report_and_layer_trace(tmp_path):
-    """The scheduler's counters surface through obsreport's slo section,
-    and traced model dispatches carry per-layer spans the trace report
-    groups by layer."""
+    """The scheduler's counters surface through obsreport's slo section;
+    a traced model dispatch is one span per group whose ``layers`` and
+    ``programs`` args the trace report groups by net, and each layer's
+    named scope is in the compiled chain's op names."""
     import importlib.util
     import json
     import os
@@ -387,10 +502,22 @@ def test_slo_report_and_layer_trace(tmp_path):
     tpath = tmp_path / "trace.json"
     tracer.export(str(tpath))
     treport = obsreport.trace_report(json.loads(tpath.read_text()))
-    assert "repro.serve.model_dispatch" in treport["spans"]
-    layer_stats = treport["layers"]
-    assert set(layer_stats) == set(chain)
-    assert all(v["count"] >= 1 for v in layer_stats.values())
+    # one model_dispatch per group, carrying its layer count and host
+    # enqueues; no per-layer host span is left
+    spans = treport["spans"]
+    assert spans["repro.serve.model_dispatch"]["count"] == \
+        slo["enqueue"]["count"]
+    assert "repro.serve.layer_dispatch" not in spans
+    net_stats = treport["layers"]
+    assert set(net_stats) == {"alexnet"}
+    assert net_stats["alexnet"]["count"] == slo["enqueue"]["count"]
+    assert net_stats["alexnet"]["layers"] == len(chain)
+    assert 1 <= net_stats["alexnet"]["programs_max"] <= 3
+    # the per-layer split lives in the compiled program's op names
+    for (net, bucket), prog in sched._chains.items():
+        hlo = prog.run.as_text()
+        for lname in chain:
+            assert f"repro.serve.{lname}/" in hlo, (bucket, lname)
 
 
 def test_scheduler_concurrent_submitters():

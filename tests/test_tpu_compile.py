@@ -150,3 +150,37 @@ def test_sharded_model_mixes_partitions_in_one_program(topo,
 
     compiled = jax.jit(jax.grad(loss)).lower(ws, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# All-CNN-C (arXiv:1412.6806, Table 2) as the serving cell runs it:
+# (IC, OC, in_hw, flt, pad, stride) per convolution, ReLU after each
+ALLCNN_C = ((3, 96, 32, 3, 1, 1), (96, 96, 32, 3, 1, 1),
+            (96, 96, 32, 3, 1, 2), (96, 192, 16, 3, 1, 1),
+            (192, 192, 16, 3, 1, 1), (192, 192, 16, 3, 1, 2),
+            (192, 192, 8, 3, 0, 1), (192, 192, 6, 1, 0, 1),
+            (192, 10, 6, 1, 0, 1))
+
+
+def test_serving_chain_compiles_for_v5e(one_chip, compiled_kernels):
+    """The scheduler's whole-model program for All-CNN-C at bucket 8: the
+    nine plans and their ReLUs compile as one program for a described
+    v5e, one Mosaic kernel per layer, each layer's ops under its
+    ``repro.serve.<layer>`` scope, the weights as arguments."""
+    from repro.serve.sched import _chain_forward, _NetChain
+    scenes = {f"allcnn-c/L{i}": ConvScene(
+        B=8, IC=ic, OC=oc, inH=hw, inW=hw, fltH=k, fltW=k, padH=p, padW=p,
+        stdH=s, stdW=s) for i, (ic, oc, hw, k, p, s) in enumerate(ALLCNN_C)}
+    chain = _NetChain(name="allcnn-c", layers=tuple(scenes),
+                      activation=jax.nn.relu)
+    plans = [make_plan(sc, ConvOp.FPROP) for sc in scenes.values()]
+    first = scenes["allcnn-c/L0"]
+    x = jax.ShapeDtypeStruct(first.in_shape(), jnp.float32,
+                             sharding=one_chip)
+    ws = tuple(jax.ShapeDtypeStruct(sc.flt_shape(), jnp.float32,
+                                    sharding=one_chip)
+               for sc in scenes.values())
+    compiled = jax.jit(_chain_forward(chain, plans)).lower(x, ws).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == len(scenes)
+    for lname in scenes:
+        assert f"repro.serve.{lname}/" in hlo, lname
